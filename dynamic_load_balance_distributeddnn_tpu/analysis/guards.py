@@ -49,6 +49,7 @@ _lock = threading.Lock()
 _installed = False
 _total_compiles = 0
 _total_bg_compiles = 0
+_total_compile_s = 0.0
 _active_budgets: List["CompileBudget"] = []
 # Weak registry: consumers (one tracker per Trainer) drop out when their
 # owner is garbage-collected, so a process that builds many engines (bench
@@ -57,7 +58,7 @@ _trackers: "weakref.WeakSet[CompileTracker]" = weakref.WeakSet()
 
 
 def _on_event(event: str, duration: float = 0.0, **_kw) -> None:
-    global _total_compiles, _total_bg_compiles
+    global _total_compiles, _total_bg_compiles, _total_compile_s
     if not event.startswith(_COMPILE_EVENT_PREFIX):
         return
     # the listener runs ON the compiling thread, so the thread name tells
@@ -65,6 +66,7 @@ def _on_event(event: str, duration: float = 0.0, **_kw) -> None:
     background = threading.current_thread().name.startswith(AOT_THREAD_PREFIX)
     with _lock:
         _total_compiles += 1
+        _total_compile_s += float(duration)
         if background:
             _total_bg_compiles += 1
         for budget in _active_budgets:
@@ -98,6 +100,16 @@ def compile_count() -> int:
     _ensure_listener()
     with _lock:
         return _total_compiles
+
+
+def compile_seconds() -> float:
+    """Summed duration of every XLA backend compile observed so far
+    (foreground AND background; persistent-cache hits fire no event, so a
+    warm run reads near zero). Diff it around a region for its compile
+    seconds — set-up time, reported apart from the timed work."""
+    _ensure_listener()
+    with _lock:
+        return _total_compile_s
 
 
 def background_compile_count() -> int:

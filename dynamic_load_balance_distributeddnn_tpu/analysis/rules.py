@@ -112,7 +112,6 @@ _TRACE_ENTRY_TAILS = (
     "jax.pjit",
     "shard_map",
     "jax.shard_map",
-    "jax.experimental.shard_map.shard_map",
     "jax.vmap",
     "vmap",
     "jax.grad",

@@ -13,6 +13,9 @@ from __future__ import annotations
 import sys
 from typing import Optional, Sequence
 
+from dynamic_load_balance_distributeddnn_tpu.compile_cache import (
+    enable_compile_cache,
+)
 from dynamic_load_balance_distributeddnn_tpu.config import config_from_args
 from dynamic_load_balance_distributeddnn_tpu.obs.logging import (
     mark_run_done,
@@ -59,14 +62,19 @@ def _run_already_done_global(cfg) -> bool:
     return skip
 
 
-def main(argv: Optional[Sequence[str]] = None) -> int:
+def run(argv: Optional[Sequence[str]] = None):
+    """Parse ``argv``, train, mark the run done. Returns the trainer — or
+    None when the idempotence probe found the run already finished and
+    nothing was trained (callers that must know, e.g. ``chip_smoke.py``,
+    check for it; the command line just exits 0 like the reference)."""
     cfg = config_from_args(argv)
+    enable_compile_cache()
     _maybe_init_distributed(cfg)
     if _run_already_done_global(cfg):
         print("\n===========================")
         print("Had finished this experiment, skipping...")
         print("===========================\n")
-        return 0
+        return None
 
     if cfg.model == "transformer" and cfg.seq_parallel:
         from dynamic_load_balance_distributeddnn_tpu.train.sp_engine import (
@@ -84,6 +92,11 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         trainer = Trainer(cfg)
     trainer.run()
     mark_run_done(cfg)
+    return trainer
+
+
+def main(argv: Optional[Sequence[str]] = None) -> int:
+    run(argv)
     return 0
 
 

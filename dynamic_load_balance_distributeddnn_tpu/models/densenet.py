@@ -4,7 +4,7 @@ Constructors 121/169/201/161 mirror Net/Densenet.py:87-100; `-m densenet`
 selects DenseNet-121 with growth 32 (dbs.py:353) — the model of the canonical
 README recipe and the benchmark north star.
 
-TPU note (the roofline lever, artifacts/ROOFLINE.md): DenseNet is
+TPU note (the roofline lever): DenseNet is
 bandwidth-bound on v5e. Two dense-block dataflows are provided, bitwise
 equivalent (pinned by test):
 
@@ -17,14 +17,14 @@ equivalent (pinned by test):
   reference concat produces.
 
 The buffer variant was round 4's cost-model bet (−36% bytes on the XLA:CPU
-cost model at B=32/f32). **Measured on the chip it LOSES**: the round-5
-on-chip A/B (artifacts/STEPTIME_tpu.json, TPU v5e, DenseNet-121 B=512 bf16)
-shows XLA:TPU does NOT alias the ``buf.at[...].set`` chain — the TPU-backend
-cost model charges the buffer variant 93.7 GB/step vs concat's 78.3 GB
-(+20%), and RTT-corrected synced step times agree: buffer ≈129 ms/step vs
-concat ≈87 ms. XLA:TPU fuses the literal concat chain better than the
-hand-scheduled buffer fill — so the concat dataflow is the default and the
-buffer variant is kept as the measured counterexample + equivalence oracle.
+cost model at B=32/f32). **On the chip it lost**: the round-5 on-chip A/B
+(pre-ledger record, deleted in PR 21, see git history; TPU v5e, DenseNet-121
+B=512 bf16) showed XLA:TPU does NOT alias the ``buf.at[...].set`` chain — the
+TPU-backend cost model charged the buffer variant +20% bytes per step and
+synced step times agreed. XLA:TPU fuses the literal concat chain better than
+the hand-scheduled buffer fill — so the concat dataflow is the default and
+the buffer variant is kept as the counterexample + equivalence oracle
+(ROADMAP D6 removes it).
 """
 
 from __future__ import annotations

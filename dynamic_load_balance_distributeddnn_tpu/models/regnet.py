@@ -21,7 +21,7 @@ class GroupedConv(nn.Module):
 
     XLA:CPU pathologically compiles ``feature_group_count > 1`` convolutions
     — a single RegNetY-400MF fwd+bwd jit was observed 77+ minutes into one
-    compile on the CPU tier (CHANGES_r04.md), while XLA:TPU compiles the
+    compile on the CPU tier (round 4), while XLA:TPU compiles the
     same graph in seconds. ``decompose=True`` emits ``groups`` plain convs
     over channel slices instead — that IS the definition of grouped
     convolution (each group is an independent conv), so the math is

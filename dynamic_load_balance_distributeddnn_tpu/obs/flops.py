@@ -54,10 +54,7 @@ def compiled_flops(jitted_fn, *args, compiled=None) -> Optional[float]:
     try:
         if compiled is None:
             compiled = jitted_fn.lower(*args).compile()
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # some backends wrap in a list
-            cost = cost[0] if cost else {}
-        val = float(cost.get("flops", 0.0))
+        val = float(compiled.cost_analysis().get("flops", 0.0))
         return val if val > 0 else None
     except Exception:
         return None

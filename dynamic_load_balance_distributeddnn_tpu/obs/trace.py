@@ -51,7 +51,7 @@ from typing import Dict, List, Optional, Tuple
 
 _LOG = logging.getLogger("graftscope")
 
-# Phase taxonomy: spans with cat="phase" are the NON-OVERLAPPING controller
+# Phase set: spans with cat="phase" are the NON-OVERLAPPING controller
 # segments that tile an epoch span (cat="epoch"); attribution() sums them.
 # Deeper instrumentation uses the other categories so nested spans never
 # double-count into the per-phase table.

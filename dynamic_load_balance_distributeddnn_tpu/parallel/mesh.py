@@ -32,48 +32,10 @@ HOST_AXIS = "host"
 DEVICE_AXIS = "device"
 
 
-def _resolve_shard_map():
-    """(shard_map callable, replication-check kwarg name) for the installed
-    jax: the public ``jax.shard_map`` landed after 0.4.37 and intermediate
-    versions still spell the flag ``check_rep`` rather than ``check_vma``, so
-    pick the function by presence and the kwarg by its actual signature."""
-    import inspect
-
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as fn
-    kwarg = (
-        "check_vma"
-        if "check_vma" in inspect.signature(fn).parameters
-        else "check_rep"
-    )
-    return fn, kwarg
-
-
-_SHARD_MAP_IMPL = None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``jax.shard_map``; every shard_map in the repo routes
-    through here so the jax-version split lives in one place."""
-    global _SHARD_MAP_IMPL
-    if _SHARD_MAP_IMPL is None:
-        _SHARD_MAP_IMPL = _resolve_shard_map()
-    fn, kwarg = _SHARD_MAP_IMPL
-    return fn(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{kwarg: check_vma},
-    )
-
-
-def axis_size(axis_name: str) -> int:
-    """Mesh-axis size from inside a shard_map/collective scope, across jax
-    versions: ``jax.lax.axis_size`` where it exists, else the classic
-    ``psum(1, axis)`` idiom (constant-folded to a static int at trace time)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+# Every shard_map in the repo routes through these two names, so call sites
+# import them from here rather than reaching into jax themselves.
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size
 
 
 def initialize_multihost(coordinator: Optional[str] = None, **kw) -> None:

@@ -29,11 +29,15 @@ subprocesses:
   stays exactly the in-process path; the subprocess only pre-pays the
   expensive XLA emitter work, on its own core, with its own GIL.
 
-A worker that dies, rejects a payload, or cannot be spawned degrades to
-the in-process path for free: the replay IS a full compile when the cache
-has no entry. Workers are spawned (never forked — forking a live XLA
-runtime is undefined behavior) with the parent's environment, so
-``JAX_PLATFORMS`` / ``XLA_FLAGS`` (device counts!) carry over.
+A worker that dies or rejects a payload degrades that job to the
+in-process path: the replay IS a full compile when the cache has no entry
+(counted as ``worker_fallback`` and logged). Workers are spawned (never
+forked — forking a live XLA runtime is undefined behavior) with the
+parent's environment, so ``JAX_PLATFORMS`` / ``XLA_FLAGS`` (device counts!)
+and the compile-cache placement carry over. Each worker opens the default
+platform's backend, so the pool is a host-platform (CPU) facility: on a TPU
+backend the chip belongs to the one process that holds it, and
+``AOTCompileService`` refuses ``backend="process"`` at construction.
 
 Each worker keeps its own graftscope span buffer (one ``worker_compile``
 span per job, pid-tagged by the exporter) and writes it as a Chrome-trace
@@ -47,7 +51,6 @@ from __future__ import annotations
 import os
 import pickle
 import queue
-import tempfile
 import threading
 import time
 import uuid
@@ -70,225 +73,105 @@ def default_worker_count() -> int:
     return min(8, cpus // 2)
 
 
-def ensure_persistent_cache(logger=None) -> Optional[str]:
+def ensure_persistent_cache() -> str:
     """Pin the run's persistent compilation cache (the channel worker
-    compiles travel through). An already-configured dir (bench.py pins an
-    absolute one into every subprocess) is respected; otherwise a
-    run-scoped temp dir is created. Floors are zeroed so small programs
-    persist too. Returns the dir, or None if the cache cannot be enabled."""
-    import jax
+    compiles travel through) where ``compile_cache.enable_compile_cache``
+    places it — the same directory every other entry point uses, never a
+    temporary one. Floors are zeroed so small programs persist too.
+    Returns the directory."""
+    from jax._src import compilation_cache as _cc
 
-    try:
-        cache_dir = jax.config.jax_compilation_cache_dir or os.environ.get(
-            "JAX_COMPILATION_CACHE_DIR"
-        )
-        if not cache_dir:
-            cache_dir = tempfile.mkdtemp(prefix="jax_graft_aot_cache_")
-        cache_dir = os.path.abspath(cache_dir)
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_enable_compilation_cache", True)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # jax memoizes BOTH the cache-used decision (_cache_checked) and the
-        # cache object itself (_cache_initialized, possibly None when no dir
-        # was configured yet) on the FIRST compile of the process; any
-        # compile that ran before this configuration freezes the cache off
-        # and every replay would silently recompile. Reset so the next
-        # compile re-evaluates with the dir in place.
-        from jax._src import compilation_cache as _cc
-
-        stale_decision = getattr(_cc, "_cache_checked", False) and not getattr(
-            _cc, "_cache_used", False
-        )
-        stale_cache = (
-            getattr(_cc, "_cache_initialized", False)
-            and getattr(_cc, "_cache", None) is None
-        )
-        if stale_decision or stale_cache:
-            _cc.reset_cache()
-        return cache_dir
-    except Exception as e:  # pragma: no cover - config surface drift
-        if logger is not None:
-            logger.warning(f"compile workers: persistent cache unavailable: {e!r}")
-        return None
-
-
-# ---------------------------------------------------------------------------
-# jax-internal-surface pinning (PR 5 follow-up): extract_lowering_payload
-# rides on ``pxla.create_compile_options``, a private jax function whose
-# signature has no stability contract. Rather than letting a jax upgrade
-# silently turn every offload into a blanket ``except Exception`` fallback
-# (the process backend would quietly degrade to the thread backend), the
-# capability is resolved ONCE per process against a pinned signature table:
-# a known surface yields a versioned adapter, drift yields a clear one-time
-# diagnostic naming the observed signature. New jax surfaces get a new row
-# here, not a rewrite at every call site.
-
-# parameter-name tuple -> adapter version tag. jax 0.4.30-0.5.x surface:
-_PAYLOAD_SURFACES: Dict[Tuple[str, ...], str] = {
-    (
-        "computation", "mesh", "spmd_lowering", "tuple_args",
-        "auto_spmd_lowering", "allow_prop_to_inputs",
-        "allow_prop_to_outputs", "backend", "np_dev", "pmap_nreps",
-        "compiler_options",
-    ): "v1",
-}
-_payload_api_cache: Optional[Dict[str, Any]] = None
-
-
-def payload_capability() -> Dict[str, Any]:
-    """Import-time-style capability check for the lowering-payload
-    extraction, resolved once per process: ``{"available", "version",
-    "reason"}``. Available means ``pxla.create_compile_options`` exists AND
-    its signature matches a pinned surface this module was written against;
-    anything else is reported as drift with the observed signature, so a
-    jax upgrade fails LOUD (one diagnostic) instead of silently disabling
-    the process compile backend."""
-    global _payload_api_cache
-    if _payload_api_cache is not None:
-        return _payload_api_cache
-    cap: Dict[str, Any]
-    try:
-        import inspect
-
-        from jax._src.interpreters import pxla
-
-        fn = getattr(pxla, "create_compile_options", None)
-        if fn is None:
-            cap = {
-                "available": False,
-                "version": None,
-                "reason": "jax._src.interpreters.pxla.create_compile_options "
-                "no longer exists (jax internal surface drift)",
-            }
-        else:
-            params = tuple(inspect.signature(fn).parameters)
-            version = _PAYLOAD_SURFACES.get(params)
-            if version is None:
-                cap = {
-                    "available": False,
-                    "version": None,
-                    "reason": (
-                        "pxla.create_compile_options signature drifted: "
-                        f"observed {params!r}, known surfaces "
-                        f"{sorted(_PAYLOAD_SURFACES.values())} — add the new "
-                        "surface to _PAYLOAD_SURFACES in "
-                        "runtime/compile_worker.py"
-                    ),
-                }
-            else:
-                cap = {"available": True, "version": version, "reason": ""}
-    except Exception as e:  # pragma: no cover - import surface drift
-        cap = {
-            "available": False,
-            "version": None,
-            "reason": f"jax internals unimportable: {e!r}",
-        }
-    _payload_api_cache = cap
-    return cap
-
-
-_payload_drift_warned = False
-
-
-def _warn_payload_drift(reason: str) -> None:
-    global _payload_drift_warned
-    if _payload_drift_warned:
-        return
-    _payload_drift_warned = True
-    import warnings
-
-    warnings.warn(
-        "compile workers: lowering-payload extraction disabled — "
-        f"{reason}; AOT jobs degrade to in-process compiles (the thread "
-        "backend)",
-        RuntimeWarning,
-        stacklevel=2,
+    from dynamic_load_balance_distributeddnn_tpu.compile_cache import (
+        enable_compile_cache,
     )
+
+    cache_dir = enable_compile_cache(min_compile_secs=0.0)
+    os.makedirs(cache_dir, exist_ok=True)
+    # jax memoizes BOTH the cache-used decision (_cache_checked) and the
+    # cache object itself (_cache_initialized, possibly None when no dir
+    # was configured yet) on the FIRST compile of the process; any
+    # compile that ran before this configuration freezes the cache off
+    # and every replay would silently recompile. Reset so the next
+    # compile re-evaluates with the dir in place.
+    stale_decision = _cc._cache_checked and not _cc._cache_used
+    stale_cache = _cc._cache_initialized and _cc._cache is None
+    if stale_decision or stale_cache:
+        _cc.reset_cache()
+    return cache_dir
 
 
 def extract_lowering_payload(lowered) -> Optional[Dict[str, Any]]:
     """Self-contained compile job from a ``jax.stages.Lowered``: MLIR
     bytecode + the exact serialized ``CompileOptions`` the parent's own
-    ``lowered.compile()`` will use, so the worker's cache write and the
-    parent's replay share one cache key. Returns None when the program
-    cannot be offloaded (host callbacks, AUTO shardings, pmap-style
-    replication) — the caller then compiles in-process as before — or when
-    the pinned jax internal surface drifted (:func:`payload_capability`;
-    one loud diagnostic, then clean degradation)."""
+    ``lowered.compile()`` will use (what ``pxla._cached_compilation`` builds
+    from ``compile_args`` and the lowering's device list), so the worker's
+    cache write and the parent's replay share one cache key. Returns None
+    when the program cannot be offloaded (host callbacks, AUTO shardings,
+    pmap-style replication, no concrete device list) — the caller then
+    compiles in-process. Written against the one installed jax: a missing
+    key or a changed private signature RAISES (the job fails and the AOT
+    service counts it) instead of degrading in silence."""
     import numpy as np
+    from jax._src.interpreters import mlir, pxla
+    from jax._src.sharding_impls import AUTO
 
-    cap = payload_capability()
-    if not cap["available"]:
-        _warn_payload_drift(cap["reason"])
+    lowering = lowered._lowering
+    ca = lowering.compile_args
+    if ca["host_callbacks"] or ca["ordered_effects"]:
         return None
-    try:
-        from jax._src.interpreters import mlir, pxla
-        from jax._src.sharding_impls import AUTO, UnspecifiedValue
-
-        lowering = lowered._lowering
-        ca = lowering.compile_args
-        if ca.get("host_callbacks") or ca.get("ordered_effects"):
-            return None
-        if int(ca.get("pmap_nreps", 1)) != 1:
-            return None
-        in_sh, out_sh = ca["in_shardings"], ca["out_shardings"]
-        if any(isinstance(s, AUTO) for s in tuple(in_sh) + tuple(out_sh)):
-            return None  # auto-SPMD keys depend on the solver's mesh choice
-        allow_in = tuple(isinstance(s, (UnspecifiedValue, AUTO)) for s in in_sh)
-        allow_out = tuple(isinstance(s, (UnspecifiedValue, AUTO)) for s in out_sh)
-        da = ca["device_assignment"]
-        dev = np.vectorize(lambda i: da[i], otypes=[object])(np.arange(len(da)))
-        kvs = dict(getattr(lowering, "_compiler_options_kvs", ()) or ())
-        module = lowering.stablehlo()
-        options = pxla.create_compile_options(
-            module,
-            None,
-            ca["spmd_lowering"],
-            ca["tuple_args"],
-            ca["auto_spmd_lowering"],
-            allow_in,
-            allow_out,
-            ca["backend"],
-            dev,
-            ca.get("pmap_nreps", 1),
-            kvs,
-        )
-        return {
-            "module": mlir.module_to_bytecode(module),
-            "options": options.SerializeAsString(),
-            "device_ids": [int(d.id) for d in da],
-            "platform": ca["backend"].platform,
-        }
-    except Exception:
-        # any internal-surface drift (new jax) degrades to in-process
-        # compiles instead of killing the job
+    if int(ca["pmap_nreps"]) != 1:
         return None
+    in_sh, out_sh = ca["in_shardings"], ca["out_shardings"]
+    if any(isinstance(s, AUTO) for s in tuple(in_sh) + tuple(out_sh)):
+        return None  # auto-SPMD keys depend on the solver's mesh choice
+    da = lowering._device_list
+    if da is None:
+        return None  # fully abstract lowering: compile() needs a device list
+    allow_in, allow_out = pxla.get_prop_to_input_output(in_sh, out_sh, 0)
+    dev = np.vectorize(lambda i: da[i], otypes=[object])(np.arange(len(da)))
+    module = lowering.stablehlo()
+    options = pxla.create_compile_options(
+        module,
+        None,
+        ca["spmd_lowering"],
+        ca["tuple_args"],
+        ca["auto_spmd_lowering"],
+        allow_in,
+        allow_out,
+        ca["backend"],
+        dev,
+        ca["pmap_nreps"],
+        dict(lowering._compiler_options_kvs),
+    )
+    return {
+        "module": mlir.module_to_bytecode(module),
+        "options": options.SerializeAsString(),
+        "device_ids": [int(d.id) for d in da],
+        "platform": ca["backend"].platform,
+    }
 
 
 def _worker_main(
     worker_id: int,
     job_q,
     ack_q,
-    cache_dir: str,
     trace_path: Optional[str],
 ) -> None:
-    """Worker process body. Spawned (fresh interpreter): configure the
-    shared cache BEFORE jax touches any backend, ack readiness once the
-    (expensive) jax import is done, then drain jobs until the poison pill.
-
-    Runs in a subprocess — keep stdlib-only until jax is configured."""
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+    """Worker process body. Spawned (fresh interpreter, the parent's
+    environment): place the shared cache through the same helper the parent
+    used BEFORE jax touches any backend — same environment, same directory
+    — ack readiness once the (expensive) jax import is done, then drain
+    jobs until the poison pill."""
     t_import = time.perf_counter()
     import numpy as np
 
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_enable_compilation_cache", True)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    from dynamic_load_balance_distributeddnn_tpu.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache(min_compile_secs=0.0)
     from jax._src import compiler as jax_compiler
     from jax._src import xla_bridge
     from jax._src.interpreters import mlir
@@ -326,9 +209,9 @@ def _worker_main(
             err = ""
             try:
                 payload = pickle.loads(blob)
-                dev = np.vectorize(lambda i: by_id[i], otypes=[object])(
-                    np.asarray(payload["device_ids"])
-                )
+                devs = tuple(by_id[i] for i in payload["device_ids"])
+                dev = np.empty(len(devs), dtype=object)
+                dev[:] = devs
                 options = xc.CompileOptions.ParseFromString(payload["options"])
                 with tracer.span(
                     "worker_compile", cat="compile", args={"key": name}
@@ -336,7 +219,8 @@ def _worker_main(
                     with mlir.make_ir_context() as ctx:
                         module = ir.Module.parse(payload["module"], context=ctx)
                         jax_compiler.compile_or_get_cached(
-                            backend, module, dev, options, ()
+                            backend, module, dev, options, (),
+                            xc.DeviceList(devs),
                         )
             except BaseException as e:  # noqa: BLE001 - reported via the ack
                 err = repr(e)
@@ -361,14 +245,12 @@ class CompileWorkerPool:
     def __init__(
         self,
         workers: int,
-        cache_dir: str,
         trace_dir: Optional[str] = None,
         logger=None,
     ):
         import multiprocessing as mp
 
         self._workers = max(int(workers), 1)
-        self._cache_dir = cache_dir
         self._logger = logger
         self._ctx = mp.get_context("spawn")
         self._job_q = self._ctx.Queue()
@@ -397,7 +279,7 @@ class CompileWorkerPool:
                 self._trace_paths.append(trace_path)
             p = self._ctx.Process(
                 target=_worker_main,
-                args=(i, self._job_q, self._ack_q, cache_dir, trace_path),
+                args=(i, self._job_q, self._ack_q, trace_path),
                 daemon=True,
                 name=f"aot-compile-worker-{i}",
             )

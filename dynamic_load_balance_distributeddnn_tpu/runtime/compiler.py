@@ -4,8 +4,7 @@ The engine's old warm-start compiled its bucket-ladder executables by
 *executing dummy steps* — allocate a zero batch, ``device_put`` five arrays,
 dispatch, ``block_until_ready`` — serially, one rung at a time
 (engine._warm_shapes, kept behind ``--aot_warm off`` as the A/B reference and
-flagged by graftlint G007). On short benchmark runs that warm wall dominated;
-two bench rounds died inside it (BENCH_r04/r05, rc=124).
+flagged by graftlint G007). On short benchmark runs that warm wall dominated.
 
 This service compiles the same executables ahead of time:
 
@@ -211,11 +210,25 @@ class AOTCompileService:
     ):
         if backend not in ("thread", "process"):
             raise ValueError(f"backend must be 'thread' or 'process', got {backend!r}")
+        if backend == "process":
+            import jax
+
+            if jax.default_backend() == "tpu":
+                # a chip belongs to one process: each compile worker opens
+                # the default platform's backend, i.e. the chip THIS process
+                # holds, and would fail or hang there. Say so at start-up
+                # rather than degrade to in-process compiles in silence.
+                raise RuntimeError(
+                    "aot_backend='process' is not available on a TPU "
+                    "backend: the chip belongs to this process and a compile "
+                    "worker that opens it fails or hangs; use the default "
+                    "aot_backend='thread'"
+                )
         self._backend = backend
         self._process_workers = int(process_workers)
         self._trace_dir = trace_dir
         self._worker_pool = None  # CompileWorkerPool, spawned lazily
-        self._worker_pool_failed = False
+        self._worker_pool_closed = False
         if backend == "process" and not int(workers):
             # keep the workers fed: while worker k compiles job i, thread
             # k should already be lowering job i+1
@@ -258,38 +271,26 @@ class AOTCompileService:
 
     def _ensure_worker_pool(self):
         """Spawn the subprocess worker pool on first use (process backend).
-        Returns the pool or None (spawn failed once → stay degraded: every
-        job compiles in-process, which is just the thread backend)."""
+        Returns the pool, or None on the thread backend and after
+        :meth:`close` (which forbids a respawn). A spawn that fails raises
+        into the job that asked for it — counted in ``stats()["failed"]``."""
         if self._backend != "process":
             return None
         with self._lock:
-            if self._worker_pool is not None or self._worker_pool_failed:
+            if self._worker_pool is not None or self._worker_pool_closed:
                 return self._worker_pool
-        try:
-            from dynamic_load_balance_distributeddnn_tpu.runtime.compile_worker import (
-                CompileWorkerPool,
-                default_worker_count,
-                ensure_persistent_cache,
-            )
+        from dynamic_load_balance_distributeddnn_tpu.runtime.compile_worker import (
+            CompileWorkerPool,
+            default_worker_count,
+            ensure_persistent_cache,
+        )
 
-            cache_dir = ensure_persistent_cache(self._logger)
-            if cache_dir is None:
-                raise RuntimeError("persistent compilation cache unavailable")
-            pool = CompileWorkerPool(
-                self._process_workers or default_worker_count(),
-                cache_dir,
-                trace_dir=self._trace_dir,
-                logger=self._logger,
-            )
-        except Exception as e:
-            with self._lock:
-                self._worker_pool_failed = True
-            if self._logger is not None:
-                self._logger.warning(
-                    f"compile workers unavailable ({e!r}); AOT service "
-                    "degrades to in-process compiles"
-                )
-            return None
+        ensure_persistent_cache()
+        pool = CompileWorkerPool(
+            self._process_workers or default_worker_count(),
+            trace_dir=self._trace_dir,
+            logger=self._logger,
+        )
         with self._lock:
             if self._worker_pool is None:
                 self._worker_pool = pool
@@ -300,8 +301,9 @@ class AOTCompileService:
 
     def _offload_to_worker(self, key: Hashable, lowered, tr, key_args) -> None:
         """Process backend: ship the lowered program to a worker and wait
-        for its cache write. Purely best-effort — on ANY failure the
-        caller's replay compiles in-process (the designed fallback)."""
+        for its cache write. A worker that dies, times out or rejects the
+        payload costs only that job's offload: the caller's replay compiles
+        in-process, logged and counted as ``worker_fallback``."""
         from dynamic_load_balance_distributeddnn_tpu.runtime.compile_worker import (
             extract_lowering_payload,
         )
@@ -527,7 +529,7 @@ class AOTCompileService:
             # offload to live workers), but forbid a respawn: a drain-time
             # job racing _ensure_worker_pool must not spin up a fresh pool
             # that close() would then leak
-            self._worker_pool_failed = True
+            self._worker_pool_closed = True
             wpool = self._worker_pool
         if pool is not None:
             pool.shutdown(drop_pending=not wait)

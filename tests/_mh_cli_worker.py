@@ -12,11 +12,11 @@ here; the rendezvous itself is cli.main's job.
 import os
 import sys
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 

@@ -27,11 +27,11 @@ import os
 import sys
 import traceback
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")
 if os.environ.get("DBS_MH_PARITY") != "1":
     # gloo needs a live distributed client; the parity leg is single-process
     jax.config.update("jax_cpu_collectives_implementation", "gloo")

@@ -139,7 +139,8 @@ def test_worker_death_falls_back_in_process(proc_service):
 def test_payload_extraction_is_self_contained():
     """The payload carries everything the worker needs: MLIR bytecode,
     serialized CompileOptions, device ids, platform — and an unoffloadable
-    program degrades to None instead of raising."""
+    program returns None, while a lowering whose private surface this code
+    was not written against raises instead of degrading in silence."""
     f, spec = _make_program(1.5, width=23)
     payload = extract_lowering_payload(f.lower(*spec))
     assert payload is not None
@@ -147,7 +148,11 @@ def test_payload_extraction_is_self_contained():
     assert isinstance(payload["options"], bytes) and payload["options"]
     assert payload["platform"] == "cpu"
     assert payload["device_ids"] == [0]
-    assert extract_lowering_payload(object()) is None
+    # host callback: deliberately not offloadable
+    cb = jax.jit(lambda x: jax.debug.callback(lambda v: None, x, ordered=True))
+    assert extract_lowering_payload(cb.lower(spec[0])) is None
+    with pytest.raises(AttributeError):
+        extract_lowering_payload(object())
 
 
 def test_pool_sizing_default_adapts_to_cores(monkeypatch):
@@ -171,34 +176,17 @@ def test_pool_sizing_default_adapts_to_cores(monkeypatch):
         assert rc.default_pool_size() == want_pool, cpus
 
 
-def test_payload_capability_pinned_and_drift_degrades_loud(monkeypatch):
-    """The jax-internal surface extract_lowering_payload rides on is pinned
-    behind a versioned capability check: the installed jax resolves to a
-    known adapter, and simulated signature drift disables extraction with
-    ONE clear diagnostic (not a silent blanket-except degradation)."""
-    import warnings
-
-    from dynamic_load_balance_distributeddnn_tpu.runtime import compile_worker as cw
-
-    cap = cw.payload_capability()
-    assert cap["available"] and cap["version"] == "v1"
-    # simulate drift: an unknown signature surface
-    monkeypatch.setattr(cw, "_payload_api_cache", {
-        "available": False, "version": None,
-        "reason": "pxla.create_compile_options signature drifted: observed "
-        "('new_arg',)",
-    })
-    monkeypatch.setattr(cw, "_payload_drift_warned", False)
-    f, spec = _make_program(2.5, width=21)
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        assert cw.extract_lowering_payload(f.lower(*spec)) is None
-        assert cw.extract_lowering_payload(f.lower(*spec)) is None
-    drift = [x for x in w if "signature drifted" in str(x.message)]
-    assert len(drift) == 1  # loud once, then clean degradation
+def test_process_backend_refuses_to_start_on_a_tpu(monkeypatch):
+    """A chip belongs to one process: compile workers open the default
+    platform's backend, so on a TPU the process backend fails at start-up
+    with that reason instead of degrading to in-process compiles."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="chip belongs to this process"):
+        AOTCompileService(backend="process")
+    AOTCompileService(backend="thread").close()  # the default is unaffected
 
 
-def test_dead_at_spawn_pool_unblocks_waiters_fast(tmp_path):
+def test_dead_at_spawn_pool_unblocks_waiters_fast():
     """A pool whose workers die before ever acking ready (e.g. a __main__
     the spawn machinery cannot re-import) must cost ~0: wait_ready returns
     False as soon as the death is detected, not after its full timeout —
@@ -206,7 +194,7 @@ def test_dead_at_spawn_pool_unblocks_waiters_fast(tmp_path):
     back, stretching a 12 s epoch to 250 s."""
     import time
 
-    pool = CompileWorkerPool(1, str(tmp_path))
+    pool = CompileWorkerPool(1)
     for p in pool._procs:
         p.terminate()  # well before the ~5 s jax import can ack ready
     t0 = time.perf_counter()
@@ -218,9 +206,8 @@ def test_dead_at_spawn_pool_unblocks_waiters_fast(tmp_path):
 
 
 def test_ensure_persistent_cache_respects_configured_dir():
-    """conftest pins the suite's cache dir; the worker channel must reuse
-    it (bench.py pins one absolute dir into every subprocess the same
-    way), not fork a second cache."""
+    """conftest places the suite's cache through the one helper; the worker
+    channel must reuse that directory, not fork a second cache."""
     configured = jax.config.jax_compilation_cache_dir
     assert configured
     assert ensure_persistent_cache() == str(configured)

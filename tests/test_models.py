@@ -47,7 +47,7 @@ def test_cnn_families_exact_param_parity(name, nc, expect):
 
 
 def test_densenet_default_is_concat():
-    """Round-5 on-chip verdict (artifacts/STEPTIME_tpu.json): the literal
+    """Round-5 on-chip verdict (pre-ledger record, deleted in PR 21): the literal
     concat dataflow beats the round-4 buffer fill on XLA:TPU (87 vs 129
     ms/step, -20% bytes by the TPU cost model), so every default-built
     DenseNet must run it."""

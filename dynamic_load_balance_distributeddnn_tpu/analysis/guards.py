@@ -18,6 +18,12 @@ The listener registers lazily on first use and is never unregistered
 (jax.monitoring has no public unregister; an idle listener costs one function
 call per compile, i.e. nothing).
 
+The same listener is graftscope's source for JAX's own share of a set-up:
+the four duration events of :data:`COMPILE_SPANS` become ``compile`` spans
+(each ending when the event fires, on the thread that fired it, so the AOT
+pool's threads keep their own tracks). With the tracer off that costs one
+dict lookup and one attribute check per event.
+
 **Background (AOT) compiles.** The async compile service
 (runtime/compiler.py) deliberately compiles on pool threads while epochs
 execute; its threads are named with :data:`AOT_THREAD_PREFIX`, and the
@@ -38,7 +44,18 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Iterator, List, Optional
 
+from dynamic_load_balance_distributeddnn_tpu.obs.trace import get_tracer
+
 _COMPILE_EVENT_PREFIX = "/jax/core/compile/backend_compile"
+
+# jax.monitoring duration event -> graftscope span (cat "compile"). A
+# `backend_compile` holds the `cache_read` that served it, where one did.
+COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax_lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read",
+}
 
 # Compile-pool threads are named with this prefix; runtime/compiler.py
 # imports it from here (single definition — a drift would silently count
@@ -59,6 +76,11 @@ _trackers: "weakref.WeakSet[CompileTracker]" = weakref.WeakSet()
 
 def _on_event(event: str, duration: float = 0.0, **_kw) -> None:
     global _total_compiles, _total_bg_compiles, _total_compile_s
+    span = COMPILE_SPANS.get(event)
+    if span is not None:
+        tracer = get_tracer()
+        if tracer.enabled:
+            tracer.span_ending_now(span, "compile", float(duration))
     if not event.startswith(_COMPILE_EVENT_PREFIX):
         return
     # the listener runs ON the compiling thread, so the thread name tells

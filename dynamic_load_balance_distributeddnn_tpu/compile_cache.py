@@ -12,6 +12,16 @@ between the processes of one command or between two commands:
   or a harness can place the cache from outside).
 * unset → ``<checkout>/.jax_cache``, an absolute path derived from this
   package's location, never from the cwd and never a temporary directory.
+
+What a key is made of is set here too. JAX keys a program without its
+metadata, so an executable compiled before a ``jax.named_scope`` was added or
+moved is served under the new code's key with the old ``op_name``s in it, and
+graftscope's scope map (obs/scopes.py), which reads them from the executable,
+names the device's work wrongly (PERF.md, PR 24: five such hits left 39 % of a
+profiled epoch in no scope). The key therefore takes the metadata, and the
+metadata is cut down to the op names: file names and line numbers stay out of
+the lowered program, or an edit anywhere in a file would make every program
+under it a miss.
 """
 
 from __future__ import annotations
@@ -42,4 +52,6 @@ def enable_compile_cache(min_compile_secs: float = 0.5) -> str:
         "jax_persistent_cache_min_compile_time_secs", float(min_compile_secs)
     )
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     return cache_dir

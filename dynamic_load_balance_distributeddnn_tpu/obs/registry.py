@@ -33,8 +33,11 @@ from dynamic_load_balance_distributeddnn_tpu.obs.trace import Tracer, get_tracer
 def device_peak_memory() -> Dict:
     """Per-device peak-memory series (ISSUE 13 satellite) — the datum the
     zero1 A/B reports. Where the backend provides ``device.memory_stats()``
-    (TPU/GPU runtimes), one row per local device with ``bytes_in_use`` and
-    ``peak_bytes_in_use``; CPU backends expose no per-device allocator, so
+    (TPU/GPU runtimes), one row per local device with ``bytes_in_use``,
+    ``peak_bytes_in_use`` (live buffers), ``peak_bytes_reserved`` (programs'
+    temporaries: the TPU runtime counts them apart, and a chip half full of
+    them read 3 % by the first alone) and their sum ``peak_bytes``, as
+    ``benchmark/harness.peak_bytes`` has it; CPU backends expose no per-device allocator, so
     the fallback reports the process's peak RSS (and tracemalloc's peak
     when tracing is active) — a coarser but honest host-side ceiling.
 
@@ -56,15 +59,15 @@ def device_peak_memory() -> Dict:
         except Exception:  # noqa: BLE001 — backend without an allocator API
             stats = None
         if stats:
+            in_use = int(stats.get("peak_bytes_in_use", stats.get("bytes_in_use", 0)))
+            reserved = int(stats.get("peak_bytes_reserved", 0))
             out["per_device"].append(
                 {
                     "device": str(d),
                     "bytes_in_use": int(stats.get("bytes_in_use", 0)),
-                    "peak_bytes_in_use": int(
-                        stats.get(
-                            "peak_bytes_in_use", stats.get("bytes_in_use", 0)
-                        )
-                    ),
+                    "peak_bytes_in_use": in_use,
+                    "peak_bytes_reserved": reserved,
+                    "peak_bytes": in_use + reserved,
                 }
             )
     if not out["per_device"]:

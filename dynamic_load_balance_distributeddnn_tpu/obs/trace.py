@@ -32,21 +32,28 @@ Design constraints, in order:
   the annotation bridge is explicitly enabled.
 
 Event tuples are ``(name, cat, ph, ts_us, dur_us, tid, args)`` with
-``ph in ("X", "i", "C")`` — complete spans, instant events (watchdog
-heartbeats), counters. ``args`` additionally carries the tracer's *current
+``ph in ("X", "i")`` — complete spans and instant events (watchdog
+heartbeats). ``args`` additionally carries the tracer's *current
 epoch* (``set_epoch``) so offline attribution can group spans per epoch
 without parsing span nesting across threads.
+
+Only ``cat="phase"`` spans enter :func:`attribution`'s sums. The other
+categories name what happens inside a phase: ``wait`` (the controller thread
+blocked on the device), ``transfer`` (input hand-over and puts), ``dispatch``,
+``probe``, ``compile`` (JAX's own tracing, lowering, cache reads and backend
+compiles, from ``jax.monitoring``, beside the AOT service's), ``host`` (one
+span per garbage collection) and ``setup`` (trainer construction).
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import logging
 import os
 import threading
 import time
 from collections import deque
-from functools import wraps
 from typing import Dict, List, Optional, Tuple
 
 _LOG = logging.getLogger("graftscope")
@@ -57,6 +64,9 @@ _LOG = logging.getLogger("graftscope")
 # double-count into the per-phase table.
 EPOCH_CAT = "epoch"
 PHASE_CAT = "phase"
+
+# The process-wide tracer, made at the end of this module (``get_tracer``).
+_TRACER: Optional["Tracer"] = None
 
 
 class _NullSpan:
@@ -115,13 +125,15 @@ class _Span:
 
 
 class Tracer:
-    """Span/instant/counter recorder with Chrome-trace export.
+    """Span/instant recorder with Chrome-trace export.
 
     ``mode``: ``"off"`` (every call degrades to the singleton no-op),
     ``"on"`` (unbounded buffer), ``"ring"`` (keep the last ``ring_size``
     events). ``jax_annotations=True`` additionally wraps each span in a
     ``jax.profiler.TraceAnnotation`` so host spans line up with device
     timelines when a profiler trace (``--profile_dir``) is active.
+    ``trace_dir``: where files that belong beside the trace are written while
+    the run goes on (the scope map of obs/scopes.py); None writes none.
     """
 
     def __init__(
@@ -129,8 +141,10 @@ class Tracer:
         mode: str = "off",
         ring_size: int = 1_000_000,
         jax_annotations: bool = False,
+        trace_dir: Optional[str] = None,
     ):
-        self.configure(mode, ring_size=ring_size, jax_annotations=jax_annotations)
+        self.configure(mode, ring_size=ring_size, jax_annotations=jax_annotations,
+                       trace_dir=trace_dir)
 
     # ------------------------------------------------------------- lifecycle
 
@@ -139,6 +153,7 @@ class Tracer:
         mode: str,
         ring_size: int = 1_000_000,
         jax_annotations: bool = False,
+        trace_dir: Optional[str] = None,
     ) -> "Tracer":
         if mode not in ("off", "on", "ring"):
             raise ValueError(f"trace mode must be 'off', 'on' or 'ring', got {mode!r}")
@@ -158,6 +173,18 @@ class Tracer:
         # never under live traffic)
         self.enabled = mode != "off"  # graftlint: disable=G012
         self._jax_bridge = bool(jax_annotations) and self.enabled
+        self.trace_dir = trace_dir if self.enabled else None
+        self._gc_span = None
+        if self is _TRACER:
+            # one `gc` span per collection, for the process-wide tracer only:
+            # a collection over a large heap stalls the controller thread
+            # with no other trace of itself. Hooked while enabled, unhooked
+            # when configured off (a private Tracer of a test hooks nothing).
+            hooked = _gc_hook in gc.callbacks
+            if self.enabled and not hooked:
+                gc.callbacks.append(_gc_hook)
+            elif hooked and not self.enabled:
+                gc.callbacks.remove(_gc_hook)
         # deque.append is atomic under the GIL — pipeline/compile-pool
         # threads emit without a lock on the hot path
         self._events: deque = deque(maxlen=ring_size if mode == "ring" else None)
@@ -246,36 +273,20 @@ class Tracer:
             return _NULL_SPAN
         return _Span(self, name, cat, args)
 
-    def traced(self, name: Optional[str] = None, cat: str = PHASE_CAT):
-        """Decorator twin of :meth:`span` — times every call of the wrapped
-        function under ``name`` (default: the function's __qualname__)."""
-
-        def deco(fn):
-            label = name or fn.__qualname__
-
-            @wraps(fn)
-            def wrapper(*a, **kw):
-                if not self.enabled:
-                    return fn(*a, **kw)
-                with _Span(self, label, cat, None):
-                    return fn(*a, **kw)
-
-            return wrapper
-
-        return deco
-
     def instant(self, name: str, cat: str = "instant", args: Optional[dict] = None) -> None:
         """Zero-duration marker (watchdog heartbeats, faults, rebalances)."""
         if not self.enabled:
             return
         self._emit(name, cat, "i", time.perf_counter(), 0.0, args)
 
-    def counter(self, name: str, value: float, cat: str = "counter") -> None:
-        """Counter sample (compile counts, queue depths) — renders as a
-        stacked track in Perfetto."""
+    def span_ending_now(self, name: str, cat: str, duration_s: float) -> None:
+        """A span reported after the fact (``jax.monitoring`` hands a
+        duration when the work is over): it ends now and lasted
+        ``duration_s``, on the calling thread's track. Not bridged to the
+        profiler, which only takes live annotations."""
         if not self.enabled:
             return
-        self._emit(name, cat, "C", time.perf_counter(), 0.0, {"value": float(value)})
+        self._emit(name, cat, "X", time.perf_counter() - duration_s, duration_s, None)
 
     def _emit(self, name, cat, ph, t0: float, dur: float, args) -> None:
         tid = threading.get_ident()
@@ -616,6 +627,18 @@ def attribution_by_job(events: List[dict]) -> Dict:
 # One process-wide tracer: the instrumented modules (engine, pipeline, AOT
 # service, solver, watchdog) fetch it by function call so a single configure()
 # — from config or tests — flips every call site at once. Ships disabled.
+def _gc_hook(phase: str, info: dict) -> None:
+    """``gc.callbacks`` entry of the process-wide tracer (see ``configure``):
+    the collector calls it with "start" and "stop" on the collecting thread."""
+    tr = _TRACER
+    if phase == "start":
+        tr._gc_span = tr.span("gc", cat="host", args={"generation": info["generation"]})
+        tr._gc_span.__enter__()
+    elif tr._gc_span is not None:
+        span, tr._gc_span = tr._gc_span, None
+        span.__exit__(None, None, None)
+
+
 _TRACER = Tracer(mode="off")
 
 
@@ -624,10 +647,12 @@ def get_tracer() -> Tracer:
 
 
 def configure(
-    mode: str, ring_size: int = 1_000_000, jax_annotations: bool = False
+    mode: str, ring_size: int = 1_000_000, jax_annotations: bool = False,
+    trace_dir: Optional[str] = None,
 ) -> Tracer:
     """(Re)configure the process-wide tracer; returns it. ``mode="off"``
     restores the zero-cost disabled state (buffer dropped)."""
     return _TRACER.configure(
-        mode, ring_size=ring_size, jax_annotations=jax_annotations
+        mode, ring_size=ring_size, jax_annotations=jax_annotations,
+        trace_dir=trace_dir,
     )

@@ -65,6 +65,7 @@ from typing import Callable, Deque, Dict, Hashable, List, Optional, Sequence, Tu
 from dynamic_load_balance_distributeddnn_tpu.analysis.guards import (
     AOT_THREAD_PREFIX,
 )
+from dynamic_load_balance_distributeddnn_tpu.obs.scopes import record_program
 from dynamic_load_balance_distributeddnn_tpu.obs.trace import get_tracer
 
 
@@ -346,6 +347,10 @@ class AOTCompileService:
                 self._offload_to_worker(key, lowered, tr, key_args)
             with tr.span("aot_compile", cat="compile", args=key_args):
                 compiled = lowered.compile()
+            # graftscope's scope map (obs/scopes.py): one line per program,
+            # written here so that it is on disk before the program first
+            # runs; nothing is parsed or written with the tracer off
+            record_program(key, compiled)
         except BaseException:
             with self._lock:
                 self._stats["failed"] += 1

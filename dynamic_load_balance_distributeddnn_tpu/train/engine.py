@@ -35,6 +35,7 @@ import numpy as np
 
 from dynamic_load_balance_distributeddnn_tpu.analysis.guards import (
     CompileTracker,
+    compile_count,
     compile_seconds,
 )
 from dynamic_load_balance_distributeddnn_tpu.balance import (
@@ -156,10 +157,24 @@ class Trainer:
                 cfg.trace,
                 ring_size=cfg.trace_ring,
                 jax_annotations=cfg.trace_annotations,
+                # where the scope map of every compiled program goes while
+                # the run goes on (obs/scopes.py)
+                trace_dir=cfg.trace_dir,
             )
         else:
             self._trace = get_tracer()
+        # the jax.monitoring listener that turns JAX's own tracing, lowering,
+        # cache reads and compiles into spans: installed before the first
+        # program of this trainer is traced
+        compile_count()
+        with self._trace.span("trainer_init", cat="setup"):
+            self._setup(bundle, injector)
 
+    def _setup(self, bundle: Optional[DatasetBundle], injector: Optional[FaultInjector]) -> None:
+        """Everything of construction that is timed: graftscope's
+        ``trainer_init`` span, with ``setup_model`` (all but a millisecond of it
+        on the chip, PERF.md PR 24) under it."""
+        cfg = self.cfg
         # Multi-host: each process owns a contiguous slice of the global
         # workers, mapped onto its LOCAL devices; the combine mesh spans every
         # process's used devices (XLA collectives ride ICI within a host, DCN
@@ -348,7 +363,8 @@ class Trainer:
         self._comm_sig = self._compute_comm_sig()
 
         self._setup_data(bundle)
-        self._setup_model()
+        with self._trace.span("setup_model", cat="setup"):
+            self._setup_model()
 
         # Async AOT compile service (runtime/compiler.py): warm-start and
         # speculative compiles run as jit(...).lower(abstract).compile() jobs
@@ -946,6 +962,25 @@ class Trainer:
         if self._aot is None:
             return fallback
         return self._aot.get(self._aot_step_key(kind, b, d, win)) or fallback
+
+    def _scoped(self, key: tuple, fn, args):
+        """The executable to dispatch for a program that is resolved outside
+        the AOT registry (validation's step, a lazy-jit fallback). With the
+        tracer off that is ``fn`` itself. While it is on, graftscope's scope
+        map (obs/scopes.py) needs the program's compiled text, and the
+        service writes a line for every program it compiles: so the wrapper
+        is compiled through the service, once per ``key``, and that
+        executable is dispatched in its place (same HLO; the lazy path would
+        trace and lower it a second time)."""
+        if not self._trace.enabled or self._aot is None or isinstance(fn, jax.stages.Compiled):
+            return fn
+        try:
+            return self._aot.compile_now(key, fn, args)
+        except Exception as e:  # the run goes on, this program unmapped
+            if key not in self._aot_failed_logged:
+                self._aot_failed_logged.add(key)
+                self.logger.warning(f"scope map of {key} not written: {e!r}")
+            return fn
 
     def _aot_submit_worker_steps(
         self, d: int, b: int, wins, want_acc: bool, want_plain: bool,
@@ -4426,10 +4461,12 @@ class Trainer:
                 pack_total,
             )
             for i, _ in enumerate(ranges):
-                # transfer vs dispatch tracks in the trace: the put span
-                # includes any wait on the overlapped gather thread
+                # the controller's wait on the overlapped gather thread, then
+                # the put alone: transfer vs dispatch tracks in the trace
+                with self._trace.span("input_wait", cat="transfer"):
+                    gathered = fut.result()
                 with self._trace.span("fused_put", cat="transfer"):
-                    win = self._put_fused_window(*fut.result())
+                    win = self._put_fused_window(*gathered)
                 if i + 1 < len(ranges):
                     fut = pool.submit(
                         self._gather_fused_window, plan, *ranges[i + 1], pad_to,
@@ -4458,7 +4495,11 @@ class Trainer:
                             xs.shape[0], xs.shape[1], slow.shape[0], args
                         )
                         self.state, metrics = fn(*args)
-                    metrics_total += np.asarray(jax.block_until_ready(metrics))
+                    # the host's one blocking read of the device in a window:
+                    # apart from the dispatch above it, so the trace tells
+                    # host work from the host waiting on the chip
+                    with self._trace.span("device_wait", cat="wait"):
+                        metrics_total += np.asarray(jax.block_until_ready(metrics))
                 heartbeat()
         metrics = metrics_total
         probe_overhead = 0.0
@@ -4986,7 +5027,8 @@ class Trainer:
             if self.timing_model is None:
                 last_aux = (aux_windows or aux_acc)[-1:] or None
                 if last_aux is not None:
-                    jax.block_until_ready(last_aux)
+                    with self._trace.span("device_wait", cat="wait"):
+                        jax.block_until_ready(last_aux)
                 now = time.perf_counter()
                 # host-side dispatch walls since the last evaluation
                 # (balance/timing.py mark_window): the measured wall below
@@ -5193,7 +5235,8 @@ class Trainer:
                 # is detected (and the epoch abandoned for re-solve) within
                 # detect_misses windows, not at the next epoch
                 self._check_health(epoch, w0 / max(plan.num_steps, 1))
-                data, staged = pipe.get(i)
+                with self._trace.span("input_wait", cat="transfer"):
+                    data, staged = pipe.get(i)
                 if first_data is None:
                     first_data = data
                 pl, _ = self._seg_for_step(seg_plans, w0)
@@ -5311,9 +5354,11 @@ class Trainer:
         if mode == "scan":
             # flatten the scanned aux back into the per-step path's exact
             # (step, worker) row order so the float64 metric summation below
-            # reproduces per-step results bit for bit
-            for aux in aux_windows:
-                aux_acc.extend(np.asarray(aux, dtype=np.float64).reshape(-1, 4))
+            # reproduces per-step results bit for bit; the first read waits
+            # for the windows dispatched above
+            with self._trace.span("device_wait", cat="wait"):
+                for aux in aux_windows:
+                    aux_acc.extend(np.asarray(aux, dtype=np.float64).reshape(-1, 4))
             cache_n = self.steps.superstep_cache_size()
             if cache_n > len(self._superstep_keys):
                 self.logger.warning(
@@ -5324,7 +5369,8 @@ class Trainer:
                 )
         data = first_data  # probes below reuse the first window's batches
 
-        jax.block_until_ready(self.state.params)
+        with self._trace.span("device_wait", cat="wait"):
+            jax.block_until_ready(self.state.params)
         heartbeat()  # epoch pipeline drained
         # Probe AFTER the epoch's async pipeline has drained, so per-worker
         # timings measure that worker's executable alone, not queueing noise.
@@ -5397,9 +5443,10 @@ class Trainer:
             self._flops_per_padded_example = f / max(b_pad, 1) if f else -1.0
             flops_probe_overhead = time.perf_counter() - t0
 
-        wloss = float(np.sum([float(a[0]) for a in aux_acc]))
-        loss_sum = float(np.sum([float(a[1]) for a in aux_acc]))
-        count = float(np.sum([float(a[2]) for a in aux_acc]))
+        with self._trace.span("device_wait", cat="wait"):  # per-step aux reads
+            wloss = float(np.sum([float(a[0]) for a in aux_acc]))
+            loss_sum = float(np.sum([float(a[1]) for a in aux_acc]))
+            count = float(np.sum([float(a[2]) for a in aux_acc]))
         if self.n_proc > 1:
             # Per-process partial sums -> global (per-epoch metadata, host path)
             from jax.experimental import multihost_utils
@@ -5471,6 +5518,11 @@ class Trainer:
         # warm pass: execute everything once, untimed (with the AOT service
         # this compiles nothing — the executables already exist)
         for r, (args, d, fn) in staged.items():
+            fn = self._scoped(
+                self._aot_step_key(probe_kind, int(data[r][0].shape[1]), d, None),
+                fn, (views[d],) + args,
+            )
+            staged[r] = (args, d, fn)
             _, aux = fn(views[d], *args)
             jax.block_until_ready(aux)
             heartbeat()
@@ -5642,6 +5694,9 @@ class Trainer:
         combine_probe = self._aot_resolve_combine(
             probe_name, getattr(self.steps, probe_name)
         )
+        combine_probe = self._scoped(
+            (probe_name, self._aot_gen) + self._comm_sig, combine_probe, (self.state, stacked)
+        )
         jax.block_until_ready(combine_probe(self.state, stacked).params)
         t0 = time.perf_counter()
         probed = combine_probe(self.state, stacked)
@@ -5750,8 +5805,14 @@ class Trainer:
 
         def run_chunk(xb, yb, mb):
             nonlocal loss_sum, correct, count
-            stats = self.steps.fused_eval_step(self.state.params, xb, yb, mb)
-            stats = np.asarray(jax.block_until_ready(stats))
+            args = (self.state.params, xb, yb, mb)
+            step = self._scoped(
+                ("fused_eval_step", self._aot_gen) + tuple(xb.shape),
+                self.steps.fused_eval_step, args,
+            )
+            stats = step(*args)
+            with self._trace.span("device_wait", cat="wait"):
+                stats = np.asarray(jax.block_until_ready(stats))
             heartbeat()
             loss_sum += float(stats[0])
             correct += float(stats[1])

@@ -35,6 +35,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamic_load_balance_distributeddnn_tpu.models import ModelSpec
+from dynamic_load_balance_distributeddnn_tpu.obs import scopes
 from dynamic_load_balance_distributeddnn_tpu.ops.augment import augment_images, normalize_images
 from dynamic_load_balance_distributeddnn_tpu.ops.faultload import synthetic_load
 from dynamic_load_balance_distributeddnn_tpu.ops.losses import (
@@ -56,6 +57,15 @@ def _per_example_loss(
 
         return fused_softmax_xent(outputs, labels)
     return per_example_cross_entropy(outputs, labels)
+
+
+def _named(name: str, fn: Callable) -> Callable:
+    """``fn`` under the name its program is filed under. jit names the HLO
+    module after the function, and a profiler event finds its scope through
+    the module's name (obs/scopes.py), so no two programs of the library may
+    share one (``per_shard`` named six of them)."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 class StepLibrary:
@@ -256,6 +266,28 @@ class StepLibrary:
             return augment_images(x_u8, rng, self.mean, self.std)
         return normalize_images(x_u8, self.mean, self.std)
 
+    def _clip_local(self, grads, w):
+        """The reference clips each worker's LOCAL mean gradient before the
+        weighted combine (dbs.py:274). Our local grad is w_r * g_r, so
+        unscale -> clip -> rescale."""
+        if not self.grad_clip > 0:
+            return grads
+        with jax.named_scope(scopes.CLIP):
+            w_r = jnp.maximum(jnp.sum(w), 1e-12)
+            unscaled = jax.tree_util.tree_map(lambda g: g / w_r, grads)
+            gnorm = optax.global_norm(unscaled)
+            scale = jnp.minimum(1.0, self.grad_clip / jnp.maximum(gnorm, 1e-12))
+            return jax.tree_util.tree_map(lambda g: g * scale, grads)
+
+    def _apply_update(self, state: TrainState, grads, **changes) -> TrainState:
+        """The replicated optimizer step every path ends in."""
+        with jax.named_scope(scopes.UPDATE):
+            updates, opt_state = self.tx.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
+        return state.replace(
+            params=params, opt_state=opt_state, step=state.step + 1, **changes
+        )
+
     # ----------------------------------------------------------- elastic path
 
     def _build(self):
@@ -263,32 +295,30 @@ class StepLibrary:
 
         def local_grads(params, x, y, w, rng, slow_iters, train_prep_rng):
             """Shared forward/backward for one worker's (padded) batch."""
-            x = self._cast_compute(self._prep_images(x, train_prep_rng, train=True))
+            with jax.named_scope(scopes.AUGMENT):
+                x = self._cast_compute(self._prep_images(x, train_prep_rng, train=True))
 
             def loss_fn(p):
-                out = self._apply_train(p, x, rng)
-                losses = _per_example_loss(spec, out.astype(jnp.float32), y, self.use_pallas)
-                mask = (w > 0).astype(jnp.float32)
-                wloss = jnp.sum(losses * w)
-                return wloss, (jnp.sum(losses * mask), jnp.sum(mask))
+                # the backward pass needs no scope of its own: JAX names it
+                # transpose(jvp(forward)), which obs/scopes.py reads as such
+                with jax.named_scope(scopes.FORWARD):
+                    out = self._apply_train(p, x, rng)
+                    losses = _per_example_loss(
+                        spec, out.astype(jnp.float32), y, self.use_pallas
+                    )
+                    mask = (w > 0).astype(jnp.float32)
+                    wloss = jnp.sum(losses * w)
+                    return wloss, (jnp.sum(losses * mask), jnp.sum(mask))
 
             (wloss, (loss_sum, count)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(params)
-
-            if self.grad_clip > 0:
-                # The reference clips each worker's LOCAL mean gradient before
-                # the weighted combine (dbs.py:274). Our local grad is
-                # w_r * g_r, so unscale -> clip -> rescale.
-                w_r = jnp.maximum(jnp.sum(w), 1e-12)
-                unscaled = jax.tree_util.tree_map(lambda g: g / w_r, grads)
-                gnorm = optax.global_norm(unscaled)
-                scale = jnp.minimum(1.0, self.grad_clip / jnp.maximum(gnorm, 1e-12))
-                grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
+            grads = self._clip_local(grads, w)
 
             # Straggler injection (fault_mode='compute'): real, unelidable MXU
             # work whose trip count is a traced scalar.
-            probe = synthetic_load(slow_iters, wloss)
+            with jax.named_scope(scopes.INJECT):
+                probe = synthetic_load(slow_iters, wloss)
             return grads, wloss, loss_sum, count, probe
 
         @jax.jit
@@ -406,7 +436,10 @@ class StepLibrary:
         # -------------------------------------------------- combine + update
 
         replicated = NamedSharding(self.mesh, P())
-        tx = self.tx
+
+        def sum_stacked(stacked_grads):
+            with jax.named_scope(scopes.COMBINE):
+                return jax.tree_util.tree_map(lambda g: jnp.sum(g, axis=0), stacked_grads)
 
         @functools.partial(
             jax.jit,
@@ -414,10 +447,7 @@ class StepLibrary:
             out_shardings=replicated,
         )
         def combine_update(state: TrainState, stacked_grads):
-            grads = jax.tree_util.tree_map(lambda g: jnp.sum(g, axis=0), stacked_grads)
-            updates, opt_state = tx.update(grads, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
-            return state.replace(params=params, opt_state=opt_state, step=state.step + 1)
+            return self._apply_update(state, sum_stacked(stacked_grads))
 
         self.combine_update = combine_update
 
@@ -426,10 +456,7 @@ class StepLibrary:
         # never double-applies an optimizer step.
         @functools.partial(jax.jit, out_shardings=replicated)
         def combine_probe(state: TrainState, stacked_grads):
-            grads = jax.tree_util.tree_map(lambda g: jnp.sum(g, axis=0), stacked_grads)
-            updates, opt_state = tx.update(grads, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
-            return state.replace(params=params, opt_state=opt_state, step=state.step + 1)
+            return self._apply_update(state, sum_stacked(stacked_grads))
 
         self.combine_probe = combine_probe
 
@@ -453,12 +480,14 @@ class StepLibrary:
             g, wloss, loss_sum, count, probe = self._local_grads(
                 state.params, xs[i], ys[i], ws_[i], ks[i], slows[i], ks[i]
             )
-            if acc is None:
-                acc = jax.tree_util.tree_map(lambda t: t[None], g)
-            else:
-                acc = jax.tree_util.tree_map(lambda a, t: a + t[None], acc, g)
+            with jax.named_scope(scopes.COMBINE):
+                if acc is None:
+                    acc = jax.tree_util.tree_map(lambda t: t[None], g)
+                else:
+                    acc = jax.tree_util.tree_map(lambda a, t: a + t[None], acc, g)
             aux.append(jnp.stack([wloss, loss_sum, count, probe]))
-        grads = jax.tree_util.tree_map(lambda t: jnp.sum(t, axis=0), acc)
+        with jax.named_scope(scopes.COMBINE):
+            grads = jax.tree_util.tree_map(lambda t: jnp.sum(t, axis=0), acc)
         if self.shard_update:
             # ZeRO-1 inside the scan (the shard_update x scan-mode gap,
             # carried since PR 13): scan mode only exists on a 1-device
@@ -474,13 +503,7 @@ class StepLibrary:
                 state, grads, rng, with_comm=False, local_index=0
             )
         else:
-            updates, opt_state = self.tx.update(
-                grads, state.opt_state, state.params
-            )
-            params = optax.apply_updates(state.params, updates)
-            state = state.replace(
-                params=params, opt_state=opt_state, step=state.step + 1
-            )
+            state = self._apply_update(state, grads)
         return state, jnp.stack(aux)
 
     @functools.cached_property
@@ -504,7 +527,9 @@ class StepLibrary:
             return jax.lax.scan(body, state, (xs, ys, ws_, ks), unroll=True)
 
         # donation rides the shard_update sanction (see _state_donate)
-        return jax.jit(superstep, donate_argnums=self._state_donate)
+        return jax.jit(
+            _named("group_superstep", superstep), donate_argnums=self._state_donate
+        )
 
     @functools.cached_property
     def group_superstep_idx(self):
@@ -529,7 +554,9 @@ class StepLibrary:
             return jax.lax.scan(body, state, (idxs, ws_, ks), unroll=True)
 
         # donation rides the shard_update sanction (see _state_donate)
-        return jax.jit(superstep, donate_argnums=self._state_donate)
+        return jax.jit(
+            _named("group_superstep_idx", superstep), donate_argnums=self._state_donate
+        )
 
     def superstep_cache_size(self) -> int:
         """Compiled (shape-tuple, window-length) superstep variants — the
@@ -559,7 +586,8 @@ class StepLibrary:
     # TrainState either way.
 
     def _sharded_combine_body(self, state: TrainState, stacked):
-        local = jax.tree_util.tree_map(lambda g: jnp.sum(g, axis=0), stacked)
+        with jax.named_scope(scopes.COMBINE):
+            local = jax.tree_util.tree_map(lambda g: jnp.sum(g, axis=0), stacked)
         rng = jax.random.fold_in(
             jax.random.fold_in(
                 jax.random.PRNGKey(0x5D1E), self._data_axis_index()
@@ -571,18 +599,14 @@ class StepLibrary:
         grads, new_residual = self._hier_combine(
             local, rng, state.comm_residual
         )
-        updates, opt_state = self.tx.update(
-            grads, state.opt_state, state.params
-        )
-        params = optax.apply_updates(state.params, updates)
-        return state.replace(
-            params=params, opt_state=opt_state, step=state.step + 1,
-            comm_residual=new_residual,
-        )
+        return self._apply_update(state, grads, comm_residual=new_residual)
 
-    def _sharded_combine_twin(self, donate: bool):
+    def _sharded_combine_twin(self, name: str, donate: bool):
+        def body(state, stacked):
+            return self._sharded_combine_body(state, stacked)
+
         sharded = shard_map(
-            self._sharded_combine_body,
+            _named(name, body),
             mesh=self.mesh,
             in_specs=(self._state_spec(), P(self._batch_entry)),
             out_specs=self._state_spec(),
@@ -599,24 +623,24 @@ class StepLibrary:
 
     @functools.cached_property
     def combine_update_hier(self):
-        return self._sharded_combine_twin(donate=True)
+        return self._sharded_combine_twin("combine_update_hier", donate=True)
 
     @functools.cached_property
     def combine_probe_hier(self):
         """Non-donating twin for timing probes (inputs stay valid, result —
         including the would-be residual update — is discarded)."""
-        return self._sharded_combine_twin(donate=False)
+        return self._sharded_combine_twin("combine_probe_hier", donate=False)
 
     @functools.cached_property
     def combine_update_zero1(self):
         """Flat-mesh ZeRO-1 combine twin (shard_update without hier): the
         same shard_map spine as the hier twins, with the body routed into
         the sharded update."""
-        return self._sharded_combine_twin(donate=True)
+        return self._sharded_combine_twin("combine_update_zero1", donate=True)
 
     @functools.cached_property
     def combine_probe_zero1(self):
-        return self._sharded_combine_twin(donate=False)
+        return self._sharded_combine_twin("combine_probe_zero1", donate=False)
 
     # ------------------------------------------------------- AOT lowerables
     # The executable families the async compile service can pre-compile,
@@ -732,16 +756,17 @@ class StepLibrary:
         grad_comm bench times the identical code."""
         names = self.axes
         sizes = tuple(int(self.mesh.shape[a]) for a in names)
-        out, new_residual = wirefmt.tree_allreduce(
-            grads,
-            rng,
-            names,
-            sizes,
-            self.grad_comm_wires,
-            residuals=(
-                tuple(r[0] for r in residual) if residual is not None else None
-            ),
-        )
+        with jax.named_scope(scopes.COMBINE):
+            out, new_residual = wirefmt.tree_allreduce(
+                grads,
+                rng,
+                names,
+                sizes,
+                self.grad_comm_wires,
+                residuals=(
+                    tuple(r[0] for r in residual) if residual is not None else None
+                ),
+            )
         return out, tuple(r[None] for r in new_residual)
 
     @functools.cached_property
@@ -804,7 +829,6 @@ class StepLibrary:
         fused-path analogue of the reference's per-step allreduce wait meter
         (dbs.py:297-299)."""
         spec = self.spec
-        tx = self.tx
         idx = self._data_axis_index()
         rng = jax.random.fold_in(
             jax.random.fold_in(jax.random.fold_in(jax.random.PRNGKey(0), seed), idx),
@@ -815,15 +839,17 @@ class StepLibrary:
             """Weighted loss + grads for one (micro-)batch slice. Per-example
             weighting makes accumulation exact: sums of weighted slice grads
             equal the whole-batch weighted grad."""
-            x_p = self._cast_compute(self._prep_images(x_s, rng_s, train=True))
+            with jax.named_scope(scopes.AUGMENT):
+                x_p = self._cast_compute(self._prep_images(x_s, rng_s, train=True))
 
             def loss_fn(p):
-                out = self._apply_train(p, x_p, rng_s)
-                losses = _per_example_loss(
-                    spec, out.astype(jnp.float32), y_s, self.use_pallas
-                )
-                mask = (w_s > 0).astype(jnp.float32)
-                return jnp.sum(losses * w_s), (jnp.sum(losses * mask), jnp.sum(mask))
+                with jax.named_scope(scopes.FORWARD):  # see local_grads
+                    out = self._apply_train(p, x_p, rng_s)
+                    losses = _per_example_loss(
+                        spec, out.astype(jnp.float32), y_s, self.use_pallas
+                    )
+                    mask = (w_s > 0).astype(jnp.float32)
+                    return jnp.sum(losses * w_s), (jnp.sum(losses * mask), jnp.sum(mask))
 
             return jax.value_and_grad(loss_fn, has_aux=True)(state.params)
 
@@ -856,21 +882,18 @@ class StepLibrary:
             )
         else:
             (wloss, (loss_sum, count)), grads = slice_grads(x, y, w, rng)
-        if self.grad_clip > 0:
-            w_r = jnp.maximum(jnp.sum(w), 1e-12)
-            unscaled = jax.tree_util.tree_map(lambda g: g / w_r, grads)
-            gnorm = optax.global_norm(unscaled)
-            scale = jnp.minimum(1.0, self.grad_clip / jnp.maximum(gnorm, 1e-12))
-            grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
+        grads = self._clip_local(grads, w)
 
-        probe = synthetic_load(slow_scalar, wloss)
+        with jax.named_scope(scopes.INJECT):
+            probe = synthetic_load(slow_scalar, wloss)
         metrics = jnp.stack([wloss, loss_sum, count, probe])
         if self.shard_update:
             state = self._zero1_update(
                 state, grads, jax.random.fold_in(rng, 0x7FFF), with_comm
             )
             if with_comm:
-                metrics = jax.lax.psum(metrics, self._axis_arg)
+                with jax.named_scope(scopes.COMBINE):
+                    metrics = jax.lax.psum(metrics, self._axis_arg)
             return state, metrics
         new_residual = state.comm_residual
         if with_comm:
@@ -881,15 +904,11 @@ class StepLibrary:
             elif self.compress_grads == "int8":
                 grads = self._compressed_psum(grads, rng)
             else:
-                grads = jax.lax.psum(grads, self._axis_arg)
-            metrics = jax.lax.psum(metrics, self._axis_arg)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        state = state.replace(
-            params=params, opt_state=opt_state, step=state.step + 1,
-            comm_residual=new_residual,
-        )
-        return state, metrics
+                with jax.named_scope(scopes.COMBINE):
+                    grads = jax.lax.psum(grads, self._axis_arg)
+            with jax.named_scope(scopes.COMBINE):
+                metrics = jax.lax.psum(metrics, self._axis_arg)
+        return self._apply_update(state, grads, comm_residual=new_residual), metrics
 
     def _compressed_psum(self, grads, rng):
         """Quantized FLAT gradient collective (compressed-allreduce family):
@@ -902,12 +921,13 @@ class StepLibrary:
         leaves, treedef = jax.tree_util.tree_flatten(grads)
         n = len(self.mesh.devices.flat)
         out = []
-        for i, g in enumerate(leaves):
-            key = jax.random.fold_in(rng, i + 0x7FFF)
-            total, _sent = wirefmt.compressed_reduce(
-                g, key, self._axis_arg, n, "int8"
-            )
-            out.append(total.astype(g.dtype))
+        with jax.named_scope(scopes.COMBINE):
+            for i, g in enumerate(leaves):
+                key = jax.random.fold_in(rng, i + 0x7FFF)
+                total, _sent = wirefmt.compressed_reduce(
+                    g, key, self._axis_arg, n, "int8"
+                )
+                out.append(total.astype(g.dtype))
         return jax.tree_util.tree_unflatten(treedef, out)
 
     def _zero1_update(
@@ -943,132 +963,140 @@ class StepLibrary:
         slice/pad pair is the identity the size-1 collectives would be."""
         import jax.flatten_util
 
-        opt = state.opt_state
-        n = len(self.mesh.devices.flat)
-        flat_g, unravel = jax.flatten_util.ravel_pytree(local_grads)
-        t_real = flat_g.size
-        # the ctor-validated padding is THE convention (train/state.py
-        # zero1_padded_size) — recomputing it here could silently diverge
-        # from the state conversion's chunk layout
-        padded = self.zero1_padded
-        assert padded % n == 0 and padded >= t_real, (padded, n, t_real)
-        flat_g = jnp.pad(flat_g, (0, padded - t_real))
-        chunk = padded // n
-        new_residual = state.comm_residual
-        key = jax.random.fold_in(rng, 0x2E01)
-        if self.hier:
-            names = self.axes
-            sizes = tuple(int(self.mesh.shape[a]) for a in names)
-            k = len(names) - 1
-            idxs = [jax.lax.axis_index(a) for a in names]
-            # same padding convention as attach_comm_residual(pad_multiple=n)
-            widths = wirefmt.tree_hop_widths(t_real, sizes, pad_multiple=n)
-            assert widths[-1] == padded, (widths, padded)
-            # this device's flat block: mixed-radix offset with the
-            # innermost axis most significant (zero1_chunk_axes order) —
-            # exactly where the scatter cascade below lands its chunk
-            off = idxs[0] * chunk
-            for i in range(1, k + 1):
-                off = off + idxs[i] * widths[i - 1]
-            if with_comm:
-                # innermost reduce-scatter at full precision (ICI): the
-                # device's index along the fastest axis picks its
-                # widths[k-1] slice of the in-group sum
-                v = jax.lax.psum_scatter(
-                    flat_g, names[k], scatter_dimension=0, tiled=True
-                )
-                res = state.comm_residual
-                new_rows = list(res) if res is not None else [None] * k
-                # middle hops k-1..1: EF'd compressed reduce-scatter on
-                # each hop's wire, vector shrinking by sizes[i] per hop
-                for i in range(k - 1, 0, -1):
-                    vi = v + (res[i][0] if res is not None else 0.0)
-                    v, sent = wirefmt.compressed_reduce_scatter_ef(
-                        vi,
-                        jax.random.fold_in(key, i),
-                        names[i],
-                        sizes[i],
-                        self.grad_comm_wires[i],
-                    )
-                    new_rows[i] = (vi - sent)[None]
-                # top hop: compressed all-reduce of the widths[0] chunk
-                v0 = v + (res[0][0] if res is not None else 0.0)
-                total, sent = wirefmt.compressed_reduce(
-                    v0,
-                    jax.random.fold_in(key, 0),
-                    names[0],
-                    sizes[0],
-                    self.grad_comm_wires[0],
-                )
-                new_rows[0] = (v0 - sent)[None]
-                new_residual = tuple(new_rows)
-                # re-split across the top level: index a_0 owns the a_0-th
-                # 1/s_0 sub-slice of the fully reduced top chunk
-                g_chunk = jax.lax.dynamic_slice(
-                    total, (idxs[0] * chunk,), (chunk,)
-                )
-            else:
-                g_chunk = jax.lax.dynamic_slice(flat_g, (off,), (chunk,))
-        else:
-            # A size-1 data axis makes the uncompressed collectives
-            # identities — route the slice twin instead, so single-device
-            # topologies compile the SAME flat-update program on every
-            # dispatch path (per-step combine twin, fused shard body,
-            # scan-mode superstep). The scan x zero1 bitwise-parity
-            # contract rides on the lowering being shared, not merely
-            # value-equal: XLA contracts the update chain differently
-            # around a collective than around a slice (ulp-scale drift no
-            # optimization_barrier placement removes). The quantized wire
-            # stays collective — stochastic rounding is no identity even
-            # over one device.
-            if n == 1 and self.compress_grads != "int8":
-                with_comm = False
-                if local_index is None:
-                    local_index = 0
-            off = (
-                self._data_axis_index() if local_index is None else local_index
-            ) * chunk
-            if with_comm:
-                if self.compress_grads == "int8":
-                    g_chunk = wirefmt.compressed_reduce_scatter(
-                        flat_g, key, self._axis_arg, n, "int8"
+        # one `update` scope over the whole sharded step; its collectives sit
+        # in a nested `combine`, which obs/scopes.py reads as the innermost
+        with jax.named_scope(scopes.UPDATE):
+            opt = state.opt_state
+            n = len(self.mesh.devices.flat)
+            flat_g, unravel = jax.flatten_util.ravel_pytree(local_grads)
+            t_real = flat_g.size
+            # the ctor-validated padding is THE convention (train/state.py
+            # zero1_padded_size) — recomputing it here could silently diverge
+            # from the state conversion's chunk layout
+            padded = self.zero1_padded
+            assert padded % n == 0 and padded >= t_real, (padded, n, t_real)
+            flat_g = jnp.pad(flat_g, (0, padded - t_real))
+            chunk = padded // n
+            new_residual = state.comm_residual
+            key = jax.random.fold_in(rng, 0x2E01)
+            if self.hier:
+                names = self.axes
+                sizes = tuple(int(self.mesh.shape[a]) for a in names)
+                k = len(names) - 1
+                idxs = [jax.lax.axis_index(a) for a in names]
+                # same padding convention as attach_comm_residual(pad_multiple=n)
+                widths = wirefmt.tree_hop_widths(t_real, sizes, pad_multiple=n)
+                assert widths[-1] == padded, (widths, padded)
+                # this device's flat block: mixed-radix offset with the
+                # innermost axis most significant (zero1_chunk_axes order) —
+                # exactly where the scatter cascade below lands its chunk
+                off = idxs[0] * chunk
+                for i in range(1, k + 1):
+                    off = off + idxs[i] * widths[i - 1]
+                if with_comm:
+                    # innermost reduce-scatter at full precision (ICI): the
+                    # device's index along the fastest axis picks its
+                    # widths[k-1] slice of the in-group sum
+                    with jax.named_scope(scopes.COMBINE):
+                        v = jax.lax.psum_scatter(
+                            flat_g, names[k], scatter_dimension=0, tiled=True
+                        )
+                    res = state.comm_residual
+                    new_rows = list(res) if res is not None else [None] * k
+                    # middle hops k-1..1: EF'd compressed reduce-scatter on
+                    # each hop's wire, vector shrinking by sizes[i] per hop
+                    for i in range(k - 1, 0, -1):
+                        vi = v + (res[i][0] if res is not None else 0.0)
+                        with jax.named_scope(scopes.COMBINE):
+                            v, sent = wirefmt.compressed_reduce_scatter_ef(
+                                vi,
+                                jax.random.fold_in(key, i),
+                                names[i],
+                                sizes[i],
+                                self.grad_comm_wires[i],
+                            )
+                        new_rows[i] = (vi - sent)[None]
+                    # top hop: compressed all-reduce of the widths[0] chunk
+                    v0 = v + (res[0][0] if res is not None else 0.0)
+                    with jax.named_scope(scopes.COMBINE):
+                        total, sent = wirefmt.compressed_reduce(
+                            v0,
+                            jax.random.fold_in(key, 0),
+                            names[0],
+                            sizes[0],
+                            self.grad_comm_wires[0],
+                        )
+                    new_rows[0] = (v0 - sent)[None]
+                    new_residual = tuple(new_rows)
+                    # re-split across the top level: index a_0 owns the a_0-th
+                    # 1/s_0 sub-slice of the fully reduced top chunk
+                    g_chunk = jax.lax.dynamic_slice(
+                        total, (idxs[0] * chunk,), (chunk,)
                     )
                 else:
-                    g_chunk = jax.lax.psum_scatter(
-                        flat_g, self._axis_arg, scatter_dimension=0, tiled=True
-                    )
+                    g_chunk = jax.lax.dynamic_slice(flat_g, (off,), (chunk,))
             else:
-                g_chunk = jax.lax.dynamic_slice(flat_g, (off,), (chunk,))
-        flat_p, _ = jax.flatten_util.ravel_pytree(state.params)
-        flat_p = jnp.pad(flat_p.astype(jnp.float32), (0, padded - t_real))
-        p_chunk = jax.lax.dynamic_slice(flat_p, (off,), (chunk,))
-        updates_chunk, opt_state = self.tx.update(g_chunk, opt, p_chunk)
-        if with_comm:
-            if self.hier:
-                # gather back in layout order, outermost axis first (each
-                # gather rebuilds the next-wider hop vector, inverting the
-                # scatter cascade LIFO), innermost last (rebuilds the flat
-                # vector)
-                delta = updates_chunk
-                for a in self.axes:
-                    delta = jax.lax.all_gather(delta, a, tiled=True)
+                # A size-1 data axis makes the uncompressed collectives
+                # identities — route the slice twin instead, so single-device
+                # topologies compile the SAME flat-update program on every
+                # dispatch path (per-step combine twin, fused shard body,
+                # scan-mode superstep). The scan x zero1 bitwise-parity
+                # contract rides on the lowering being shared, not merely
+                # value-equal: XLA contracts the update chain differently
+                # around a collective than around a slice (ulp-scale drift no
+                # optimization_barrier placement removes). The quantized wire
+                # stays collective — stochastic rounding is no identity even
+                # over one device.
+                if n == 1 and self.compress_grads != "int8":
+                    with_comm = False
+                    if local_index is None:
+                        local_index = 0
+                off = (
+                    self._data_axis_index() if local_index is None else local_index
+                ) * chunk
+                if with_comm:
+                    with jax.named_scope(scopes.COMBINE):
+                        if self.compress_grads == "int8":
+                            g_chunk = wirefmt.compressed_reduce_scatter(
+                                flat_g, key, self._axis_arg, n, "int8"
+                            )
+                        else:
+                            g_chunk = jax.lax.psum_scatter(
+                                flat_g, self._axis_arg, scatter_dimension=0, tiled=True
+                            )
+                else:
+                    g_chunk = jax.lax.dynamic_slice(flat_g, (off,), (chunk,))
+            flat_p, _ = jax.flatten_util.ravel_pytree(state.params)
+            flat_p = jnp.pad(flat_p.astype(jnp.float32), (0, padded - t_real))
+            p_chunk = jax.lax.dynamic_slice(flat_p, (off,), (chunk,))
+            updates_chunk, opt_state = self.tx.update(g_chunk, opt, p_chunk)
+            if with_comm:
+                with jax.named_scope(scopes.COMBINE):
+                    if self.hier:
+                        # gather back in layout order, outermost axis first (each
+                        # gather rebuilds the next-wider hop vector, inverting the
+                        # scatter cascade LIFO), innermost last (rebuilds the flat
+                        # vector)
+                        delta = updates_chunk
+                        for a in self.axes:
+                            delta = jax.lax.all_gather(delta, a, tiled=True)
+                    else:
+                        delta = jax.lax.all_gather(
+                            updates_chunk, self._axis_arg, tiled=True
+                        )
             else:
-                delta = jax.lax.all_gather(
-                    updates_chunk, self._axis_arg, tiled=True
+                delta = jax.lax.dynamic_update_slice(
+                    jnp.zeros((padded,), updates_chunk.dtype), updates_chunk, (off,)
                 )
-        else:
-            delta = jax.lax.dynamic_update_slice(
-                jnp.zeros((padded,), updates_chunk.dtype), updates_chunk, (off,)
+            params = jax.tree_util.tree_map(
+                lambda p, u: p + u.reshape(p.shape).astype(p.dtype),
+                state.params,
+                unravel(delta[:t_real]),
             )
-        params = jax.tree_util.tree_map(
-            lambda p, u: p + u.reshape(p.shape).astype(p.dtype),
-            state.params,
-            unravel(delta[:t_real]),
-        )
-        return state.replace(
-            params=params, opt_state=opt_state, step=state.step + 1,
-            comm_residual=new_residual,
-        )
+            return state.replace(
+                params=params, opt_state=opt_state, step=state.step + 1,
+                comm_residual=new_residual,
+            )
 
     @functools.cached_property
     def fused_step(self):
@@ -1081,7 +1109,7 @@ class StepLibrary:
 
         bx = self._batch_entry
         sharded = shard_map(
-            per_shard,
+            _named("fused_step", per_shard),
             mesh=self.mesh,
             in_specs=(self._state_spec(), P(bx), P(bx), P(bx), P(bx), P()),
             out_specs=(self._state_spec(), P()),
@@ -1107,7 +1135,7 @@ class StepLibrary:
 
         bx = self._batch_entry
         sharded = shard_map(
-            per_shard,
+            _named("fused_epoch", per_shard),
             mesh=self.mesh,
             in_specs=(
                 self._state_spec(),
@@ -1142,7 +1170,7 @@ class StepLibrary:
 
         bx = self._batch_entry
         sharded = shard_map(
-            per_shard,
+            _named("fused_epoch_idx", per_shard),
             mesh=self.mesh,
             in_specs=(
                 self._state_spec(),
@@ -1158,7 +1186,7 @@ class StepLibrary:
         )
         return jax.jit(sharded, donate_argnums=self._state_donate)
 
-    def _fused_probe(self, with_comm: bool):
+    def _fused_probe(self, name: str, with_comm: bool):
         """Non-donating single-step twin of ``fused_step`` for timing probes.
         ``with_comm=False`` drops the psums (see _fused_shard_body); outputs
         are discarded by the caller, so the unreplicated no-comm outputs are
@@ -1171,7 +1199,7 @@ class StepLibrary:
 
         bx = self._batch_entry
         sharded = shard_map(
-            per_shard,
+            _named(name, per_shard),
             mesh=self.mesh,
             in_specs=(self._state_spec(), P(bx), P(bx), P(bx), P(bx), P()),
             out_specs=(self._state_spec(), P()),
@@ -1181,11 +1209,11 @@ class StepLibrary:
 
     @functools.cached_property
     def fused_step_probe(self):
-        return self._fused_probe(with_comm=True)
+        return self._fused_probe("fused_step_probe", with_comm=True)
 
     @functools.cached_property
     def fused_step_nocomm(self):
-        return self._fused_probe(with_comm=False)
+        return self._fused_probe("fused_step_nocomm", with_comm=False)
 
     @functools.cached_property
     def comm_probe(self):
@@ -1197,10 +1225,11 @@ class StepLibrary:
         axes = self._axis_arg
 
         def per_shard(tree):
-            return jax.lax.psum(tree, axes)
+            with jax.named_scope(scopes.COMBINE):
+                return jax.lax.psum(tree, axes)
 
         sharded = shard_map(
-            per_shard,
+            _named("comm_probe", per_shard),
             mesh=self.mesh,
             in_specs=(P(),),
             out_specs=P(),
@@ -1219,19 +1248,20 @@ class StepLibrary:
         axes = self._axis_arg
 
         def per_shard(params, x, y, mask):
-            xf = prep(x, jax.random.PRNGKey(0), train=False)
-            out = apply_fn(params, xf, train=False)
-            losses = _per_example_loss(spec, out, y)
-            m = mask.astype(jnp.float32)
-            pred = jnp.argmax(out, axis=-1)
-            stats = jnp.stack(
-                [jnp.sum(losses * m), jnp.sum((pred == y).astype(jnp.float32) * m), jnp.sum(m)]
-            )
-            return jax.lax.psum(stats, axes)
+            with jax.named_scope(scopes.EVAL):
+                xf = prep(x, jax.random.PRNGKey(0), train=False)
+                out = apply_fn(params, xf, train=False)
+                losses = _per_example_loss(spec, out, y)
+                m = mask.astype(jnp.float32)
+                pred = jnp.argmax(out, axis=-1)
+                stats = jnp.stack(
+                    [jnp.sum(losses * m), jnp.sum((pred == y).astype(jnp.float32) * m), jnp.sum(m)]
+                )
+                return jax.lax.psum(stats, axes)
 
         bx = self._batch_entry
         sharded = shard_map(
-            per_shard,
+            _named("fused_eval_step", per_shard),
             mesh=self.mesh,
             in_specs=(P(), P(bx), P(bx), P(bx)),
             out_specs=P(),

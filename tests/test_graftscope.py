@@ -13,6 +13,8 @@ from dynamic_load_balance_distributeddnn_tpu.obs.trace import (
     Tracer,
     attribution,
     attribution_by_job,
+    configure,
+    get_tracer,
     load_trace,
 )
 from dynamic_load_balance_distributeddnn_tpu.obs.scope_cli import main as scope_main
@@ -82,12 +84,18 @@ def test_disabled_mode_is_singleton_and_allocation_free():
         for _ in range(100):
             with tr.span("hot"):
                 pass
+            with tr.span("device_wait", cat="wait"):
+                pass
             tr.instant("beat")
+            tr.span_ending_now("jax_trace", "compile", 0.001)
         snap1 = tracemalloc.take_snapshot()
         for _ in range(1000):
             with tr.span("hot"):
                 pass
+            with tr.span("device_wait", cat="wait"):
+                pass
             tr.instant("beat")
+            tr.span_ending_now("jax_trace", "compile", 0.001)
         snap2 = tracemalloc.take_snapshot()
     finally:
         tracemalloc.stop()
@@ -113,19 +121,20 @@ def test_ring_mode_keeps_the_tail():
     assert names == ["s7", "s8", "s9"]
 
 
-def test_traced_decorator_and_counter_and_instant():
+def test_instant_and_span_ending_now():
     tr = Tracer(mode="on")
-
-    @tr.traced("unit_of_work", cat="probe")
-    def work(x):
-        return x + 1
-
-    assert work(1) == 2
-    tr.counter("queue_depth", 3)
-    tr.instant("heartbeat", cat="heartbeat")
-    phs = {e[2] for e in tr.events()}
-    assert phs == {"X", "C", "i"}
-    assert spans(tr, "unit_of_work")
+    tr.instant("heartbeat", cat="heartbeat", args={"n": 1})
+    tr.span_ending_now("jax_lower", "compile", 0.25)
+    (beat,) = [e for e in tr.events() if e[2] == "i"]
+    assert beat[0] == "heartbeat" and beat[1] == "heartbeat" and beat[4] == 0.0
+    assert beat[6] == {"n": 1}
+    (lower,) = spans(tr, "jax_lower")
+    # reported after the fact: it ends now, so it began a quarter second ago
+    assert lower[4] == pytest.approx(0.25e6) and lower[3] < 0 < lower[3] + lower[4] + 1e5
+    off = Tracer(mode="off")
+    off.instant("heartbeat")
+    off.span_ending_now("jax_lower", "compile", 0.25)
+    assert off.events() == []
 
 
 # ---------------------------------------------------------------- export/schema
@@ -330,7 +339,8 @@ def test_registry_snapshot_unifies_surfaces():
     assert mem["source"] in ("memory_stats", "host_rss")
     if mem["source"] == "memory_stats":
         assert mem["per_device"] and all(
-            m["peak_bytes_in_use"] >= 0 for m in mem["per_device"]
+            m["peak_bytes"] == m["peak_bytes_in_use"] + m["peak_bytes_reserved"] >= 0
+            for m in mem["per_device"]
         )
     else:
         assert mem["host_peak_rss_bytes"] > 0
@@ -339,3 +349,96 @@ def test_registry_snapshot_unifies_surfaces():
     assert reg.series("examples_per_s") == [100.0]
     with pytest.raises(ValueError):
         reg.attach(host_metre=meter)
+
+
+def test_device_peak_memory_counts_what_programs_reserve(monkeypatch):
+    """The TPU runtime counts programs' temporaries under "reserved": a chip
+    half full read 0.53 GiB in use beside 7.05 reserved (PERF.md, PR 23)."""
+    import jax
+
+    from dynamic_load_balance_distributeddnn_tpu.obs.registry import device_peak_memory
+
+    class Chip:
+        def memory_stats(self):
+            return {"bytes_in_use": 5, "peak_bytes_in_use": 7, "peak_bytes_reserved": 100}
+
+    monkeypatch.setattr(jax, "local_devices", lambda: [Chip()])
+    (row,) = device_peak_memory()["per_device"]
+    assert (row["peak_bytes_in_use"], row["peak_bytes_reserved"], row["peak_bytes"]) == (7, 100, 107)
+
+
+# ------------------------------------------------ the spans of a traced run
+
+
+def _tiny_run(tmp_path, trace, **kw):
+    from dynamic_load_balance_distributeddnn_tpu.config import Config
+    from dynamic_load_balance_distributeddnn_tpu.data.datasets import synthetic_dataset
+    from dynamic_load_balance_distributeddnn_tpu.train import Trainer
+
+    cfg = Config(debug=True, world_size=4, batch_size=128, learning_rate=0.05, epoch_size=2,
+                 dataset="mnist", model="mnistnet", dynamic_batch_size=True, seed=11, bucket=8,
+                 device=0, trace=trace, trace_dir=str(tmp_path / "traces"),
+                 stat_dir=str(tmp_path / "statis"), **kw)
+    tr = Trainer(cfg, bundle=synthetic_dataset("mnist", n_train=512, n_test=128),
+                 log_to_file=False)
+    for epoch in range(2):
+        tr.run_epoch(epoch)
+    tr._aot.close(False)
+    return tr
+
+
+@pytest.mark.parametrize("path,kw", [("packed", {}), ("elastic:scan", {"packed": "off"})])
+def test_a_traced_run_names_the_hosts_waits(tmp_path, path, kw):
+    import gc
+
+    import dynamic_load_balance_distributeddnn_tpu.obs.trace as trace_mod
+
+    try:
+        tr = _tiny_run(tmp_path, "on", **kw)
+        assert trace_mod._gc_hook in gc.callbacks
+        assert tr.recorder.meta["exec_path"] == [path, path]
+        events = get_tracer().chrome_events()
+    finally:
+        configure("off")
+    assert trace_mod._gc_hook not in gc.callbacks
+    done = [e for e in events if e["ph"] == "X"]
+    cats = {e["name"]: e["cat"] for e in done}
+    for name, cat in [("device_wait", "wait"), ("input_wait", "transfer"), ("gc", "host"),
+                      ("jax_trace", "compile"), ("jax_lower", "compile"),
+                      ("backend_compile", "compile"), ("trainer_init", "setup"),
+                      ("setup_model", "setup")]:
+        assert cats.get(name) == cat, (name, sorted(cats))
+    epochs = [e for e in done if e["cat"] == EPOCH_CAT]
+    waits = [e for e in done if e["name"] == "device_wait"]
+    assert len(epochs) == 2 and len(waits) >= 4  # train and validate, both epochs
+    for w in waits:  # the controller thread waits inside an epoch, never between two
+        assert any(e["tid"] == w["tid"] and e["ts"] <= w["ts"]
+                   and w["ts"] + w["dur"] <= e["ts"] + e["dur"] + 1 for e in epochs), w
+    # attribution still tiles an epoch by its phase spans alone: the new
+    # categories nest inside phases and must not enter its sums
+    whole = attribution(events)
+    phases_only = attribution([e for e in events if e.get("cat") in (EPOCH_CAT, "phase")])
+    assert whole == phases_only and whole["coverage_min"] >= 0.95
+
+
+def test_with_the_tracer_off_nothing_is_hooked_parsed_or_written(tmp_path):
+    import gc
+
+    import dynamic_load_balance_distributeddnn_tpu.obs.trace as trace_mod
+    from dynamic_load_balance_distributeddnn_tpu.obs import scopes
+
+    class NeverRead:
+        def as_text(self):
+            raise AssertionError("the tracer is off: no text is parsed")
+
+    tr = _tiny_run(tmp_path, "off")
+    assert not tr._trace.enabled and tr._trace.trace_dir is None
+    # (a pool thread of an earlier traced test may still close an `aot_lower`
+    # span into this buffer: look for this run's own call sites)
+    mine = {"device_wait", "input_wait", "gc", "jax_trace", "jax_lower", "backend_compile",
+            "cache_read", "trainer_init", "setup_model", "epoch", "train"}
+    assert not mine & {e[0] for e in tr._trace.events()}
+    assert not tr._aot.has(("fused_eval_step", 0, 128, 28, 28, 1))
+    assert trace_mod._gc_hook not in gc.callbacks
+    scopes.record_program(("k",), NeverRead())
+    assert not (tmp_path / "traces").exists()

@@ -4,13 +4,14 @@
 the plain reference put in the program's place at the cell's own size.
 
     python3 benchmark/control.py --workload <name> --seeds 11,12,13 \
-        [--variants fp8,half_batch,state_unchanged] [--rehearsal]
+        [--variants fp8,half_batch,state_unchanged] [--rehearsal] [--manifest <path>]
 
 For each seed it follows the cell's first epoch with the reference, then with
 each variant, and prints the numbers ``run.py`` compares, one JSON line per
 seed and variant. ``fp8``: the same equations with every convolution's and
-matrix product's inputs rounded to float8. ``half_batch`` and
-``state_unchanged``: see ``reference/common.py``. A variant has to come out
+matrix product's inputs rounded to float8. ``half_batch``,
+``state_unchanged`` and a task's further faults (``no_clip`` for tokens): see
+the task's ``train_epoch`` (``tasks/<task>.py``). A variant has to come out
 as not correct under the cell's limits. Not run by the benchmark's own runs.
 """
 
@@ -25,26 +26,26 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def readings(workload: str, seed: int, variants, rehearsal: bool = False, block_rows: int = 512):
+def readings(workload: str, seed: int, variants, rehearsal: bool = False, manifest_path=None):
     """``{variant: compared numbers}`` for one seed, and the cell's verdict on each."""
     import jax
 
-    from benchmark import harness
+    from benchmark import harness, tasks
     from benchmark.reference import common as reference
 
-    spec = harness.load_cell(workload)
+    spec = harness.load_cell(workload, manifest_path=manifest_path)
     config, traffic = spec["config"], spec["traffic"]
+    task = tasks.load(config)
     argv = harness.job_argv(config, traffic, rehearsal)
-    sizes = harness.job_sizes(argv)
+    sizes = task.job_sizes(argv)
     model = config["rehearsal_model" if rehearsal else "model"]
-    rows = harness.make_rows(seed, sizes["n_train"], 1, model["image"], model["num_classes"])
+    rows = task.make_rows(seed, sizes, 1, model)
     shapes = reference.family(model).param_shapes(model)
-    params0 = jax.device_get(harness.make_weights(shapes, None, seed))
-    job = harness.job_definition(config, traffic, sizes, seed % harness.JOB_SEED_MOD)
+    params0 = jax.device_get(harness.make_weights(shapes, None, seed, reference.init_std(model)))
+    job = harness.job_definition(config, traffic, sizes, seed % harness.JOB_SEED_MOD, task)
 
     def follow(**kw):
-        return reference.train_epoch(params0, rows["train_x"], rows["train_y"], model, job,
-                                     block_rows=block_rows, **kw)
+        return task.train_epoch(params0, rows, model, job, **kw)
 
     ref = follow()
     out = {}
@@ -62,11 +63,12 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--variants", default="fp8,half_batch,state_unchanged")
     ap.add_argument("--rehearsal", action="store_true")
+    ap.add_argument("--manifest", default=None)
     args = ap.parse_args(argv)
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()
         for variant, r in readings(args.workload, seed, args.variants.split(","),
-                                   args.rehearsal).items():
+                                   args.rehearsal, manifest_path=args.manifest).items():
             print(json.dumps({"workload": args.workload, "seed": seed, "variant": variant,
                               "correct": r["correct"], **r["readings"]}), flush=True)
         print(json.dumps({"seed": seed, "seconds": time.perf_counter() - t0}), flush=True)

@@ -1,6 +1,8 @@
 """The benchmark's own machinery: the manifest and the data files a cell is
-made of, rows and weights from the seed, compile and cache counters, the
-table of peaks, the per-layer readers, and the decision on ``correct``.
+made of, weights from the seed, compile and cache counters, the table of
+peaks, the per-layer readers, and the decision on ``correct``. What a row is
+(rows from the seed, sizes, samples, the plan's checks, the job's recipe)
+belongs to the configuration's task, ``benchmark/tasks/<task>.py``.
 
 Everything that belongs to one configuration, one traffic mix, one cell or
 one per-layer metric sits in a file of its own and is found by the name
@@ -14,7 +16,7 @@ import json
 import math
 import os
 import threading
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -38,19 +40,36 @@ def load_manifest(path: str = MANIFEST) -> dict:
     return _load_json(path)
 
 
-def load_cell(workload: str, manifest: Optional[dict] = None) -> dict:
+def data_roots(manifest_path: Optional[str] = None) -> List[str]:
+    """Where a cell's data files are looked for. A manifest other than the
+    repository's (``tests/benchmark`` keeps one for its fixture cell) brings
+    its own directory first: its ``traffic/``, ``limits/`` and
+    ``layer_metrics/`` are found before this one's."""
+    own = os.path.dirname(os.path.abspath(manifest_path or MANIFEST))
+    return [HERE] if own == ROOT else [own, HERE]
+
+
+def find_file(roots: Sequence[str], *parts: str) -> str:
+    """The first root that holds ``parts``; the last root's path where none does."""
+    paths = [os.path.join(root, *parts) for root in roots]
+    return next((p for p in paths if os.path.isfile(p)), paths[-1])
+
+
+def load_cell(workload: str, manifest: Optional[dict] = None,
+              manifest_path: Optional[str] = None) -> dict:
     """The cell's entry with its configuration, traffic mix and limits."""
-    manifest = manifest or load_manifest()
+    manifest = manifest or load_manifest(manifest_path or MANIFEST)
+    roots = data_roots(manifest_path)
     cells = {w["name"]: w for w in manifest["workloads"]}
     if workload not in cells:
-        raise SystemExit(f"unknown workload {workload!r}; BENCHMARK.json has {sorted(cells)}")
+        raise SystemExit(f"unknown workload {workload!r}; the manifest has {sorted(cells)}")
     cell = cells[workload]
     cfg_entry = {c["name"]: c for c in manifest["configs"]}[cell["config"]]
     config = _load_json(os.path.join(ROOT, cfg_entry["file"]))
-    traffic = _load_json(os.path.join(HERE, "traffic", cell["traffic"] + ".json"))
-    limits = _load_json(os.path.join(HERE, "limits", workload + ".json"))
+    traffic = _load_json(find_file(roots, "traffic", cell["traffic"] + ".json"))
+    limits = _load_json(find_file(roots, "limits", workload + ".json"))
     return {"cell": cell, "config": config, "traffic": traffic, "limits": limits,
-            "manifest": manifest}
+            "manifest": manifest, "roots": roots}
 
 
 def cell_metrics(manifest: dict, workload: str, kind: str) -> List[dict]:
@@ -82,51 +101,21 @@ def job_argv(config: dict, traffic: dict, rehearsal: bool) -> List[str]:
     return list(config["rehearsal_argv" if rehearsal else "argv"]) + list(traffic["argv"])
 
 
-def job_sizes(argv: List[str]) -> dict:
-    """Batch and rows an epoch, read back from the argv the job is run with."""
-    def after(flag):
-        return argv[argv.index(flag) + 1]
-
-    return {"batch": int(after("-b")), "n_train": int(after("--n_train")),
-            "bucket": int(after("--bucket"))}
-
-
-def job_definition(config: dict, traffic: dict, sizes: dict, job_seed: int) -> dict:
-    """What the reference needs to know of the job to follow its first epoch."""
+def job_definition(config: dict, traffic: dict, sizes: dict, job_seed: int, task) -> dict:
+    """What the reference needs to know of the job to follow its first epoch:
+    the mix's workers, the seed, the learning rate, and the task's own keys."""
     if not traffic["one_chip"]:
         raise SystemExit(f"traffic mix {traffic['name']!r} spreads its workers over chips: the "
                          "reference draws the rows of workers that share one chip, and the "
                          "PR that brings such a mix brings the other draw")
-    return {"n_train": sizes["n_train"], "world_size": traffic["world_size"],
-            "batch": sizes["batch"], "seed": job_seed, "epoch": 0, "lr": config["lr"],
-            "dataset": config["dataset"]}
+    return {"world_size": traffic["world_size"], "seed": job_seed, "epoch": 0,
+            "lr": config["lr"], **task.job_keys(config, sizes)}
 
 
 # ------------------------------------------------- inputs from the seed
 
 
-def make_rows(seed: int, n_train: int, n_test: int, image, num_classes: int) -> dict:
-    """CIFAR-shaped rows that all differ, with a label a model can learn
-    (the top-left patch carries the class), drawn in bulk from the seed."""
-    import numpy as np
-
-    rng = np.random.default_rng([int(seed), 0xDA7A])
-    h, w, c = image
-
-    def gen(n):
-        x = rng.integers(0, 256, size=(n, h, w, c), dtype=np.uint8)
-        y = rng.integers(0, num_classes, size=n).astype(np.int32)
-        step = 255 // num_classes
-        x[:, : h // 4, : w // 4, :] = (y * step + step // 2).astype(np.uint8)[:, None, None, None]
-        return x, y
-
-    train_x, train_y = gen(n_train)
-    test_x, test_y = gen(n_test)
-    return {"train_x": train_x, "train_y": train_y, "test_x": test_x, "test_y": test_y,
-            "num_classes": num_classes}
-
-
-def make_weights(shapes, shardings, seed: int):
+def make_weights(shapes, shardings, seed: int, init_std=None):
     """Every parameter drawn on the device in one jitted call from the seed,
     in the float32 the program keeps them in: He-normal convolution kernels,
     the classifier's kernel at a tenth of that (logits start near nought and
@@ -134,7 +123,11 @@ def make_weights(shapes, shardings, seed: int):
     loss of 4.4 the first steps are violent enough to carry a rounding-sized
     difference 25 times farther on one seed than on the next, PERF.md PR 23),
     scales around 1 and biases around 0 (not exactly 1 and 0, so that every
-    leaf's gradient is exercised)."""
+    leaf's gradient is exercised).
+
+    ``init_std(path, shape)`` is a family's own rule (``reference/<family>.py``):
+    the standard deviation of a leaf's draw (around 1 for a scale, around 0
+    for any other leaf), or ``None`` for a leaf that keeps the rule above."""
     import jax
     import jax.numpy as jnp
 
@@ -149,7 +142,10 @@ def make_weights(shapes, shardings, seed: int):
             kind = jax.tree_util.keystr(path)
             noise = flat_noise[lo:lo + size].reshape(s.shape)
             lo += size
-            if "kernel" in kind:
+            std = init_std(kind, s.shape) if init_std else None
+            if std is not None:
+                leaves.append((1.0 if "scale" in kind else 0.0) + std * noise)
+            elif "kernel" in kind:
                 fan_in = max(math.prod(s.shape[:-1]), 1)
                 gain = CLASSIFIER_GAIN if len(s.shape) == 2 else 1.0
                 leaves.append(noise * gain * math.sqrt(2.0 / fan_in))
@@ -202,10 +198,10 @@ class Counters:
 # ------------------------------------------------------- per-layer readers
 
 
-def read_layer_metric(name: str, ctx: dict) -> Optional[float]:
-    """Run ``benchmark/layer_metrics/<name>.py``'s ``read(ctx)``. A reader
-    that finds nothing to read returns ``None`` and the metric is left out."""
-    path = os.path.join(HERE, "layer_metrics", name + ".py")
+def read_layer_metric(name: str, ctx: dict, roots: Sequence[str] = (HERE,)) -> Optional[float]:
+    """Run ``layer_metrics/<name>.py``'s ``read(ctx)``. A reader that finds
+    nothing to read returns ``None`` and the metric is left out."""
+    path = find_file(roots, "layer_metrics", name + ".py")
     spec = importlib.util.spec_from_file_location("layer_metric_" + name.replace(".", "_"), path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
@@ -217,31 +213,6 @@ def window_spans(ctx: dict, *names: str):
     """The graftscope spans of the given names that lie inside the window."""
     t0, t1 = ctx["window"]["t0"], ctx["window"]["t1"]
     return [s for s in ctx["spans"] if s[0] in names and s[2] >= t0 and s[2] + s[3] <= t1 + 1e-3]
-
-
-# ------------------------------------------------------------ the plan
-
-
-def plan_batches(shares: List[float], batch: int) -> List[int]:
-    return [int(round(s * batch)) for s in shares]
-
-
-def epoch_samples(shares: List[float], n_train: int) -> int:
-    """Rows an epoch trains on: each worker owns ``int(share * n)`` rows of
-    the fixed permutation and visits each once."""
-    return int(sum(int(s * n_train) for s in shares))
-
-
-def plan_errors(epochs: List[dict], sizes: dict) -> Dict[str, float]:
-    """The two exact checks on every epoch of the window: the plan's widths
-    sum to the global batch, and the epoch runs ``n_train / batch`` steps
-    (every row placed once). An epoch that recorded no plan fails both."""
-    want_steps = sizes["n_train"] // sizes["batch"]
-    sums = [abs(sum(e["batches"]) - sizes["batch"]) if e.get("batches") else sizes["batch"]
-            for e in epochs]
-    steps = [abs(e["steps"] - want_steps) if e.get("batches") else want_steps for e in epochs]
-    return {"plan_sum_err": float(max(sums, default=sizes["batch"])),
-            "steps_err": float(max(steps, default=want_steps))}
 
 
 # ------------------------------------------------------------- correct

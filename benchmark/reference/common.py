@@ -3,12 +3,18 @@ SGD-with-momentum update in straightforward ``jax.numpy``, float32, matrix
 products at ``highest`` precision, no kernels, no scan, no sharding.
 
 It imports nothing of the program. What it shares with the program is the
-*recipe* a training job is defined by, copied here so that no later PR can
-move it: which rows a step trains on (the fixed-seed partition and the
-per-epoch visit order), the random crop and flip each row gets, the loss,
-and the optimizer. A family's layer equations live beside this file
-(``densenet.py``, ``resnet.py``) and are found by the ``family`` key of a
-configuration's ``model`` group.
+*recipe* a training job is defined by, copied under ``benchmark/`` so that
+no later PR can move it. The part of the recipe that knows what a row is
+(which rows or tokens a step trains on, their weights, a per-worker clip)
+lives with its task, ``benchmark/tasks/<task>.py``; here are the numerics
+every task shares, the images' crop with its key (``tests/test_augment.py``
+holds the program's crop to them), the optimizer and the comparison. A
+family's layer equations live beside this file (``densenet.py``,
+``resnet.py``, ``transformer.py``) and are found by the ``family`` key of a
+configuration's ``model`` group. A family may also bring two hooks:
+``loss(params, x, y, weights, model, precision)`` (a token family whose
+training loss has terms beside the next-token loss) and
+``init_std(path, shape)`` (the draw of its leaves, ``harness.make_weights``).
 
 ``precision`` selects what the same equations are computed in:
 
@@ -77,6 +83,13 @@ def dense(x, p, precision="f32"):
     return jnp.dot(x, k, precision=HIGHEST) + p["bias"]
 
 
+def einsum(spec, a, b, precision="f32"):
+    """A product of two tensors (a projection, attention's scores), its
+    inputs rounded as ``precision`` says."""
+    a, b = _operands(precision, a, b)
+    return jnp.einsum(spec, a, b, precision=HIGHEST)
+
+
 def group_norm(x, p, relu=False, groups=32):
     """GroupNorm over (H, W, C/G) per sample and group, then scale and bias.
     The group count is ``gcd(32, C)``, as the program's models choose it."""
@@ -96,9 +109,10 @@ def avg_pool(x, k):
 
 
 def cross_entropy(logits, labels):
+    """One loss per label: ``logits`` ``[..., classes]``, ``labels`` ``[...]``."""
     logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[:, None].astype(jnp.int32), axis=-1)
-    return logz - gold[:, 0]
+    gold = jnp.take_along_axis(logits, labels[..., None].astype(jnp.int32), axis=-1)
+    return logz - gold[..., 0]
 
 
 def family(model: dict):
@@ -107,30 +121,12 @@ def family(model: dict):
     return importlib.import_module(f"{__package__}.{model['family']}")
 
 
-# ------------------------------------------------------- the job's recipe
+def init_std(model: dict):
+    """The family's ``init_std(path, shape)`` hook, or ``None``."""
+    return getattr(family(model), "init_std", None)
 
 
-def epoch_rows(n_train: int, world_size: int, batch: int, seed: int, epoch: int):
-    """Rows of every step of an epoch under the even split:
-    ``rows[step][worker]`` is an index vector of ``batch / world_size`` rows.
-
-    A fixed-seed permutation of the rows is cut into one contiguous shard per
-    worker; each epoch visits a shard in an order drawn from (seed, epoch,
-    worker); a step takes the next ``batch / world_size`` rows of every
-    shard. (The job definition of the paper's ``dataloader.py``, as the
-    program implements it in ``data/partitioner.py``.)"""
-    per = batch // world_size
-    order = np.random.RandomState(seed).permutation(n_train)
-    shard = int(n_train / world_size)
-    steps = -(-shard // per)
-    visits = []
-    for r in range(world_size):
-        owned = order[r * shard:(r + 1) * shard]
-        visit = np.random.RandomState(
-            (seed * 1000003 + epoch * 9176 + r) % (2**32)
-        ).permutation(len(owned))
-        visits.append(owned[visit])
-    return [[v[s * per:(s + 1) * per] for v in visits] for s in range(steps)]
+# ------------------------------------------- the images' crop and its key
 
 
 def augment(x_u8, key, mean, std, pad=4):
@@ -158,88 +154,24 @@ def step_key(job_seed: int, epoch: int, step: int):
     return jax.random.fold_in(jax.random.fold_in(base, 0), jnp.int32(step))
 
 
-# ------------------------------------------------------------ one epoch
+# ------------------------------------------------------------ optimizer
 
 
-def _block_fn(model: dict, precision: str):
-    """Gradient of ``weight`` x the summed loss of a block of rows (the weight
-    is an argument, so every batch size and fault shares one program)."""
-    fwd = family(model).forward
-
-    def loss_sum(params, x, y, weight):
-        losses = cross_entropy(fwd(params, x, model, precision), y)
-        return jnp.sum(losses) * weight, jnp.sum(losses)
-
-    return jax.jit(jax.value_and_grad(loss_sum, has_aux=True))
-
-
-def train_epoch(
-    params,
-    train_x: np.ndarray,
-    train_y: np.ndarray,
-    model: dict,
-    job: dict,
-    *,
-    precision: str = "f32",
-    fault: str = "",
-    block_rows: int = 512,
-    device=None,
-):
-    """Follow the job's first epoch from ``params`` (a host tree) and return
-    what is compared: the mean loss of the epoch's rows, the first step's
-    gradient, the momentum and the parameters after the last step.
-
-    ``job``: ``n_train, world_size, batch, seed, epoch, lr, dataset``; the
-    workers share one chip, so all rows of a step are drawn together.
-
-    ``fault`` plants, in this reference, a fault a program could have:
-    ``"half_batch"`` leaves out every second row of each step and takes the
-    mean over the rest; ``"state_unchanged"`` computes every step and throws
-    its update away."""
-    device = device or jax.devices()[0]
-    mean, std = NORM_STATS[job["dataset"]]
-    batch, ws = int(job["batch"]), int(job["world_size"])
-    put = lambda a: jax.device_put(a, device)  # noqa: E731
-    params = jax.tree_util.tree_map(lambda a: put(np.asarray(a, np.float32)), params)
-    trace = jax.tree_util.tree_map(jnp.zeros_like, params)
-    aug = jax.jit(lambda x, k: augment(x, k, mean, std))
-    if fault not in ("", "half_batch", "state_unchanged"):
-        raise ValueError(f"unknown fault {fault!r}")
-    weight = jnp.float32(1.0 / (batch // 2 if fault == "half_batch" else batch))
-    grad_fn = _block_fn(model, precision)
-    sgd = jax.jit(
-        lambda p, t, g, lr: (
-            jax.tree_util.tree_map(lambda t_, g_: g_ + MOMENTUM * t_, t, g),
-            jax.tree_util.tree_map(
-                lambda p_, t_, g_: p_ - lr * (g_ + MOMENTUM * t_), p, t, g
-            ),
-        )
+@jax.jit
+def sgd_step(params, trace, grads, lr):
+    """SGD with momentum as ``optax.sgd`` does it: ``(trace, params)`` after
+    one update."""
+    return (
+        jax.tree_util.tree_map(lambda t_, g_: g_ + MOMENTUM * t_, trace, grads),
+        jax.tree_util.tree_map(
+            lambda p_, t_, g_: p_ - lr * (g_ + MOMENTUM * t_), params, trace, grads
+        ),
     )
-    add = jax.jit(lambda a, b: jax.tree_util.tree_map(jnp.add, a, b))
-    loss_total, rows_total, first_grad = 0.0, 0, None
-    steps = epoch_rows(job["n_train"], ws, batch, job["seed"], job["epoch"])
-    for s, by_worker in enumerate(steps):
-        rows = np.concatenate(by_worker)
-        x = aug(put(train_x[rows]), step_key(job["seed"], job["epoch"], s))
-        y = put(train_y[rows].astype(np.int32))
-        if fault == "half_batch":
-            x, y = x[::2], y[::2]
-        grads = None
-        for lo in range(0, x.shape[0], block_rows):
-            (_, lsum), g = grad_fn(params, x[lo:lo + block_rows], y[lo:lo + block_rows], weight)
-            grads = g if grads is None else add(grads, g)
-            loss_total += float(lsum)
-            rows_total += int(min(block_rows, x.shape[0] - lo))
-        if first_grad is None:
-            first_grad = jax.device_get(grads)
-        if fault != "state_unchanged":
-            trace, params = sgd(params, trace, grads, jnp.float32(job["lr"]))
-    return {
-        "loss": loss_total / max(rows_total, 1),
-        "first_grad": first_grad,
-        "trace": jax.device_get(trace),
-        "params": jax.device_get(params),
-    }
+
+
+@jax.jit
+def tree_add(a, b):
+    return jax.tree_util.tree_map(jnp.add, a, b)
 
 
 # ------------------------------------------------------------ comparison

@@ -63,15 +63,16 @@ def parse(argv=None):
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearsal", action="store_true",
                     help="CPU tests only: the configuration's rehearsal_argv, any platform")
+    ap.add_argument("--manifest", default=None,
+                    help="tests only: a manifest other than BENCHMARK.json (its directory's "
+                         "traffic/, limits/ and layer_metrics/ are looked in first)")
     return ap.parse_args(argv)
 
 
-def _timed_epoch(job, epoch: int, steps_per_epoch: int, sizes: dict):
+def _timed_epoch(job, epoch: int, steps_per_epoch: int, sizes: dict, task):
     """One ``run_epoch`` on this clock, with what the program recorded for it.
     An epoch that raised, lost an AOT job or returned a loss that is not
     finite trained no samples and its steps are failed."""
-    from benchmark import harness
-
     t0 = time.perf_counter()
     try:
         out = job.run_epoch(epoch)
@@ -84,14 +85,14 @@ def _timed_epoch(job, epoch: int, steps_per_epoch: int, sizes: dict):
     return {
         "index": epoch, "t0": t0, "t1": time.perf_counter(), "steps": rec["steps"],
         "failed_steps": 0 if ok else rec["steps"],
-        "samples": harness.epoch_samples(rec["shares"], sizes["n_train"]) if ok else 0,
-        "batches": harness.plan_batches(rec["shares"], sizes["batch"]),
+        "samples": task.epoch_samples(rec["shares"], sizes) if ok else 0,
+        "batches": task.plan_batches(rec["shares"], sizes), "shares": rec["shares"],
         "loss": float(out["loss"]), "exec_path": rec["exec_path"],
     }
 
 
 def measure_window(job, first_epoch: int, seconds: float, steps_per_epoch: int,
-                   sizes: dict, counters):
+                   sizes: dict, task, counters):
     """Whole epochs from ``first_epoch`` until ``seconds`` have passed, on
     this clock, ending with the state ready: the same window in a traced run
     as in a plain one."""
@@ -99,7 +100,7 @@ def measure_window(job, first_epoch: int, seconds: float, steps_per_epoch: int,
     c0 = counters.snapshot()["compiles"]
     t_start = time.perf_counter()
     while True:
-        epochs.append(_timed_epoch(job, first_epoch + len(epochs), steps_per_epoch, sizes))
+        epochs.append(_timed_epoch(job, first_epoch + len(epochs), steps_per_epoch, sizes, task))
         if epochs[-1].get("raised") or epochs[-1]["t1"] - t_start >= seconds:
             break
     job.block()
@@ -114,7 +115,7 @@ def measure_window(job, first_epoch: int, seconds: float, steps_per_epoch: int,
     }, epochs
 
 
-def profiled_epoch(job, epoch: int, steps_per_epoch: int, sizes: dict, profile_dir: str):
+def profiled_epoch(job, epoch: int, steps_per_epoch: int, sizes: dict, task, profile_dir: str):
     """One more epoch, after the window has closed, under ``jax.profiler``:
     the device's side of a traced run. It follows the window so that starting
     and stopping the profiler (seconds, and gigabytes of host memory) cost
@@ -127,7 +128,7 @@ def profiled_epoch(job, epoch: int, steps_per_epoch: int, sizes: dict, profile_d
     opts.python_tracer_level = 0
     jax.profiler.start_trace(profile_dir, profiler_options=opts)
     try:
-        e = _timed_epoch(job, epoch, steps_per_epoch, sizes)
+        e = _timed_epoch(job, epoch, steps_per_epoch, sizes, task)
         job.block()
     finally:
         jax.profiler.stop_trace()
@@ -138,10 +139,11 @@ def main(argv=None) -> int:
     args = parse(argv)
     capped = os.environ.pop(CACHE_CAP_ENV, None)  # see PERF.md: a capped cache misses everything
 
-    from benchmark import harness
+    from benchmark import harness, tasks
 
-    spec = harness.load_cell(args.workload)
+    spec = harness.load_cell(args.workload, manifest_path=args.manifest)
     cell, config, traffic = spec["cell"], spec["config"], spec["traffic"]
+    task = tasks.load(config)
 
     import jax
 
@@ -161,23 +163,22 @@ def main(argv=None) -> int:
     t_imports = time.perf_counter()
 
     argv_job = harness.job_argv(config, traffic, args.rehearsal)
-    sizes = harness.job_sizes(argv_job)
+    sizes = task.job_sizes(argv_job)
     model = config["rehearsal_model" if args.rehearsal else "model"]
     n_test = config["rehearsal_n_test" if args.rehearsal else "n_test"]
     job_seed = args.seed % harness.JOB_SEED_MOD
     out_dir = os.path.join(_HERE, "out", f"{args.workload}.s{args.seed}.t{args.trace}")
     shutil.rmtree(out_dir, ignore_errors=True)
     os.makedirs(out_dir)
-    rows = harness.make_rows(args.seed, sizes["n_train"], n_test, model["image"],
-                             model["num_classes"])
+    rows = task.make_rows(args.seed, sizes, n_test, model)
     t_rows = time.perf_counter()
 
     from benchmark.sut import Job
 
-    job = Job(argv_job, rows, reference.NORM_STATS[config["dataset"]], out_dir, job_seed,
+    job = Job(argv_job, lambda cfg: task.bundle(rows, config, cfg), out_dir, job_seed,
               trace=bool(args.trace))
     shapes, shardings = job.param_shapes()
-    weights = harness.make_weights(shapes, shardings, args.seed)
+    weights = harness.make_weights(shapes, shardings, args.seed, reference.init_std(model))
     params0 = jax.device_get(weights)
     job.set_weights(weights)
     del weights
@@ -188,14 +189,14 @@ def main(argv=None) -> int:
     first = job.run_epoch(0)
     got = {"loss": float(first["loss"]), **job.snapshot()}
     t_first = time.perf_counter()
-    plans = [harness.plan_batches(job.epoch_record()["shares"], sizes["batch"])]
+    plans = [task.plan_batches(job.epoch_record()["shares"], sizes)]
     steps_per_epoch = job.epoch_record()["steps"]
     warm = traffic.get("rehearsal_warmup" if args.rehearsal else "", traffic["warmup"])
     epoch = 1
     while epoch < warm["max_epochs"]:
         c0 = counters.snapshot()["compiles"]
         job.run_epoch(epoch)
-        plans.append(harness.plan_batches(job.epoch_record()["shares"], sizes["batch"]))
+        plans.append(task.plan_batches(job.epoch_record()["shares"], sizes))
         quiet = counters.snapshot()["compiles"] == c0
         epoch += 1
         if epoch >= warm["min_epochs"] and plans[-1] == plans[-2] and quiet:
@@ -212,12 +213,14 @@ def main(argv=None) -> int:
         "input_path": job.input_path(), "host_maxrss_gib": _maxrss_gib(),
     })
 
-    window, epochs = measure_window(job, epoch, args.seconds, steps_per_epoch, sizes, counters)
+    window, epochs = measure_window(job, epoch, args.seconds, steps_per_epoch, sizes, task,
+                                    counters)
     setup_s = window["t0"] - _T_PROCESS
     profile_dir = os.path.join(out_dir, "profile")
     traced = None
     if args.trace and not epochs[-1].get("raised"):
-        traced = profiled_epoch(job, epoch + len(epochs), steps_per_epoch, sizes, profile_dir)
+        traced = profiled_epoch(job, epoch + len(epochs), steps_per_epoch, sizes, task,
+                                profile_dir)
     peak_bytes = max(harness.peak_bytes(d.memory_stats()) for d in devices[: cell["chips"]])
     for e in epochs + ([traced] if traced else []):
         say(epoch={k: e[k] for k in e if k not in ("t0", "t1")}, seconds=e["t1"] - e["t0"],
@@ -245,19 +248,20 @@ def main(argv=None) -> int:
             ops, host, layout = trace_reduce.load_xplane(xplane)
             if args.rehearsal and not ops:
                 ops = trace_reduce.host_ops_as_device(xplane)
-            profile = trace_reduce.reduce_profile(ops, host, harness.PHASES)
+            profile = trace_reduce.reduce_profile(ops, host, harness.PHASES, unit=trace_reduce.NS)
             say(trace={"file_bytes": os.path.getsize(xplane), "layout": layout[:40]})
         if profile:
             device["busy_s"], device["window_s"] = profile["busy_s"], profile["window_s"]
             breakdown = {"device_ops": profile["device_ops"], "idle_gaps": profile["idle_gaps"]}
         ctx = {
             "cell": cell, "config": config, "traffic": traffic, "epochs": epochs,
-            "profiled_epoch": traced, "window": window, "spans": spans, "setup": setup_counts, "profile": profile,
+            "profiled_epoch": traced, "window": window, "spans": spans, "setup": setup_counts,
+            "profile": profile, "run_dir": out_dir,
             "peak_hbm_bytes": peak_bytes, "sizes": sizes, "model": model,
             "peak": None if args.rehearsal else harness.peak_for(dev0.device_kind),
         }
         for m in harness.cell_metrics(spec["manifest"], args.workload, "per_layer"):
-            value = harness.read_layer_metric(m["name"], ctx)
+            value = harness.read_layer_metric(m["name"], ctx, spec["roots"])
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": units[m["name"]]}
     else:
@@ -270,11 +274,11 @@ def main(argv=None) -> int:
     # the plain reference follows the same first epoch, once the window has
     # closed, the peak has been read and the program's state is gone
     t_ref = time.perf_counter()
-    ref = reference.train_epoch(params0, rows["train_x"], rows["train_y"], model,
-                                harness.job_definition(config, traffic, sizes, job_seed),
-                                device=dev0)
+    ref = task.train_epoch(params0, rows, model,
+                           harness.job_definition(config, traffic, sizes, job_seed, task),
+                           device=dev0)
     compared = reference.compare(got, ref, params0)
-    compared.update(harness.plan_errors(epochs, sizes))
+    compared.update(task.plan_errors(epochs, sizes))
     verdict = harness.decide(compared, spec["limits"])
     say(reference_s=time.perf_counter() - t_ref, readings=compared,
         host_maxrss_gib=_maxrss_gib(),

@@ -9,9 +9,9 @@ the profiler names an instruction and its module, so the two join without
 either side knowing the other's names for ``fusion.825``. What is computed
 here: self seconds by scope on the busiest device (an event outside the map
 counts as ``unscoped``), and the device's idle time outside the host's probe
-spans. ``run.py`` hands the readers no path, so the run's directory is found
-by the cell's name under ``benchmark/out`` (the newest traced one); it is
-deleted only after the readers have run. A program that writes no map (the
+spans. ``run.py`` hands every reader its run directory (``ctx["run_dir"]``,
+deleted only after the readers have run), where the profile and the
+program's trace directory with the map lie. A program that writes no map (the
 parent of the PR that brought this file) gives no scope shares, and the
 readers return nothing.
 
@@ -21,14 +21,12 @@ The arithmetic is checked on hand-made events in ``tests/benchmark``.
 from __future__ import annotations
 
 import bisect
-import glob
 import json
 import os
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from benchmark import trace_reduce
 
-OUT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "out")
 MAP_FILE = "hlo_scopes.jsonl"
 UNSCOPED = "unscoped"
 PROBES = ("probe", "sync_probe")          # host spans that stand for the probes' own waits
@@ -38,7 +36,7 @@ WAITS = ("device_wait",) + PROBES         # the controller thread blocked on the
 # 7e-18 s inside it, is taken for its child, and the enclosing `while` is
 # counted twice (0.04-0.2 s of a profiled epoch, my chip runs, PR 24)
 OpEvent = Tuple[str, str, float, float]
-NS = 1e-9
+NS = trace_reduce.NS
 
 
 # ------------------------------------------------------------------ the map
@@ -180,13 +178,6 @@ def load_events(path: str):
     return devices, host_spans
 
 
-def run_dir(cell: str) -> Optional[str]:
-    """The newest traced run's directory of this cell under ``benchmark/out``."""
-    dirs = [d for d in glob.glob(os.path.join(OUT, glob.escape(cell) + ".s*.t1"))
-            if os.path.isdir(d)]
-    return max(dirs, key=os.path.getmtime) if dirs else None
-
-
 # ----------------------------------------------------------------- the table
 
 
@@ -222,7 +213,7 @@ def table(ctx: dict) -> Optional[dict]:
     ``None`` for a run with no profile."""
     if "scope_table" not in ctx:
         ctx["scope_table"] = None
-        where = run_dir(ctx["cell"]["name"]) if ctx.get("profile") else None
+        where = ctx.get("run_dir") if ctx.get("profile") else None
         xplane = trace_reduce.find_xplane(os.path.join(where, "profile")) if where else None
         if xplane:
             map_path = os.path.join(where, "traces", MAP_FILE)

@@ -1,29 +1,49 @@
 """The one place where the benchmark touches the system under test.
 
 It builds the trainer exactly as ``cli.run`` does (``config_from_args`` ->
-``enable_compile_cache`` -> ``Trainer(cfg)``), feeds it the benchmark's own
-rows and weights, calls ``run_epoch`` — the entry the measured window drives
-— and reads back what the program exposes: its state, its recorder's
-per-epoch series, its graftscope spans and its AOT service's failure count.
-Nothing here measures or decides anything.
+``enable_compile_cache`` -> the trainer class of the parsed ``cfg``), feeds
+it the benchmark's own rows and weights, calls ``run_epoch`` — the entry the
+measured window drives — and reads back what the program exposes: its state,
+its recorder's per-epoch series, its graftscope spans and its AOT service's
+failure count. Nothing here measures or decides anything, and nothing here
+knows what a row is: the task (``benchmark/tasks/``) makes the bundle.
 """
 
 from __future__ import annotations
 
 import gc
 import os
-from typing import List
+from typing import Callable, List
+
+
+def trainer_class(cfg):
+    """The class ``cli.run`` builds for this ``cfg``: its three-way choice,
+    copied (a benchmark PR edits no program file; ``tests/benchmark`` holds
+    the two together for every name in ``config.MODELS``). For a later PR to
+    replace by one function in ``cli.py`` that both call."""
+    if cfg.model == "transformer" and cfg.seq_parallel:
+        from dynamic_load_balance_distributeddnn_tpu.train.sp_engine import (
+            SeqParallelLMTrainer,
+        )
+
+        return SeqParallelLMTrainer
+    if cfg.model == "transformer":
+        from dynamic_load_balance_distributeddnn_tpu.train.lm_engine import LMTrainer
+
+        return LMTrainer
+    from dynamic_load_balance_distributeddnn_tpu.train.engine import Trainer
+
+    return Trainer
 
 
 class Job:
-    def __init__(self, argv: List[str], rows: dict, norm, out_dir: str, job_seed: int,
+    def __init__(self, argv: List[str], make_bundle: Callable, out_dir: str, job_seed: int,
                  trace: bool):
+        """``make_bundle(cfg)`` gives what the trainer is handed as its data."""
         from dynamic_load_balance_distributeddnn_tpu.compile_cache import (
             enable_compile_cache,
         )
         from dynamic_load_balance_distributeddnn_tpu.config import config_from_args
-        from dynamic_load_balance_distributeddnn_tpu.data.datasets import DatasetBundle
-        from dynamic_load_balance_distributeddnn_tpu.train.engine import Trainer
 
         argv = list(argv) + [
             "--seed", str(job_seed),
@@ -35,14 +55,7 @@ class Job:
                      "--trace_dir", os.path.join(out_dir, "traces")]
         self.cfg = config_from_args(argv)
         self.cache_dir = enable_compile_cache()
-        bundle = DatasetBundle(
-            name=self.cfg.dataset,
-            train_x=rows["train_x"], train_y=rows["train_y"],
-            test_x=rows["test_x"], test_y=rows["test_y"],
-            num_classes=int(rows["num_classes"]),
-            mean=tuple(norm[0]), std=tuple(norm[1]), synthetic=True,
-        )
-        self.trainer = Trainer(self.cfg, bundle=bundle)
+        self.trainer = trainer_class(self.cfg)(self.cfg, bundle=make_bundle(self.cfg))
 
     # ------------------------------------------------------------- state
 

@@ -1,11 +1,18 @@
 """Reduction of a profiler trace to the few numbers the benchmark reports.
 
-Pure functions over lists of ``(name, start_s, duration_s)`` — interval
-union for busy and idle time, self time by operation name, the longest gaps
-with the host phase that covers each, the share of collectives — and one
-loader that reads a ``.xplane.pb`` with nothing but JAX
+Pure functions over lists of ``(name, start, duration)`` — interval union
+for busy and idle time, self time by operation name, the longest gaps with
+the host phase that covers each, the share of collectives — and one loader
+that reads a ``.xplane.pb`` with nothing but JAX
 (``jax.profiler.ProfileData``). The arithmetic is checked on a hand-made
 event list in ``tests/benchmark``.
+
+Device events keep the profiler's whole nanoseconds until self time has been
+added up (``reduce_profile`` scales what it returns): scaled to seconds
+first, an event that starts where another ends comes out 7e-18 s inside it,
+is taken for its child, and the enclosing ``while`` is counted twice
+(PERF.md, PR 24 Findings 2). An epoch of a language-model job is one
+``while`` over its windows.
 """
 
 from __future__ import annotations
@@ -15,7 +22,8 @@ import os
 import re
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
-Event = Tuple[str, float, float]  # name, start (s), duration (s)
+Event = Tuple[str, float, float]  # name, start, duration (one unit: s, or whole ns)
+NS = 1e-9
 
 COLLECTIVE_MARKS = ("all-reduce", "reduce-scatter", "all-gather", "all-to-all",
                     "collective-permute")
@@ -105,26 +113,29 @@ def reduce_profile(
     device_ops: Dict[str, List[Event]],
     host_spans: List[Event],
     phase_names: Sequence[str],
+    unit: float = 1.0,
 ) -> Optional[dict]:
     """Per-device busy seconds and the window they are taken over, the
     operations with most self time on the busiest device, its five longest
     gaps with the phase that covers each, and its collective seconds.
-    ``None`` when no operation ran on any device."""
+    ``None`` when no operation ran on any device. ``device_ops`` are in
+    ``unit`` seconds (``NS`` as ``load_xplane`` gives them), ``host_spans``
+    in seconds; self time is added up before anything is scaled."""
     device_ops = {k: v for k, v in device_ops.items() if v}
     if not device_ops:
         return None
     t0 = min(span_of(v)[0] for v in device_ops.values())
     t1 = max(span_of(v)[1] for v in device_ops.values())
-    busy = {k: union_seconds(v) for k, v in device_ops.items()}
+    busy = {k: union_seconds(v) * unit for k, v in device_ops.items()}
     busiest = max(busy, key=busy.get)
-    by_name = self_seconds_by_name(device_ops[busiest])
+    by_name = {n: s * unit for n, s in self_seconds_by_name(device_ops[busiest]).items()}
     phases = [e for e in host_spans if e[0] in phase_names]
     idle = [
-        [cover(phases, s, length), length]
+        [cover(phases, s * unit, length * unit), length * unit]
         for s, length in gaps(device_ops[busiest], t0, t1)[:5]
     ]
     return {
-        "window_s": t1 - t0,
+        "window_s": (t1 - t0) * unit,
         "busy_s_by_device": busy,
         "busy_s": sum(busy.values()) / len(busy),
         "busiest": busiest,
@@ -151,8 +162,9 @@ def load_xplane(path: str, ops_line: str = "XLA Ops"):
     ``host_spans``: every event of the host planes' lines (the program's
     phases appear there when its spans are bridged to
     ``jax.profiler.TraceAnnotation``). ``layout``: plane and line names with
-    event counts, for a look by hand. Times are seconds on the trace's own
-    clock."""
+    event counts, for a look by hand. Device events are in the profiler's
+    whole nanoseconds (``reduce_profile(..., unit=NS)``), host spans in
+    seconds, both on the trace's own clock."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
@@ -164,14 +176,16 @@ def load_xplane(path: str, ops_line: str = "XLA Ops"):
         is_tpu = plane.name.startswith("/device:TPU:")
         is_host = plane.name.startswith("/host:")
         for line in plane.lines:
-            keep = device_ops.setdefault(plane.name, []) if is_tpu and line.name == ops_line \
+            on_device = is_tpu and line.name == ops_line
+            keep = device_ops.setdefault(plane.name, []) if on_device \
                 else host_spans if is_host else None
+            scale = 1 if on_device else NS
             count = 0
             for e in line.events:
                 count += 1
                 if keep is not None and e.duration_ns > 0:
-                    keep.append((names.setdefault(e.name, e.name), e.start_ns * 1e-9,
-                                 e.duration_ns * 1e-9))
+                    keep.append((names.setdefault(e.name, e.name), e.start_ns * scale,
+                                 e.duration_ns * scale))
             layout.append([plane.name, line.name, count])
     return device_ops, host_spans, layout
 
@@ -190,7 +204,7 @@ def host_ops_as_device(path: str) -> Dict[str, List[Event]]:
             if "XLA" not in line.name:
                 continue
             out["host-xla"].extend(
-                (e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9)
+                (e.name, e.start_ns, e.duration_ns)  # whole nanoseconds, as load_xplane's
                 for e in line.events
                 if e.duration_ns > 0
             )

@@ -139,7 +139,9 @@ def test_configuration_file(manifest, name):
     assert any(c["config"] == name for c in manifest["workloads"])  # used by some cell
     for key in ("model", "argv", "rehearsal_argv", "rehearsal_model", "assumed", "source"):
         assert key in config, key
-    sizes = harness.job_sizes(config["argv"])
+    from benchmark import tasks
+
+    sizes = tasks.load(config).job_sizes(config["argv"])
     assert sizes["n_train"] == config["n_train"]  # the key `reduced` names is the one that runs
     assert set(entry["reduced"]) <= set(config) and set(entry["reduced"]) == set(config["reduced_why"])
     assert sizes["batch"] % (4 * sizes["bucket"]) == 0 and sizes["n_train"] % sizes["batch"] == 0
@@ -171,3 +173,38 @@ def test_cell_files(manifest, name):
 def test_unknown_workload_is_refused():
     with pytest.raises(SystemExit):
         harness.load_cell("no.such_cell")
+
+
+FIXTURE = os.path.join(ROOT, "tests", "benchmark", "fixture")
+
+
+def test_a_manifest_of_its_own_brings_its_own_data_files_first(manifest):
+    """The fixture language-model cells are in no cell of ``BENCHMARK.json``:
+    their manifest names them, and its directory's ``traffic/`` and ``limits/``
+    are looked in before ``benchmark/``'s (readers fall through to it)."""
+    path = os.path.join(FIXTURE, "manifest.json")
+    assert harness.data_roots() == [harness.HERE]
+    assert harness.data_roots(path) == [FIXTURE, harness.HERE]
+    own = harness.load_manifest(path)
+    assert not {w["name"] for w in own["workloads"]} & {w["name"] for w in manifest["workloads"]}
+    assert not {c["name"] for c in own["configs"]} & {c["name"] for c in manifest["configs"]}
+    for cell in own["workloads"]:
+        spec = harness.load_cell(cell["name"], manifest_path=path)
+        assert spec["config"]["task"] == "tokens" and spec["traffic"]["world_size"] == 4
+        assert spec["limits"]["plan_sum_err"] == 0 and spec["roots"][0] == FIXTURE
+        config = spec["config"]
+        from benchmark import flops, tasks
+        from benchmark.reference import common
+        from dynamic_load_balance_distributeddnn_tpu.config import config_from_args
+
+        argv = harness.job_argv(config, spec["traffic"], rehearsal=False)
+        sizes = tasks.load(config).job_sizes(argv)
+        cfg = config_from_args(argv + ["--seed", "5"])  # the program accepts the job as written
+        assert cfg.model == "transformer" and cfg.bptt == sizes["bptt"] == config["model"]["seq_len"]
+        assert sizes["n_train"] == config["n_train"] and cfg.batch_size == sizes["batch"]
+        assert flops.train_flops_per_sample(config["model"]) > 0
+        assert hasattr(common.family(config["model"]), "forward")
+    for m in own["per_layer"]:  # every reader it names is found, in benchmark/layer_metrics
+        assert os.path.isfile(harness.find_file(spec["roots"], "layer_metrics", m["name"] + ".py"))
+    with pytest.raises(SystemExit):  # and the repository's manifest does not know the cell
+        harness.load_cell(own["workloads"][0]["name"])

@@ -1,6 +1,12 @@
 """The harness end to end on the CPU (``--rehearsal``: ResNet-18 in float32
-at batch 8), one run per cell; what it must refuse; and runs with the timed
-path broken underneath, each of which has to come out as not correct."""
+at batch 8), one run per cell, and one of the fixture language-model cell
+(``tests/benchmark/fixture``: the repo's own 2-layer LM through the ``tokens``
+task, found by its own manifest and in no cell of ``BENCHMARK.json``); what
+the harness must refuse; and runs with the timed path broken underneath, each
+of which has to come out as not correct.
+
+The fixture runs with ``LMTrainer.DROPOUT`` set to 0.0 by monkeypatch (a class
+constant of 0.2 in the program): a plain reference cannot follow its masks."""
 
 import json
 import os
@@ -17,25 +23,39 @@ if ROOT not in sys.path:
 from benchmark import harness, run  # noqa: E402
 
 CELLS = [w["name"] for w in harness.load_manifest()["workloads"]]
+LM_MANIFEST = os.path.join(ROOT, "tests", "benchmark", "fixture", "manifest.json")
+LM_CELL = "transformer_wikitext2.ws4_even_dbs"
+# every cell of BENCHMARK.json, and the fixture cell with the manifest that names it
+RUNS = [(c, None) for c in CELLS] + [(LM_CELL, LM_MANIFEST)]
 RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device"]
 
 
-def _run(capsys, workload, trace, seed=2**31 + 11):
+@pytest.fixture(autouse=True)
+def _no_dropout(monkeypatch):
+    from dynamic_load_balance_distributeddnn_tpu.train.lm_engine import LMTrainer
+
+    monkeypatch.setattr(LMTrainer, "DROPOUT", 0.0)
+
+
+def _run(capsys, workload, trace, seed=2**31 + 11, manifest=None):
     rc = run.main(["--workload", workload, "--seed", str(seed), "--seconds", "1",
-                   "--trace", str(trace), "--rehearsal"])
+                   "--trace", str(trace), "--rehearsal"]
+                  + (["--manifest", manifest] if manifest else []))
     lines = [ln for ln in capsys.readouterr().out.splitlines() if ln.startswith("{")]
     return rc, json.loads(lines[-1]), lines
 
 
-@pytest.mark.parametrize("workload", CELLS)
-def test_rehearsal_prints_the_contracts_line(capsys, workload):
-    manifest = harness.load_manifest()
-    rc, result, lines = _run(capsys, workload, trace=1)
+@pytest.mark.parametrize("workload,manifest_path", RUNS, ids=[r[0] for r in RUNS])
+def test_rehearsal_prints_the_contracts_line(capsys, workload, manifest_path):
+    manifest = harness.load_manifest(manifest_path or harness.MANIFEST)
+    rc, result, lines = _run(capsys, workload, trace=1, manifest=manifest_path)
     assert rc == 0
     keys = list(result)
     assert keys[:5] == RESULT_KEYS and keys[-1] == "compared"
     assert set(keys) <= set(RESULT_KEYS) | {"breakdown", "compared"}
-    assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 0
+    assert result["correct"] is True and result["failed"] == 0, (result["failed"],
+                                                                 result["compared"])
+    assert result["attempted"] > 0
     assert result["device"]["platform"] == "cpu"  # said as it is, never a device number
     assert result["device"]["busy_s"] > 0 and result["device"]["window_s"] > 0
     wanted = {m["name"] for m in harness.cell_metrics(manifest, workload, "per_layer")}
@@ -55,16 +75,36 @@ def test_rehearsal_prints_the_contracts_line(capsys, workload):
     assert result["attempted"] == sum(e["epoch"]["steps"] for e in epochs)
     assert not os.path.isdir(os.path.join(ROOT, "benchmark", "out",
                                           f"{workload}.s{2**31 + 11}.t1"))
+    if manifest_path:  # the token job: its own trainer, path and unit of work
+        assert {e["epoch"]["exec_path"] for e in epochs} == {"elastic:scan"}
+        assert setup["plans"][0] == [2, 2, 2, 2]  # epoch 0, the one compared, is even
+        # a sample is one window of --bptt target tokens in one column: 8 columns
+        # x 90 targets / 35 in an epoch under the even plan (the balancer is on,
+        # and on a loaded CPU its probes' noise may move two-column workers)
+        even = [e["epoch"] for e in epochs if e["epoch"]["batches"] == [2, 2, 2, 2]]
+        assert all(e["samples"] == pytest.approx(720 / 35) and e["steps"] == 3 for e in even)
+        for e in epochs:  # any plan: fewer targets than the stream has tokens, at most -b columns
+            assert 0 < e["epoch"]["samples"] < 728 / 35 and sum(e["epoch"]["batches"]) <= 8, e
 
 
-def test_untraced_line_reports_the_end_to_end_metrics(capsys):
-    manifest = harness.load_manifest()
-    rc, result, lines = _run(capsys, CELLS[0], trace=0, seed=7)
-    wanted = {m["name"] for m in harness.cell_metrics(manifest, CELLS[0], "end_to_end")}
+@pytest.mark.parametrize("workload,manifest_path", RUNS, ids=[r[0] for r in RUNS])
+def test_untraced_line_reports_the_end_to_end_metrics(capsys, workload, manifest_path):
+    manifest = harness.load_manifest(manifest_path or harness.MANIFEST)
+    rc, result, lines = _run(capsys, workload, trace=0, seed=7, manifest=manifest_path)
+    wanted = {m["name"] for m in harness.cell_metrics(manifest, workload, "end_to_end")}
     assert rc == 0 and set(result["metrics"]) == wanted and "breakdown" not in result
+    assert result["correct"] is True and result["failed"] == 0, (result["failed"],
+                                                                 result["compared"])
+    assert list(result)[:5] == RESULT_KEYS and list(result)[-1] == "compared"
     assert result["metrics"]["samples_per_s"]["value"] > 0
     assert "busy_s" not in result["device"]
     assert not any('"profiled": true' in ln for ln in lines)
+    if manifest_path:
+        window = [json.loads(ln) for ln in lines if ln.startswith('{"epoch"')]
+        spent = sum(e["seconds"] for e in window)
+        # samples/s = target tokens / bptt over the window's wall
+        assert result["metrics"]["samples_per_s"]["value"] == pytest.approx(
+            sum(e["epoch"]["samples"] for e in window) / spent, rel=0.2)
 
 
 def _cli(cwd, *extra):
@@ -124,16 +164,44 @@ def _half_batch(monkeypatch):
     monkeypatch.setattr(engine, "example_weights", example_weights)
 
 
+def _half_columns(monkeypatch):
+    """The token job's half batch: every second column of every worker left
+    out, the mean taken over the rest."""
+    from dynamic_load_balance_distributeddnn_tpu.train.lm_engine import LMTrainer
+
+    real = LMTrainer._build_windows
+
+    def build_windows(self, plan, rank, pad_to):
+        x, y, w = real(self, plan, rank, pad_to)
+        w = w.copy()
+        w[:, 1::2, :] = 0.0
+        return x, y, w * 2.0
+
+    monkeypatch.setattr(LMTrainer, "_build_windows", build_windows)
+
+
+def _no_clip(monkeypatch):
+    """The per-worker clip left out: the workers' gradients summed as they are."""
+    from dynamic_load_balance_distributeddnn_tpu.train.steps import StepLibrary
+
+    monkeypatch.setattr(StepLibrary, "_clip_local", lambda self, grads, w: grads)
+
+
 # the faults a one-chip training cell can have (no exchange between chips, no
-# token or answer): each planted in the program, under every cell
-FAULTS = [(f.__name__.strip("_"), f, c) for c in CELLS for f in (_state_unchanged, _half_batch)]
+# token or answer): each planted in the program, under every cell; the token
+# job has its own half batch (columns) and its per-worker clip to lose
+FAULTS = [(f.__name__.strip("_"), f, c, None) for c in CELLS
+          for f in (_state_unchanged, _half_batch)]
+FAULTS += [(f.__name__.strip("_"), f, LM_CELL, LM_MANIFEST)
+           for f in (_state_unchanged, _half_columns, _no_clip)]
 
 
-@pytest.mark.parametrize("fault,plant,workload", FAULTS, ids=[f"{f[0]}-{f[2]}" for f in FAULTS])
+@pytest.mark.parametrize("fault,plant,workload,manifest_path", FAULTS,
+                         ids=[f"{f[0]}-{f[2]}" for f in FAULTS])
 def test_a_broken_timed_path_comes_out_as_not_correct(capsys, monkeypatch, fault, plant,
-                                                      workload):
+                                                      workload, manifest_path):
     plant(monkeypatch)
-    rc, result, _ = _run(capsys, workload, trace=0, seed=99)
+    rc, result, _ = _run(capsys, workload, trace=0, seed=99, manifest=manifest_path)
     assert rc == 0 and result["correct"] is False, (fault, result["compared"])
     over = [k for k, row in result["compared"].items()
             if row["value"] is None or not row["value"] <= row["limit"]]

@@ -160,3 +160,13 @@ def test_table_of_a_run_with_no_profile_reads_no_file():
     ctx = _ctx()
     del ctx["scope_table"]
     assert scope_reduce.table(ctx) is None and ctx["scope_table"] is None
+
+
+def test_table_looks_in_the_run_directory_the_harness_hands_it(tmp_path):
+    """``run.py`` hands every reader ``ctx["run_dir"]``; the table is read
+    from the profile and the scope map under it, not found by the cell's name.
+    A directory with no profile in it gives no table."""
+    ctx = _ctx(profile={"busy_s": 1.0}, run_dir=str(tmp_path))
+    del ctx["scope_table"]
+    assert scope_reduce.table(ctx) is None and ctx["scope_table"] is None
+    assert not hasattr(scope_reduce, "run_dir")

@@ -62,6 +62,25 @@ def _run_already_done_global(cfg) -> bool:
     return skip
 
 
+def trainer_class(cfg):
+    """The trainer a parsed ``cfg`` is run by: the language-model trainers
+    under ``-m transformer`` (whatever ``--lm_arch`` names), ``Trainer`` for
+    every other model."""
+    if cfg.model == "transformer" and cfg.seq_parallel:
+        from dynamic_load_balance_distributeddnn_tpu.train.sp_engine import (
+            SeqParallelLMTrainer,
+        )
+
+        return SeqParallelLMTrainer
+    if cfg.model == "transformer":
+        from dynamic_load_balance_distributeddnn_tpu.train.lm_engine import LMTrainer
+
+        return LMTrainer
+    from dynamic_load_balance_distributeddnn_tpu.train.engine import Trainer
+
+    return Trainer
+
+
 def run(argv: Optional[Sequence[str]] = None):
     """Parse ``argv``, train, mark the run done. Returns the trainer — or
     None when the idempotence probe found the run already finished and
@@ -76,20 +95,7 @@ def run(argv: Optional[Sequence[str]] = None):
         print("===========================\n")
         return None
 
-    if cfg.model == "transformer" and cfg.seq_parallel:
-        from dynamic_load_balance_distributeddnn_tpu.train.sp_engine import (
-            SeqParallelLMTrainer,
-        )
-
-        trainer = SeqParallelLMTrainer(cfg)
-    elif cfg.model == "transformer":
-        from dynamic_load_balance_distributeddnn_tpu.train.lm_engine import LMTrainer
-
-        trainer = LMTrainer(cfg)
-    else:
-        from dynamic_load_balance_distributeddnn_tpu.train.engine import Trainer
-
-        trainer = Trainer(cfg)
+    trainer = trainer_class(cfg)(cfg)
     trainer.run()
     mark_run_done(cfg)
     return trainer

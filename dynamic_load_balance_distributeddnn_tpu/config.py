@@ -13,7 +13,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import os
-from typing import List, Optional, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 # Family-default names mirror the reference switch (dbs.py:345-362); explicit
 # variants expose the full Net/ constructor surface (e.g. ResNet-18 for
@@ -27,6 +27,9 @@ MODELS = [
     "transformer",
 ]
 DATASETS = ["cifar10", "cifar100", "mnist", "wikitext2"]
+# --lm_arch by name: "paper", or a published config kept as models/<name>.json.
+# Any other value is the path of such a file (tests bring theirs at test widths)
+LM_ARCHS = ["paper", "trinity_mini"]
 
 
 def str2bool(v) -> bool:
@@ -161,6 +164,19 @@ class Config:
     stat_dir: str = "./statis"
     ckpt_dir: str = ""                 # non-empty → orbax checkpointing on
     bptt: int = 35                     # LM window (dbs.py:343)
+    lm_arch: str = "paper"             # which language model -m transformer
+                                       # builds: "paper" (the reference's
+                                       # 2-layer, 200-wide post-norm LM), the
+                                       # name of a published config kept in
+                                       # models/ ("trinity_mini": AFMoE), or
+                                       # the path of such a .json file
+    lm_layers: str = ""                # published layers kept ("1,4,5,6,7");
+                                       # "" = all. Published architectures only
+    lm_experts_held: str = ""          # routed experts this chip holds, as
+                                       # "first:end"; "" = all. The router
+                                       # still ranges over every published one
+    lm_dropout: float = 0.2            # the paper LM's dropout (dbs.py:341);
+                                       # a published architecture has none
     seq_parallel: str = ""             # "ring" | "ulysses": train the LM with
                                        # the SEQUENCE axis sharded over the
                                        # mesh (long-context mode; bptt scales
@@ -351,6 +367,11 @@ class Config:
                                        # runtime (~2-4 s startup, paid once,
                                        # overlapped with the run's own
                                        # warm-up)
+    release_on_close: bool = False     # the AOT service, when closed, also
+                                       # clears JAX's in-memory caches, so
+                                       # that the device gives back what its
+                                       # programs had reserved: for a process
+                                       # that uses the chip after its trainer
     aot_speculate: bool = True         # when a rebalance dispatches a
                                        # ladder rung, background-compile the
                                        # ADJACENT rungs (±bucket) while the
@@ -569,6 +590,9 @@ class Config:
             raise ValueError(f"invalid model {self.model!r}; choose from {MODELS}")
         if self.dataset not in DATASETS:
             raise ValueError(f"invalid dataset {self.dataset!r}; choose from {DATASETS}")
+        if self.lm_arch not in LM_ARCHS and not self.lm_arch.endswith(".json"):
+            raise ValueError(f"invalid lm_arch {self.lm_arch!r}; choose from {LM_ARCHS} "
+                             "or give the path of a published config (.json)")
         if self.world_size < 1:
             raise ValueError("world_size must be >= 1")
         if isinstance(self.device, list) and len(self.device) != self.world_size:
@@ -699,6 +723,17 @@ class Config:
 
     def straggler_factors(self) -> List[float]:
         return [float(x) for x in self.straggler.split(",")] if self.straggler else []
+
+    def lm_kept_layers(self) -> List[int]:
+        """--lm_layers as published layer indices; empty = all."""
+        return [int(i) for i in self.lm_layers.split(",")] if self.lm_layers else []
+
+    def lm_expert_range(self) -> Optional[Tuple[int, int]]:
+        """--lm_experts_held as (first, end); None = all."""
+        if not self.lm_experts_held:
+            return None
+        first, end = (int(v) for v in self.lm_experts_held.split(":"))
+        return first, end
 
     @property
     def num_classes(self) -> int:
@@ -848,6 +883,16 @@ def get_parser() -> argparse.ArgumentParser:
     p.add_argument("--stat_dir", type=str, default=d.stat_dir)
     p.add_argument("--ckpt_dir", type=str, default=d.ckpt_dir)
     p.add_argument("--bptt", type=int, default=d.bptt)
+    p.add_argument("--lm_arch", type=str, default=d.lm_arch,
+                   help="Language model built under -m transformer: one of "
+                        f"{LM_ARCHS} (a published architecture is kept as "
+                        "models/<name>.json), or the path of such a .json file.")
+    p.add_argument("--lm_layers", type=str, default=d.lm_layers,
+                   help="Published layers kept, e.g. 1,4,5,6,7 (empty: all).")
+    p.add_argument("--lm_experts_held", type=str, default=d.lm_experts_held,
+                   help="Routed experts held here, first:end (empty: all).")
+    p.add_argument("--lm_dropout", type=float, default=d.lm_dropout,
+                   help="Dropout of the paper's LM (0.2 in the reference).")
     p.add_argument("--seq_parallel", type=str, default=d.seq_parallel,
                    choices=["", "ring", "ulysses"],
                    help="Long-context LM mode: shard the sequence axis over "
@@ -874,6 +919,9 @@ def get_parser() -> argparse.ArgumentParser:
                         "many-core hosts).")
     p.add_argument("--aot_workers", type=int, default=d.aot_workers,
                    help="Process-backend compile worker count (0 = auto).")
+    p.add_argument("--release_on_close", type=str2bool, default=d.release_on_close,
+                   help="Closing the AOT service also clears JAX's in-memory "
+                        "caches (frees the device for what the process does next).")
     p.add_argument("--aot_speculate", type=str2bool, default=d.aot_speculate,
                    help="Background-compile adjacent ladder rungs during "
                         "epochs so mid-run rebalances never block on XLA.")

@@ -28,6 +28,20 @@ class ModelSpec:
     output_kind: str
     # "image" (NHWC uint8 pipeline) or "tokens" (LM bptt pipeline)
     input_kind: str
+    # non-empty: with train=True the module returns (outputs, a float32 array
+    # of this shape that the step carries out beside the loss): the routed
+    # model's arrivals, [expert layers, held experts + 1]
+    aux_shape: tuple = ()
+    # substrings of parameter paths the step's compute-dtype cast leaves in
+    # float32 (a router, whose product the published code keeps in float32)
+    f32_leaves: tuple = ()
+    # the module places its own checkpoints under --remat (one a block); the
+    # step then adds none around the whole forward
+    own_remat: bool = False
+
+    @property
+    def train_aux(self) -> bool:
+        return bool(self.aux_shape)
 
 
 def _cnn_constructor(name: str) -> Callable[..., nn.Module] | None:
@@ -68,6 +82,16 @@ def build_model(name: str, num_classes: int = 10, **kw) -> ModelSpec:
     ctor = _cnn_constructor(name)
     if ctor is not None:
         return ModelSpec(name, ctor(num_classes=num_classes), "logits", "image")
+    if name == "afmoe":
+        from dynamic_load_balance_distributeddnn_tpu.models import afmoe
+
+        pub = afmoe.published(kw["arch"])
+        if pub.get("model_type") != "afmoe":
+            raise ValueError(f"{kw['arch']!r} is not an afmoe architecture")
+        cfg = afmoe.cut_config(pub, kw["ntoken"], kw.get("layers", ()), kw.get("experts_held"))
+        return ModelSpec(name, afmoe.AFMoELM(cfg, remat=bool(kw.get("remat", False))), "logits",
+                         "tokens", aux_shape=(cfg.layer_dense.count(False), cfg.experts_held + 1),
+                         f32_leaves=afmoe.F32_LEAVES, own_remat=True)
     if name == "transformer":
         from dynamic_load_balance_distributeddnn_tpu.models.transformer import (
             TransformerLM,

@@ -550,6 +550,9 @@ class Trainer:
         # warning is cross-checked against (run_epoch).
         self._host_meter = HostOverheadMeter()
         self._superstep_keys: set = set()
+        # a routed model's per-step arrivals, carried out of the scanned
+        # superstep with the loss (_aux_rows) until the epoch records them
+        self._routing_rows: List = []
         # Solver-trajectory predictor (balance/solver.py): one-step-ahead
         # share-vector prediction feeding scan-mode shape-TUPLE speculation
         # (config.speculate_scan) — tuples have no finite ±bucket adjacency,
@@ -620,6 +623,7 @@ class Trainer:
             # workers write their own graftscope trace files next to the
             # run trace; save_trace stitches them in (pid-tagged tracks)
             trace_dir=cfg.trace_dir if cfg.trace != "off" else None,
+            release_caches=cfg.release_on_close,
         )
         if getattr(self, "steps", None) is not None:
             self.steps.aot_service = self._aot
@@ -5316,8 +5320,27 @@ class Trainer:
         )
         jax.block_until_ready(self.state.params)
         for aux in aux_windows:
-            aux_acc.extend(np.asarray(aux, dtype=np.float64).reshape(-1, 4))
+            aux_acc.extend(self._aux_rows(aux))
         return aux_acc
+
+    def _aux_rows(self, aux) -> np.ndarray:
+        """A scanned window's aux ``[win, n_workers, 4 + r]`` as float64 rows
+        ``[win * n_workers, 4]`` in (step, worker) order. The ``r`` entries a
+        routed model's step adds (its arrivals, train/steps.py) are set
+        aside for :meth:`_record_routing`."""
+        rows = np.asarray(aux, dtype=np.float64)
+        rows = rows.reshape(-1, rows.shape[-1])
+        if rows.shape[1] > 4:
+            self._routing_rows.extend(rows[:, 4:])
+        return rows[:, :4]
+
+    def _record_routing(self, epoch: int) -> None:
+        """Hand the epoch's routing counts to graftscope (obs/routing.py)."""
+        rows, self._routing_rows = self._routing_rows, []
+        if rows:
+            from dynamic_load_balance_distributeddnn_tpu.obs import routing
+
+            routing.record_epoch(epoch, rows, self.spec.aux_shape)
 
     def _train_epoch_elastic(self, plan, faults: EpochFaults, epoch: int) -> Dict[str, float]:
         cfg = self.cfg
@@ -5358,7 +5381,8 @@ class Trainer:
             # for the windows dispatched above
             with self._trace.span("device_wait", cat="wait"):
                 for aux in aux_windows:
-                    aux_acc.extend(np.asarray(aux, dtype=np.float64).reshape(-1, 4))
+                    aux_acc.extend(self._aux_rows(aux))
+                self._record_routing(epoch)
             cache_n = self.steps.superstep_cache_size()
             if cache_n > len(self._superstep_keys):
                 self.logger.warning(

@@ -45,12 +45,14 @@ from dynamic_load_balance_distributeddnn_tpu.train.steps import StepLibrary
 class LMTrainer(Trainer):
     SNAP_BATCHES = False  # columns, not examples — keep the exact split
 
-    # Reference LM hyperparameters (dbs.py:337-343)
+    # Reference LM hyperparameters (dbs.py:337-343). Its dropout is the flag
+    # --lm_dropout (0.2 there); a DROPOUT set on the class overrides the flag
+    # (tests/benchmark's fixture, which no model_config PR may edit, sets it)
     EMSIZE = 200
     NHEAD = 2
     NHID = 200
     NLAYERS = 2
-    DROPOUT = 0.2
+    DROPOUT = None
 
     def _setup_data(self, bundle) -> None:
         cfg = self.cfg
@@ -74,18 +76,31 @@ class LMTrainer(Trainer):
         from dynamic_load_balance_distributeddnn_tpu.ops.pallas import set_use_pallas
 
         set_use_pallas(cfg.use_pallas)
-        self.spec = build_model(
-            "transformer",
-            ntoken=self.corpus.ntokens,
-            ninp=self.EMSIZE,
-            nhead=self.NHEAD,
-            nhid=self.NHID,
-            nlayers=self.NLAYERS,
-            dropout=self.DROPOUT,
-            # separate knob: flash attention omits attention-prob dropout, a
-            # training-semantics change, so it is NOT tied to use_pallas
-            use_flash=cfg.use_flash_attention,
-        )
+        if cfg.lm_arch != "paper":
+            # a published architecture (models/<lm_arch>.json, or the file
+            # --lm_arch names), cut as the command line says; the
+            # vocabulary is the corpus's
+            self.spec = build_model(
+                "afmoe",
+                arch=cfg.lm_arch,
+                ntoken=self.corpus.ntokens,
+                layers=cfg.lm_kept_layers(),
+                experts_held=cfg.lm_expert_range(),
+                remat=cfg.remat,
+            )
+        else:
+            self.spec = build_model(
+                "transformer",
+                ntoken=self.corpus.ntokens,
+                ninp=self.EMSIZE,
+                nhead=self.NHEAD,
+                nhid=self.NHID,
+                nlayers=self.NLAYERS,
+                dropout=cfg.lm_dropout if self.DROPOUT is None else self.DROPOUT,
+                # separate knob: flash attention omits attention-prob dropout, a
+                # training-semantics change, so it is NOT tied to use_pallas
+                use_flash=cfg.use_flash_attention,
+            )
         self.tx = make_optimizer(cfg.learning_rate, cfg.momentum)
         example = jnp.zeros((1, cfg.bptt), jnp.int32)
         self.state = create_state(

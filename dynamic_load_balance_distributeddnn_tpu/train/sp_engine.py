@@ -49,7 +49,7 @@ from dynamic_load_balance_distributeddnn_tpu.train.state import create_state, ma
 
 # reference LM dims (dbs.py:337-343) — kept so SP checkpoints interchange
 # with the DBS LM trainer's
-EMSIZE, NHEAD, NHID, NLAYERS, DROPOUT = 200, 2, 200, 2, 0.2
+EMSIZE, NHEAD, NHID, NLAYERS = 200, 2, 200, 2
 
 
 class SeqParallelLMTrainer:
@@ -89,7 +89,7 @@ class SeqParallelLMTrainer:
         dims = dict(
             ntoken=self.corpus.ntokens,
             ninp=EMSIZE, nhead=NHEAD, nhid=NHID, nlayers=NLAYERS,
-            dropout=DROPOUT,
+            dropout=cfg.lm_dropout,
         )
         # init with the param-compatible single-device twin: the SP module's
         # collectives (axis_size/axis_index) only exist inside shard_map

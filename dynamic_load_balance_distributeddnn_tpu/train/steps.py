@@ -239,7 +239,7 @@ class StepLibrary:
         apply = lambda p, xx: self.spec.module.apply(  # noqa: E731
             self._cast_compute(p), xx, train=True, rngs={"dropout": rng}
         )
-        if self.remat:
+        if self.remat and not self.spec.own_remat:
             # prevent_cse=False: safe (and recommended) because the remat'd
             # forward only ever runs under jit, including the grad-accum scan
             # body — avoids optimization barriers in the hot loop.
@@ -250,10 +250,16 @@ class StepLibrary:
         if self.compute_dtype is None:
             return tree
         dt = self.compute_dtype
-        return jax.tree_util.tree_map(
-            lambda t: t.astype(dt) if hasattr(t, "dtype") and t.dtype == jnp.float32 else t,
-            tree,
-        )
+        keep = self.spec.f32_leaves  # e.g. a router, whose product stays in float32
+
+        def cast(path, t):
+            if not hasattr(t, "dtype") or t.dtype != jnp.float32:
+                return t
+            if any(k in jax.tree_util.keystr(path) for k in keep):
+                return t
+            return t.astype(dt)
+
+        return jax.tree_util.tree_map_with_path(cast, tree)
 
     # ------------------------------------------------------------ input prep
 
@@ -294,7 +300,10 @@ class StepLibrary:
         spec = self.spec
 
         def local_grads(params, x, y, w, rng, slow_iters, train_prep_rng):
-            """Shared forward/backward for one worker's (padded) batch."""
+            """Shared forward/backward for one worker's (padded) batch. Last
+            of its outputs are the model's own counts (a routed model's
+            arrivals; ``None`` for a model without ``train_aux``), which the
+            scanned superstep carries out and the per-step paths drop."""
             with jax.named_scope(scopes.AUGMENT):
                 x = self._cast_compute(self._prep_images(x, train_prep_rng, train=True))
 
@@ -303,14 +312,17 @@ class StepLibrary:
                 # transpose(jvp(forward)), which obs/scopes.py reads as such
                 with jax.named_scope(scopes.FORWARD):
                     out = self._apply_train(p, x, rng)
+                    # a model with train_aux hands back counts of its own
+                    # (a routed model's arrivals) beside its outputs
+                    out, counts = out if spec.train_aux else (out, None)
                     losses = _per_example_loss(
                         spec, out.astype(jnp.float32), y, self.use_pallas
                     )
                     mask = (w > 0).astype(jnp.float32)
                     wloss = jnp.sum(losses * w)
-                    return wloss, (jnp.sum(losses * mask), jnp.sum(mask))
+                    return wloss, (jnp.sum(losses * mask), jnp.sum(mask), counts)
 
-            (wloss, (loss_sum, count)), grads = jax.value_and_grad(
+            (wloss, (loss_sum, count, counts)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(params)
             grads = self._clip_local(grads, w)
@@ -319,11 +331,11 @@ class StepLibrary:
             # work whose trip count is a traced scalar.
             with jax.named_scope(scopes.INJECT):
                 probe = synthetic_load(slow_iters, wloss)
-            return grads, wloss, loss_sum, count, probe
+            return grads, wloss, loss_sum, count, probe, counts
 
         @jax.jit
         def worker_step_first(params, x, y, w, rng, slow_iters):
-            g, wloss, loss_sum, count, probe = local_grads(
+            g, wloss, loss_sum, count, probe, _ = local_grads(
                 params, x, y, w, rng, slow_iters, rng
             )
             acc = jax.tree_util.tree_map(lambda t: t[None], g)
@@ -331,7 +343,7 @@ class StepLibrary:
 
         @functools.partial(jax.jit, donate_argnums=(1,))
         def worker_step_acc(params, acc, x, y, w, rng, slow_iters):
-            g, wloss, loss_sum, count, probe = local_grads(
+            g, wloss, loss_sum, count, probe, _ = local_grads(
                 params, x, y, w, rng, slow_iters, rng
             )
             acc = jax.tree_util.tree_map(lambda a, t: a + t[None], acc, g)
@@ -359,7 +371,7 @@ class StepLibrary:
         @jax.jit
         def worker_step_first_win(params, xw, yw, ww, kw, s, slow_iters):
             x, y, w, rng = _win_slice(s, xw, yw, ww, kw)
-            g, wloss, loss_sum, count, probe = local_grads(
+            g, wloss, loss_sum, count, probe, _ = local_grads(
                 params, x, y, w, rng, slow_iters, rng
             )
             acc = jax.tree_util.tree_map(lambda t: t[None], g)
@@ -368,7 +380,7 @@ class StepLibrary:
         @functools.partial(jax.jit, donate_argnums=(1,))
         def worker_step_acc_win(params, acc, xw, yw, ww, kw, s, slow_iters):
             x, y, w, rng = _win_slice(s, xw, yw, ww, kw)
-            g, wloss, loss_sum, count, probe = local_grads(
+            g, wloss, loss_sum, count, probe, _ = local_grads(
                 params, x, y, w, rng, slow_iters, rng
             )
             acc = jax.tree_util.tree_map(lambda a, t: a + t[None], acc, g)
@@ -381,7 +393,7 @@ class StepLibrary:
             idx, w, rng = _win_slice(s, iw, ww, kw)
             x = jnp.take(train_x, idx, axis=0, mode="clip")
             y = jnp.take(train_y, idx, axis=0, mode="clip")
-            g, wloss, loss_sum, count, probe = local_grads(
+            g, wloss, loss_sum, count, probe, _ = local_grads(
                 params, x, y, w, rng, slow_iters, rng
             )
             acc = jax.tree_util.tree_map(lambda t: t[None], g)
@@ -394,7 +406,7 @@ class StepLibrary:
             idx, w, rng = _win_slice(s, iw, ww, kw)
             x = jnp.take(train_x, idx, axis=0, mode="clip")
             y = jnp.take(train_y, idx, axis=0, mode="clip")
-            g, wloss, loss_sum, count, probe = local_grads(
+            g, wloss, loss_sum, count, probe, _ = local_grads(
                 params, x, y, w, rng, slow_iters, rng
             )
             acc = jax.tree_util.tree_map(lambda a, t: a + t[None], acc, g)
@@ -414,7 +426,7 @@ class StepLibrary:
         def worker_step_first_idx(params, train_x, train_y, idx, w, rng, slow_iters):
             x = jnp.take(train_x, idx, axis=0, mode="clip")
             y = jnp.take(train_y, idx, axis=0, mode="clip")
-            g, wloss, loss_sum, count, probe = local_grads(
+            g, wloss, loss_sum, count, probe, _ = local_grads(
                 params, x, y, w, rng, slow_iters, rng
             )
             acc = jax.tree_util.tree_map(lambda t: t[None], g)
@@ -424,7 +436,7 @@ class StepLibrary:
         def worker_step_acc_idx(params, acc, train_x, train_y, idx, w, rng, slow_iters):
             x = jnp.take(train_x, idx, axis=0, mode="clip")
             y = jnp.take(train_y, idx, axis=0, mode="clip")
-            g, wloss, loss_sum, count, probe = local_grads(
+            g, wloss, loss_sum, count, probe, _ = local_grads(
                 params, x, y, w, rng, slow_iters, rng
             )
             acc = jax.tree_util.tree_map(lambda a, t: a + t[None], acc, g)
@@ -477,7 +489,7 @@ class StepLibrary:
         acc = None
         aux = []
         for i in range(len(ws_)):
-            g, wloss, loss_sum, count, probe = self._local_grads(
+            g, wloss, loss_sum, count, probe, counts = self._local_grads(
                 state.params, xs[i], ys[i], ws_[i], ks[i], slows[i], ks[i]
             )
             with jax.named_scope(scopes.COMBINE):
@@ -485,7 +497,11 @@ class StepLibrary:
                     acc = jax.tree_util.tree_map(lambda t: t[None], g)
                 else:
                     acc = jax.tree_util.tree_map(lambda a, t: a + t[None], acc, g)
-            aux.append(jnp.stack([wloss, loss_sum, count, probe]))
+            row = jnp.stack([wloss, loss_sum, count, probe])
+            if counts is not None:
+                # [wloss, loss_sum, count, probe, the model's counts...]
+                row = jnp.concatenate([row, counts.reshape(-1)])
+            aux.append(row)
         with jax.named_scope(scopes.COMBINE):
             grads = jax.tree_util.tree_map(lambda t: jnp.sum(t, axis=0), acc)
         if self.shard_update:

@@ -302,3 +302,19 @@ def test_aot_off_keeps_legacy_warm(bundle):
     assert tr._aot is None
     tr._maybe_warm()  # executes the dummy ladder without error
     assert tr._warmed
+
+
+@pytest.mark.parametrize("release", [False, True])
+def test_close_lets_the_executables_go_and_clears_caches_only_where_asked(monkeypatch, release):
+    """A closed service holds no executable. JAX's in-memory caches go with
+    them only for a service built with ``release_caches`` (--release_on_close:
+    on the TPU a program's temporaries stay reserved while any cache holds
+    its executable)."""
+    cleared = []
+    monkeypatch.setattr(jax, "clear_caches", lambda: cleared.append(True))
+    svc = AOTCompileService(workers=1, release_caches=release)
+    svc.submit("k", jax.jit(lambda x: x * 2), (jax.ShapeDtypeStruct((4,), jnp.float32),))
+    assert svc.wait() == [] and svc.get("k") is not None
+    svc.close()
+    svc.close()  # a second close (the trainer's finalizer) clears nothing more
+    assert svc.get("k") is None and svc.keys() == [] and cleared == [True] * release

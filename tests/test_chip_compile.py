@@ -59,3 +59,51 @@ def test_kernel_compiles_for_v5e(one_chip, kind, shape, mode):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
+
+
+# The routed decoder's two new ops (PR 27) at Trinity-Mini's widths and the
+# cell's shapes: XLA throughout, so what is guarded is that they fit the chip
+# (one block's scores, one chunk's rows), and that `lax.ragged_dot` still
+# becomes XLA:TPU's own grouped matmul and not a dense product per expert.
+
+
+@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
+def test_blocked_attention_gradient_fits_a_v5e(one_chip, window):
+    import jax.numpy as jnp
+
+    from dynamic_load_balance_distributeddnn_tpu.ops.attention import blocked_causal_attention
+
+    def loss(q, k, v):
+        return jnp.sum(blocked_causal_attention(q, k, v, window).astype(jnp.float32))
+
+    q = jax.ShapeDtypeStruct((2, 4096, 32, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 4096, 4, 128), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+    # all [32 heads, 4096, 4096] float32 scores of two columns would be 4.3 GB;
+    # tied in sequence, a few blocks' worth are alive at once
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+
+
+def test_expert_layer_gradient_is_a_grouped_matmul_on_a_v5e(one_chip):
+    import jax.numpy as jnp
+
+    from dynamic_load_balance_distributeddnn_tpu.ops import moe
+
+    n, d, f, experts, held, k = 8192, 2048, 1024, 128, 8, 8
+
+    def loss(m, w_r, w_gate, w_up, w_down):
+        chosen, weights = moe.route(m, w_r, jnp.zeros((experts,)), k, True, 2.826)
+        out, arrivals = moe.expert_ffn(m, chosen, weights, 0, w_gate, w_up, w_down, experts)
+        return jnp.sum(out.astype(jnp.float32)), arrivals
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds((n, d)), sds((d, experts), jnp.float32), sds((held, d, f)), sds((held, d, f)),
+            sds((held, f, d)))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    # one chunk of 8,192 rows at a time, not all 65,536 pairs' rows
+    assert moe.chunk_rows(n * k, held, experts) == 8192
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 * 2**30

@@ -1,15 +1,24 @@
-"""Decoder attention parts in plain XLA: RMSNorm, rotary positions and causal
-grouped-query attention with an optional window, blocked over queries.
+"""Decoder attention parts: RMSNorm, rotary positions and causal grouped-query
+attention with an optional window, in two forms of one algorithm.
 
-At thousands of tokens the ``[heads, T, T]`` scores cannot be materialised
+*Fused* (``ops/pallas/fused_attention.py``): a flash-form kernel that keeps the
+scores in VMEM. It serves a call whose operands are bfloat16, whose head size
+divides by 128 and whose ``T`` divides by its blocks, when the program is
+lowered for a TPU. *Blocked*, in plain XLA, serves every other call (float32
+operands, odd shapes, the CPU). The choice is made from the call itself and
+from the platform the program is lowered for, never from the process's default
+backend: a program compiled for a described chip takes the path the chip
+would. Each lowered call leaves one ``attention_path`` instant in the tracer.
+
+The blocked form: at thousands of tokens the ``[heads, T, T]`` scores cannot be materialised
 (2 columns x 32 heads x 4096^2 x 4 B = 4.3 GB a layer), so the queries are
 taken ``block_q`` at a time against the static slice of keys they can see:
 ``[0, q_end)`` under the causal mask alone, ``(q_start - window, q_end)``
 with a window. Each block is a ``jax.checkpoint`` and the blocks are tied one
 after the other, so either pass holds one block's scores at a time and the
 backward pass recomputes them from q, k and v. Blocks the mask empties are
-never computed; inside the kept slices the mask does the rest. No kernel:
-every product is XLA's own.
+never computed; inside the kept slices the mask does the rest. Every product
+is XLA's own.
 """
 
 from __future__ import annotations
@@ -19,8 +28,21 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Primitive
+from jax.interpreters import ad, batching, mlir
+
+from dynamic_load_balance_distributeddnn_tpu.obs.trace import get_tracer
+from dynamic_load_balance_distributeddnn_tpu.ops.pallas.fused_attention import (
+    fused_causal_attention,
+)
 
 _NEG = -1e30
+# the fused form's query and key blocks, by scripts/kernel_bench.py's attention
+# case on a v5e (PERF.md section 6, PR 28)
+FUSED_BLOCK = 512
+# its K and V of one head stay in VMEM, twice each for the pipeline, beside the
+# backward pass's float32 dK and dV: 4 MB is 16,384 keys of 128
+_FUSED_KV_BYTES = 4 * 2**20
 
 
 def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float) -> jnp.ndarray:
@@ -66,6 +88,41 @@ def _block(q, k, v, q0: int, k0: int, window: Optional[int]):
     return jnp.einsum("bhgqk,bkhd->bqhgd", p, v)
 
 
+# An identity on q that says, when its program is lowered, which form the call
+# took: only there is the platform known.
+_path_p = Primitive("attention_path")
+_path_p.def_impl(lambda x, **_: x)
+_path_p.def_abstract_eval(lambda x, **_: x)
+ad.primitive_jvps[_path_p] = lambda primals, tangents, **params: (
+    _path_p.bind(*primals, **params), tangents[0])
+batching.defvectorized(_path_p)
+
+
+def _path_lowering(ctx, x, *, path, why, window):
+    aval = ctx.avals_in[0]
+    get_tracer().instant("attention_path", cat="dispatch", args={
+        "path": path, "why": why or ",".join(ctx.module_context.platforms),
+        "window": window, "t": aval.shape[1], "dtype": str(aval.dtype)})
+    return [x]
+
+
+# not cacheable: the rule speaks, once for every call it lowers
+mlir.register_lowering(_path_p, _path_lowering, cacheable=False)
+
+
+def _why_not_fused(q, k, v) -> Optional[str]:
+    """What about the call itself keeps the fused form from serving it."""
+    t, d = q.shape[1], q.shape[-1]
+    for x in (q, k, v):
+        if x.dtype != jnp.bfloat16:
+            return str(x.dtype)
+    if d % 128:
+        return "head_dim"
+    if t % FUSED_BLOCK or t * d * 2 > _FUSED_KV_BYTES:
+        return "t"
+    return None
+
+
 def blocked_causal_attention(
     q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, window: Optional[int] = None,
     block_q: int = 256,
@@ -73,7 +130,23 @@ def blocked_causal_attention(
     """Causal softmax attention, scale ``1/sqrt(D)``. ``q`` is ``[B, T, H,
     D]``, ``k`` and ``v`` ``[B, T, Hkv, D]`` with ``H`` a multiple of ``Hkv``
     (query head ``h`` reads key head ``h // (H / Hkv)``). With ``window``, a
-    query at ``i`` sees the keys ``j`` with ``0 <= i - j < window``."""
+    query at ``i`` sees the keys ``j`` with ``0 <= i - j < window``. Fused
+    where the kernel serves the call (see the module's text), else blocked
+    over ``block_q`` queries."""
+
+    def form(path, why=""):
+        fn = fused_causal_attention if path == "fused" else _blocked
+        block = FUSED_BLOCK if path == "fused" else block_q
+        return lambda q, k, v: fn(
+            _path_p.bind(q, path=path, why=why, window=window), k, v, window, block)
+
+    why = _why_not_fused(q, k, v)
+    if why:
+        return form("blocked", why)(q, k, v)
+    return jax.lax.platform_dependent(q, k, v, tpu=form("fused"), default=form("blocked"))
+
+
+def _blocked(q, k, v, window: Optional[int], block_q: int) -> jnp.ndarray:
     b, t, h, d = q.shape
     hkv = k.shape[2]
     q = q.reshape(b, t, hkv, h // hkv, d)

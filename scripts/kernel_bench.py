@@ -13,6 +13,9 @@ artifacts/KERNELS.md. The use_pallas / use_flash_attention config defaults
 are chosen from (and justified by) this table.
 
 Usage: python scripts/kernel_bench.py [--repeats 30] [--quick]
+       python scripts/kernel_bench.py --only cell_attention   (PERF.md's table
+       of the Trinity-Mini cell's attention: blocked XLA against each fused
+       candidate, window and full)
 """
 
 import argparse
@@ -113,6 +116,118 @@ def bench_attention(results, dtype, repeats, quick):
             print(json.dumps(row), flush=True)
 
 
+# ------------------------------------------- the Trinity-Mini cell's attention
+
+# (block_q, block_k) of ops/pallas/fused_attention.py, and of JAX's bundled
+# splash attention (block_q, block_kv, block_kv_compute, fused backward): what
+# PR 28 tried on the chip
+FUSED_BLOCKS = [(256, 256), (256, 512), (512, 256), (512, 512), (512, 1024), (1024, 512), (1024, 1024)]
+SPLASH_BLOCKS = [(512, 512, 512, True), (512, 1024, 512, True), (1024, 1024, 512, True),
+                 (512, 2048, 512, True), (512, 1024, 256, True), (512, 1024, 512, False)]
+
+
+def splash_attention(q, k, v, window, block_q, block_kv, block_kv_compute, fused_bwd):
+    """JAX's bundled splash attention in its MQA form behind the model's
+    ``[B, T, H, D]`` interface: the scale goes into q, the layout changes
+    happen here (and are timed), the key-value heads and the batch are mapped."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+        splash_attention_mask as sm,
+    )
+
+    b, t, h, d = q.shape
+    hkv = k.shape[2]
+    mask = sm.CausalMask((t, t))
+    if window is not None:
+        mask = sm.LogicalAnd(mask, sm.LocalMask((t, t), window_size=(window - 1, 0), offset=0))
+    dq = {} if fused_bwd else {"block_q_dq": block_q, "block_kv_dq": block_kv}
+    sizes = sk.BlockSizes(
+        block_q=block_q, block_kv=block_kv, block_kv_compute=block_kv_compute,
+        block_q_dkv=block_q, block_kv_dkv=block_kv, block_kv_dkv_compute=block_kv_compute,
+        use_fused_bwd_kernel=fused_bwd, **dq)
+    kernel = sk.make_splash_mqa_single_device(
+        sm.MultiHeadMask([mask] * (h // hkv)), block_sizes=sizes)
+    q = (q * (1.0 / d ** 0.5)).astype(q.dtype)
+    q = q.reshape(b, t, hkv, h // hkv, d).transpose(0, 2, 3, 1, 4)
+    k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    o = jax.vmap(jax.vmap(kernel))(q, k, v)
+    return o.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d)
+
+
+def cell_attention_candidates(window):
+    """``(name, attention(q, k, v))`` of every form the cell's attention was
+    measured in."""
+    from dynamic_load_balance_distributeddnn_tpu.ops import attention
+    from dynamic_load_balance_distributeddnn_tpu.ops.pallas.fused_attention import (
+        fused_causal_attention,
+    )
+
+    yield "blocked_xla_256", lambda q, k, v: attention._blocked(q, k, v, window, 256)
+    yield "default_path", lambda q, k, v: attention.blocked_causal_attention(q, k, v, window)
+    for bq, bk in FUSED_BLOCKS:
+        yield f"fused_{bq}x{bk}", (
+            lambda q, k, v, bq=bq, bk=bk: fused_causal_attention(q, k, v, window, bq, bk))
+    for blocks in SPLASH_BLOCKS:
+        yield "splash_" + "x".join(str(int(x)) for x in blocks), (
+            lambda q, k, v, blocks=blocks: splash_attention(q, k, v, window, *blocks))
+
+
+def bf16_peak():
+    """The device's published bf16 FLOP/s (``benchmark/peaks.json``), None for
+    a device the table does not hold: no share of a peak is printed then."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "peaks.json")) as f:
+        return json.load(f).get(jax.devices()[0].device_kind, {}).get("bf16_flops_per_s")
+
+
+def best_of(fn, *args, sets=3, calls=10):
+    """Best of ``sets`` means over ``calls`` calls in a row, seconds a call."""
+    jax.block_until_ready(fn(*args))
+    best = float("inf")
+    for _ in range(sets):
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        best = min(best, (time.perf_counter() - t0) / calls)
+    return best
+
+
+def bench_cell_attention(results, dtype, repeats, quick):
+    """The attention of ``trinity_mini.ws4_even_dbs`` as one worker's step calls
+    it: 2 columns of 4,096 tokens, 32 query and 4 key-value heads of 128, the
+    window of 2,048 and none. The FLOPs are the visible pairs' alone (two
+    products forward, five backward), as a share of the device's bf16 peak."""
+    del repeats, quick
+    peak = bf16_peak()
+    b, t, h, hkv, d = 2, 4096, 32, 4, 128
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(keys[0], (b, t, h, d), dtype)
+    k = jax.random.normal(keys[1], (b, t, hkv, d), dtype)
+    v = jax.random.normal(keys[2], (b, t, hkv, d), dtype)
+    w = jax.random.normal(keys[3], (b, t, h, d), jnp.float32)
+    for window in (2048, None):
+        pairs = b * h * sum(min(i + 1, window or t) for i in range(t))
+        for name, fn in cell_attention_candidates(window):
+            row = {"kernel": "cell_attention", "form": name, "window": window,
+                   "shape": f"B{b}xT{t}xH{h}/{hkv}xD{d}", "dtype": str(dtype.__name__)}
+            try:
+                fwd = jax.jit(fn)
+                grad = jax.jit(jax.grad(
+                    lambda q, k, v, w, fn=fn: jnp.sum(fn(q, k, v).astype(jnp.float32) * w),
+                    argnums=(0, 1, 2)))
+                row["fwd_ms"] = best_of(fwd, q, k, v) * 1e3
+                row["fwd_bwd_ms"] = best_of(grad, q, k, v, w) * 1e3
+                if peak:
+                    row["fwd_pct_of_peak"] = 100 * 4 * d * pairs / (row["fwd_ms"] * 1e-3) / peak
+                    row["fwd_bwd_pct_of_peak"] = (
+                        100 * 14 * d * pairs / (row["fwd_bwd_ms"] * 1e-3) / peak)
+            except Exception as e:  # a candidate the compiler refuses is a result
+                row["error"] = f"{type(e).__name__}: {e}"[:300]
+            results.append(row)
+            print(json.dumps(row), flush=True)
+
+
 def bench_groupnorm(results, dtype, repeats, quick):
     """CNN shapes: 32x32 CIFAR maps through the zoo's widths, GroupNorm(32)
     (Net/Resnet.py:11-13); batch = per-worker 128 of the B=512/ws=4 recipe."""
@@ -202,6 +317,8 @@ def to_markdown(results, platform, kind):
         "|---|---|---|---|---|---|---|---|---|",
     ]
     for r in results:
+        if r["kernel"] == "cell_attention":
+            continue  # printed as JSON lines and kept in the .json: other columns
         if "error" in r:
             lines.append(
                 f"| {r['kernel']} | {r['shape']} | {r['dtype']} | ERROR: {r['error'][:80]} | | | | | |"
@@ -217,12 +334,18 @@ def to_markdown(results, platform, kind):
     return "\n".join(lines) + "\n"
 
 
+LEGS = {"attention": bench_attention, "groupnorm": bench_groupnorm, "xent": bench_xent,
+        "cell_attention": bench_cell_attention}
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--repeats", type=int, default=30)
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--dtype", default="bfloat16", choices=["bfloat16", "float32"])
     ap.add_argument("--out_dir", default="artifacts")
+    ap.add_argument("--only", default="", choices=["", *LEGS],
+                    help="run one leg (default: the three kernels' legs)")
     ns = ap.parse_args()
 
     dev = jax.devices()[0]
@@ -266,9 +389,8 @@ def main():
                 f.write(to_markdown(self, platform, kind))
 
     results = _IncrementalResults()
-    bench_attention(results, dtype, ns.repeats, ns.quick)
-    bench_groupnorm(results, dtype, ns.repeats, ns.quick)
-    bench_xent(results, dtype, ns.repeats, ns.quick)
+    for leg in [ns.only] if ns.only else ["attention", "groupnorm", "xent"]:
+        LEGS[leg](results, dtype, ns.repeats, ns.quick)
     print(f"[kernel_bench] wrote {json_path}")
     return 0
 

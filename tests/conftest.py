@@ -5,6 +5,7 @@ multi-worker behavior as N gloo processes on localhost (dbs.py:538-541,
 parser.py:42-43): here, one process with 8 virtual XLA CPU devices.
 """
 
+import contextlib
 import os
 
 os.environ["JAX_PLATFORMS"] = "cpu"
@@ -33,6 +34,23 @@ def devices():
     import jax
 
     return jax.devices()
+
+
+@contextlib.contextmanager
+def traced_instants(name):
+    """The ``args`` of every graftscope instant called ``name`` that the block
+    emits, in a list filled when the block ends; the process-wide tracer is on
+    for the block's length and back as it was after."""
+    from dynamic_load_balance_distributeddnn_tpu.obs import trace
+
+    tracer = trace.get_tracer()
+    was, said = tracer.mode, []
+    trace.configure("on")
+    try:
+        yield said
+        said.extend(e[6] for e in tracer.events() if e[0] == name)
+    finally:
+        trace.configure(was)
 
 
 def make_tiny_corpus(dirpath, vocab=50, lines=400, words_per_line=12, seed=0):

@@ -11,6 +11,7 @@ module-scoped fixture: only the xdist worker that is handed this file loads
 the TPU library, and every worker collects the same tests.
 """
 
+import functools
 import os
 import sys
 
@@ -61,27 +62,94 @@ def test_kernel_compiles_for_v5e(one_chip, kind, shape, mode):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
 
 
-# The routed decoder's two new ops (PR 27) at Trinity-Mini's widths and the
-# cell's shapes: XLA throughout, so what is guarded is that they fit the chip
-# (one block's scores, one chunk's rows), and that `lax.ragged_dot` still
-# becomes XLA:TPU's own grouped matmul and not a dense product per expert.
+# The routed decoder's ops (PR 27, PR 28) at Trinity-Mini's widths and the
+# cell's shapes. The blocked attention and the expert layer are XLA
+# throughout: what is guarded is that they fit the chip (one block's scores,
+# one chunk's rows), and that `lax.ragged_dot` still becomes XLA:TPU's own
+# grouped matmul and not a dense product per expert. The fused attention is
+# the default path's kernel: that it is taken, fits and is seen by the scopes.
+
+
+def _cell_attention_operands(one_chip):
+    import jax.numpy as jnp
+
+    q = jax.ShapeDtypeStruct((2, 4096, 32, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 4096, 4, 128), jnp.bfloat16, sharding=one_chip)
+    return q, kv, kv
+
+
+def _attention_gradient(fn):
+    import jax.numpy as jnp
+
+    return jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32)), argnums=(0, 1, 2)))
+
+
+@functools.lru_cache(maxsize=None)
+def _blocked_attention_gradient(one_chip, window):
+    """The blocked form at the cell's shapes, compiled once for both tests
+    that look at it (12 s each)."""
+    from dynamic_load_balance_distributeddnn_tpu.ops import attention
+
+    return _attention_gradient(
+        lambda q, k, v: attention._blocked(q, k, v, window, 256)
+    ).lower(*_cell_attention_operands(one_chip)).compile()
 
 
 @pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
 def test_blocked_attention_gradient_fits_a_v5e(one_chip, window):
-    import jax.numpy as jnp
-
-    from dynamic_load_balance_distributeddnn_tpu.ops.attention import blocked_causal_attention
-
-    def loss(q, k, v):
-        return jnp.sum(blocked_causal_attention(q, k, v, window).astype(jnp.float32))
-
-    q = jax.ShapeDtypeStruct((2, 4096, 32, 128), jnp.bfloat16, sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((2, 4096, 4, 128), jnp.bfloat16, sharding=one_chip)
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+    compiled = _blocked_attention_gradient(one_chip, window)
+    assert "tpu_custom_call" not in compiled.as_text()
     # all [32 heads, 4096, 4096] float32 scores of two columns would be 4.3 GB;
     # tied in sequence, a few blocks' worth are alive at once
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+
+
+@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
+def test_fused_attention_gradient_is_taken_and_fits_a_v5e(one_chip, window):
+    """The cell's attention call, compiled for the chip from a CPU process,
+    is the program the chip runs: the fused kernels, forward and backward, and
+    less temporary memory than the blocked form asks for."""
+    from dynamic_load_balance_distributeddnn_tpu.ops import attention
+    from tests.conftest import traced_instants
+
+    with traced_instants("attention_path") as said:
+        compiled = _attention_gradient(
+            lambda q, k, v: attention.blocked_causal_attention(q, k, v, window)
+        ).lower(*_cell_attention_operands(one_chip)).compile()
+    assert said == [{"path": "fused", "why": "tpu", "window": window, "t": 4096,
+                     "dtype": "bfloat16"}]
+    text = compiled.as_text()
+    assert "fused_attention_fwd" in text and "fused_attention_bwd" in text
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < _blocked_attention_gradient(one_chip, window).memory_analysis().temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("window,scope", [(2048, "attention_window"), (None, "attention_full")])
+def test_the_scopes_see_the_fused_attention_kernels(one_chip, window, scope):
+    """A custom VJP's backward pass is not ``transpose(jvp(forward))`` of
+    anything: its kernel has to land in the layer's scope all the same, under
+    the block's rematerialisation as the model runs it, or
+    ``attention_device_pct`` would fall for the wrong reason."""
+    import re
+
+    import jax.numpy as jnp
+
+    from dynamic_load_balance_distributeddnn_tpu.obs import scopes
+    from dynamic_load_balance_distributeddnn_tpu.ops.attention import blocked_causal_attention
+
+    def layer(q, k, v):
+        with jax.named_scope(scopes.FORWARD):
+            with jax.named_scope(scope):
+                o = blocked_causal_attention(q, k, v, window)
+            return jnp.sum(o.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(jax.checkpoint(layer), argnums=(0, 1, 2))).lower(
+        *_cell_attention_operands(one_chip)).compile().as_text()
+    _, by_instruction = scopes.instruction_scopes(text)
+    kernels = re.findall(r"^\s*(?:ROOT\s+)?%?(\S+) = .*tpu_custom_call", text, flags=re.M)
+    assert {k.split(".")[0] for k in kernels} == {"fused_attention_fwd", "fused_attention_bwd"}
+    assert {by_instruction[k] for k in kernels} == {scope}
 
 
 def test_expert_layer_gradient_is_a_grouped_matmul_on_a_v5e(one_chip):

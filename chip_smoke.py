@@ -65,14 +65,15 @@ KERNEL_GRAD_TOL = 4e-2
 # dropout key differently. DenseNet has none.)
 MULTICHIP_LOSS_TOL = 1e-2
 
-# (id, kind, shape): the widths the main paths really run. flash: [B,H,T,D]
-# (the LM's own per-worker shape last); groupnorm: DenseNet-121's first and
+# (id, kind, shape): the widths the main paths really run. attention: the
+# routed decoder's fused kernel (ops/attention.py takes it for bfloat16 calls
+# lowered for a TPU), queries [B,T,H,D] over one key-value head, with a window
+# of T/2 and without; groupnorm: DenseNet-121's first and
 # last stage at per-worker batch 128, [B,H,W,C]; xent: the CNN criterion at
 # B 512 x 10 classes and the LM's 20x35 tokens over the wikitext-2 vocab.
 KERNEL_CASES = (
-    ("flash_b40_h2_t64_d128", "flash", (40, 2, 64, 128)),
-    ("flash_b8_h2_t512_d128", "flash", (8, 2, 512, 128)),
-    ("flash_lm_b20_h2_t35_d100", "flash", (20, 2, 35, 100)),
+    ("fused_attention_window512_b2_t1024_h8_d128", "attention_window", (2, 1024, 8, 128)),
+    ("fused_attention_full_b2_t1024_h8_d128", "attention_full", (2, 1024, 8, 128)),
     ("groupnorm_relu_128x32x32x64", "groupnorm", (128, 32, 32, 64)),
     ("groupnorm_relu_128x8x8x512", "groupnorm", (128, 8, 8, 512)),
     ("xent_512x10", "xent", (512, 10)),
@@ -95,30 +96,38 @@ def kernel_case(kind: str, shape):
     import jax
     import jax.numpy as jnp
 
+    from dynamic_load_balance_distributeddnn_tpu.ops.attention import (
+        FUSED_BLOCK,
+        _blocked,
+    )
     from dynamic_load_balance_distributeddnn_tpu.ops.losses import (
         per_example_cross_entropy,
     )
     from dynamic_load_balance_distributeddnn_tpu.ops.pallas import (
-        flash_attention,
         fused_group_norm,
         fused_softmax_xent,
     )
+    from dynamic_load_balance_distributeddnn_tpu.ops.pallas.fused_attention import (
+        fused_causal_attention,
+    )
 
     bf16, f32 = jnp.bfloat16, jnp.float32
-    if kind == "flash":
-        spec = jax.ShapeDtypeStruct(shape, bf16)
-        t, d = shape[2], shape[3]
+    if kind in ("attention_window", "attention_full"):
+        b, t, _, d = shape  # queries; keys and values have one head
+        window = t // 2 if kind == "attention_window" else None
+        q_spec = jax.ShapeDtypeStruct(shape, bf16)
+        kv_spec = jax.ShapeDtypeStruct((b, t, 1, d), bf16)
 
         def pallas_fn(q, k, v):
-            return flash_attention(q, k, v, causal=True, interpret=False)
+            return fused_causal_attention(
+                q, k, v, window, FUSED_BLOCK, interpret=False
+            )
 
         def ref_fn(q, k, v):
             q, k, v = (a.astype(f32) for a in (q, k, v))
-            s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(d)
-            s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
-            return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+            return _blocked(q, k, v, window, 256)
 
-        return pallas_fn, ref_fn, (spec, spec, spec), (0, 1, 2)
+        return pallas_fn, ref_fn, (q_spec, kv_spec, kv_spec), (0, 1, 2)
     if kind == "groupnorm":
         c = shape[-1]
         groups = math.gcd(32, c)  # models/common.py group_norm

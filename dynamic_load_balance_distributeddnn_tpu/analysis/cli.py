@@ -2,8 +2,8 @@
 
 Usage::
 
-    graftlint dynamic_load_balance_distributeddnn_tpu bench.py
-    graftlint --flow dynamic_load_balance_distributeddnn_tpu bench.py
+    graftlint dynamic_load_balance_distributeddnn_tpu
+    graftlint --flow dynamic_load_balance_distributeddnn_tpu
     graftlint --select G001,G003 train/engine.py
     graftlint --ignore G008 --format json pkg/ | jq .findings
     graftlint --flow --format sarif pkg/ > lint.sarif

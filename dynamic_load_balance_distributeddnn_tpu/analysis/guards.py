@@ -31,9 +31,8 @@ listener runs on the compiling thread, so events can be attributed. Budgets
 and trackers default to counting only *foreground* compiles — the ones on
 the execution path, which is what the recompile sentinel and the
 steady-epoch zero-budgets police — and opt into background events with
-``include_background=True`` (the warm-ladder CI guard and the bench's
-serial-vs-concurrent warm A/B, which must see equal compile counts on both
-legs).
+``include_background=True`` (the warm-ladder CI guard, which must see the
+background compiles too).
 """
 
 from __future__ import annotations

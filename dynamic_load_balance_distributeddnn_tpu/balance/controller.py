@@ -472,8 +472,8 @@ class OnlineRebalanceController:
         """JSON-safe controller observability (recorder meta / registry).
         ``include_journal=True`` additionally embeds the construction config
         and the full decision journal — the shape `balance/replaylab.py`
-        loads as a replay corpus (the bench's ``online_dbs_ab`` arm and
-        `scripts/harvest_replay_corpus.py` harvest through this)."""
+        loads as a replay corpus (`scripts/harvest_replay_corpus.py` and
+        the engine's registry snapshot harvest through this)."""
         out = {
             "evals": self.evals,
             "switches": self.switches,

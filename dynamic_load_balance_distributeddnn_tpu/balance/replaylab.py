@@ -562,8 +562,9 @@ class Scenario:
 
 
 def builtin_scenarios(world_size: int = 4) -> List[Scenario]:
-    """The stock scenario library the sweep (and the bench's
-    ``controller_sweep`` field) runs against: one per schedule family."""
+    """The stock scenario library ``graftscope sweep`` and
+    ``scripts/harvest_replay_corpus.py`` run against: one per schedule
+    family."""
     return [
         Scenario("sin-surge", world_size, schedule="sin", period=2.0),
         Scenario("ramp-degrade", world_size, schedule="ramp", period=1.5),
@@ -689,7 +690,7 @@ def simulate(
 
 def knob_grid(size: str = "small") -> List[Dict]:
     """Deterministic grid over the decision knobs. ``small`` (18 points)
-    fits the tier-1/bench budget; ``full`` is the offline-tuning grid."""
+    fits the tier-1 budget; ``full`` is the offline-tuning grid."""
     if size == "small":
         hs, ms, bfs = (0.05, 0.1, 0.2), (1.5, 3.0, 6.0), (0.5, 1.0)
     elif size == "full":
@@ -735,8 +736,8 @@ def sweep(
 ) -> Dict:
     """Run every knob set over every scenario; rank by geometric-mean
     speedup over the hold baseline. The report carries the full ranked
-    table, the winner, the defaults' row, and winner-vs-default — the
-    artifact the ``controller_sweep`` bench field records."""
+    table, the winner, the defaults' row, and winner-vs-default — what
+    ``graftscope sweep`` prints."""
     candidates: List[Optional[Dict]] = (
         [None] if include_default else []
     ) + [dict(k) for k in knob_sets]

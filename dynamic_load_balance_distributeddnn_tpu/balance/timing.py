@@ -64,7 +64,9 @@ class HostOverheadMeter:
     NOT sync the device: they measure the controller, which is exactly what
     wall-clock-around-async-dispatch measures (the G002 failure mode, here
     the intended quantity). The elastic superstep path exists to shrink
-    them; bench.py reports them per step as the dispatch-overhead A/B."""
+    them; the engine records them per epoch (``host_dispatch_s``,
+    ``host_put_s``, ``host_overhead_per_step_s``) and its window loop reads
+    ``mark_window``."""
 
     def __init__(self):
         self._lock = threading.Lock()

@@ -1,7 +1,7 @@
 """Placement of JAX's persistent compilation cache — one rule, one place.
 
-Every entry point that compiles (``cli.main``, ``chip_smoke.py``, ``bench.py``
-children, the kept scripts, ``tests/conftest.py``, the compile-worker pool)
+Every entry point that compiles (``cli.main``, ``chip_smoke.py``, the kept
+scripts, ``tests/conftest.py``, the compile-worker pool)
 calls :func:`enable_compile_cache` and sets no cache directory of its own.
 
 The directory is part of the cache key's lookup path, so it must not move

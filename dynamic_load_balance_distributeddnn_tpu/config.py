@@ -186,10 +186,6 @@ class Config:
     use_pallas: bool = False           # route GroupNorm/xent through the
                                        # Pallas kernels (ops/pallas/) —
                                        # numerics-preserving kernel routing
-    use_flash_attention: bool = False  # LM attention via the Pallas flash
-                                       # kernel; NOTE: drops attention-prob
-                                       # dropout (a semantics change, hence a
-                                       # separate knob from use_pallas)
     remat: bool = False                # jax.checkpoint the training forward:
                                        # activations recomputed in the
                                        # backward (exact math; HBM for ~1/3
@@ -239,7 +235,7 @@ class Config:
                                        # links). Off = trust --grad_comm.
     hier_hosts: int = 0                # synthetic host-axis size for
                                        # single-process meshes (CPU tiers,
-                                       # tests, the grad_comm bench): split
+                                       # tests): split
                                        # the n devices into this many "host"
                                        # groups. 0 = derive from the real
                                        # process topology.
@@ -338,8 +334,9 @@ class Config:
                                        # dispatch resolves the compiled
                                        # objects from the service. off = the
                                        # legacy execute-to-compile warm loop
-                                       # (kept as the A/B reference; see
-                                       # bench aot_warm_ab + graftlint G007)
+                                       # (the reference leg of
+                                       # tests/test_aot_compiler.py; see
+                                       # graftlint G007)
     aot_pool: int = 0                  # AOT compile pool width; 0 = auto
                                        # (min(8, cpus), >= 2). Lowering is
                                        # single-flight (GIL-bound) either
@@ -359,8 +356,8 @@ class Config:
                                        # replay (runtime/compile_worker.py).
                                        # Worth it on many-core hosts where
                                        # per-program compiles no longer
-                                       # share an emitter; bench
-                                       # compile_workers_ab measures it.
+                                       # share an emitter (no chip run
+                                       # has measured it: ROADMAP D4).
     aot_workers: int = 0               # process-backend subprocess count
                                        # (0 = auto: min(4, cpus)); each
                                        # worker is a full spawned JAX
@@ -901,7 +898,6 @@ def get_parser() -> argparse.ArgumentParser:
     p.add_argument("--grad_clip", type=float, default=d.grad_clip)
     p.add_argument("--profile_dir", type=str, default=d.profile_dir)
     p.add_argument("--use_pallas", type=str2bool, default=d.use_pallas)
-    p.add_argument("--use_flash_attention", type=str2bool, default=d.use_flash_attention)
     p.add_argument("--warm_start", type=str2bool, default=d.warm_start)
     p.add_argument("--aot_warm", type=str2bool, default=d.aot_warm,
                    help="Warm + dispatch through the async AOT compile "

@@ -210,9 +210,9 @@ class ScheduledStragglerInjector(StaticStragglerInjector):
       engine's window loop re-stages compute-mode injection from.
 
     Deterministic for a given ``seed`` (sin/ramp/spike/diurnal use no rng at
-    all): the realized schedule replays bit-for-bit, so the window-vs-epoch
-    cadence A/B (bench ``online_dbs_ab``) compares arms under the identical
-    injected trajectory."""
+    all): the realized schedule replays bit-for-bit, so a window-vs-epoch
+    cadence comparison (tests/test_online_dbs.py) runs both arms under the
+    identical injected trajectory."""
 
     SCALAR_SCHEDULES = ("sin", "ramp", "spike", "diurnal")
     WORKER_SCHEDULES = ("brownout", "killstorm")

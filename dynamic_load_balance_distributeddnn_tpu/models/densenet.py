@@ -4,27 +4,8 @@ Constructors 121/169/201/161 mirror Net/Densenet.py:87-100; `-m densenet`
 selects DenseNet-121 with growth 32 (dbs.py:353) — the model of the canonical
 README recipe and the benchmark north star.
 
-TPU note (the roofline lever): DenseNet is
-bandwidth-bound on v5e. Two dense-block dataflows are provided, bitwise
-equivalent (pinned by test):
-
-- ``use_buffer=False`` (DEFAULT): the literal per-layer channel concat,
-  the reference shape (``torch.cat([out, x], 1)``, Net/Densenet.py:20).
-- ``use_buffer=True``: each block pre-allocates its final-width buffer and
-  every layer writes its ``growth_rate`` new channels with a static-offset
-  slice update, filling RIGHT-TO-LEFT so the live prefix ``buf[..., s:]``
-  reads ``[out_{i-1}, ..., out_0, x]`` — the channel order the nested
-  reference concat produces.
-
-The buffer variant was round 4's cost-model bet (−36% bytes on the XLA:CPU
-cost model at B=32/f32). **On the chip it lost**: the round-5 on-chip A/B
-(pre-ledger record, deleted in PR 21, see git history; TPU v5e, DenseNet-121
-B=512 bf16) showed XLA:TPU does NOT alias the ``buf.at[...].set`` chain — the
-TPU-backend cost model charged the buffer variant +20% bytes per step and
-synced step times agreed. XLA:TPU fuses the literal concat chain better than
-the hand-scheduled buffer fill — so the concat dataflow is the default and
-the buffer variant is kept as the counterexample + equivalence oracle
-(ROADMAP D6 removes it).
+The dense block is the literal per-layer channel concat, the reference shape
+(``torch.cat([out, x], 1)``, Net/Densenet.py:20): XLA:TPU fuses that chain.
 """
 
 from __future__ import annotations
@@ -75,31 +56,16 @@ class DenseNet(nn.Module):
     growth_rate: int = 12
     reduction: float = 0.5
     num_classes: int = 10
-    # concat measured faster on TPU v5e (see module docstring); True keeps
-    # the round-4 buffer fill as an equivalence oracle / counterexample
-    use_buffer: bool = False
 
     def _dense_block(self, x, nblock: int):
         """One dense block; returns the full-width feature map equal to the
         reference's nested ``cat([out, x], C)`` chain."""
-        g = self.growth_rate
-        if not self.use_buffer:
-            for _ in range(nblock):
-                out = DenseBottleneck(growth_rate=g)(x)
-                # NHWC concat on channels (reference cats on dim 1 in NCHW,
-                # Net/Densenet.py:20)
-                x = jnp.concatenate([out, x], axis=-1)
-            return x
-        c0 = x.shape[-1]
-        c_final = c0 + nblock * g
-        buf = jnp.zeros(x.shape[:-1] + (c_final,), x.dtype)
-        start = c_final - c0
-        buf = buf.at[..., start:].set(x)
         for _ in range(nblock):
-            out = DenseBottleneck(growth_rate=g)(buf[..., start:])
-            start -= g
-            buf = buf.at[..., start : start + g].set(out)
-        return buf  # start == 0: fully filled
+            out = DenseBottleneck(growth_rate=self.growth_rate)(x)
+            # NHWC concat on channels (reference cats on dim 1 in NCHW,
+            # Net/Densenet.py:20)
+            x = jnp.concatenate([out, x], axis=-1)
+        return x
 
     @nn.compact
     def __call__(self, x, train: bool = False):

@@ -71,41 +71,6 @@ class RingSelfAttention(nn.Module):
         )(o)
 
 
-class FlashSelfAttention(nn.Module):
-    """Causal multi-head self-attention over the Pallas flash kernel
-    (ops/pallas/flash_attention.py): O(T) memory, MXU-tiled matmuls — the
-    long-context replacement for materialized-score attention. Attention-prob
-    dropout is not applied inside the kernel (the residual-path dropouts in
-    the encoder layer remain)."""
-
-    num_heads: int
-    qkv_features: int
-
-    @nn.compact
-    def __call__(self, x):
-        from dynamic_load_balance_distributeddnn_tpu.ops.pallas import (
-            flash_attention,
-        )
-
-        h = self.num_heads
-        hd = self.qkv_features // h
-        dense = functools.partial(
-            nn.DenseGeneral, features=(h, hd), axis=-1
-        )
-        q = dense(name="query")(x)  # [B, T, H, hd]
-        k = dense(name="key")(x)
-        v = dense(name="value")(x)
-        o = flash_attention(
-            q.transpose(0, 2, 1, 3),
-            k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3),
-            causal=True,
-        ).transpose(0, 2, 1, 3)
-        return nn.DenseGeneral(
-            features=self.qkv_features, axis=(-2, -1), name="out"
-        )(o)
-
-
 class UlyssesSelfAttention(nn.Module):
     """Causal multi-head self-attention over a SEQUENCE-SHARDED axis via
     head all-to-all (parallel/ulysses.py): each device ends up with the FULL
@@ -148,7 +113,6 @@ class EncoderLayer(nn.Module):
     nhead: int
     d_ff: int
     dropout: float
-    use_flash: bool = False
     seq_axis: str = ""  # non-empty: sequence parallelism over this sharded axis
     sp_mode: str = "ring"  # "ring" (ppermute pipeline) | "ulysses" (head a2a)
 
@@ -156,7 +120,7 @@ class EncoderLayer(nn.Module):
     def __call__(self, x, mask, train: bool):
         # all variants share the scope name "attn" and the same
         # query/key/value/out param layout, so weights are interchangeable
-        # across single-device, flash and sequence-parallel modes
+        # across single-device and sequence-parallel modes
         if self.seq_axis and self.sp_mode == "ulysses":
             attn = UlyssesSelfAttention(
                 self.nhead, self.d_model, self.seq_axis, name="attn"
@@ -165,8 +129,6 @@ class EncoderLayer(nn.Module):
             attn = RingSelfAttention(
                 self.nhead, self.d_model, self.seq_axis, name="attn"
             )(x)
-        elif self.use_flash:
-            attn = FlashSelfAttention(self.nhead, self.d_model, name="attn")(x)
         else:
             attn = nn.MultiHeadDotProductAttention(
                 num_heads=self.nhead,
@@ -194,7 +156,6 @@ class TransformerLM(nn.Module):
     nlayers: int = 2
     dropout: float = 0.2
     max_len: int = 5000
-    use_flash: bool = False  # route attention through the Pallas flash kernel
     seq_axis: str = ""  # non-empty: sequence-parallel mode — tokens arrive as
                         # the local shard of a T-sharded global sequence (call
                         # inside shard_map); attention parallelizes over this
@@ -236,18 +197,13 @@ class TransformerLM(nn.Module):
             x = x + pe[None, :t, :]
         x = nn.Dropout(self.dropout, deterministic=not train)(x)
 
-        causal = (
-            None
-            if (self.use_flash or self.seq_axis)
-            else nn.make_causal_mask(tokens)
-        )
+        causal = None if self.seq_axis else nn.make_causal_mask(tokens)
         for _ in range(self.nlayers):
             x = EncoderLayer(
                 self.ninp,
                 self.nhead,
                 self.nhid,
                 self.dropout,
-                self.use_flash,
                 self.seq_axis,
                 self.sp_mode,
             )(x, causal, train)

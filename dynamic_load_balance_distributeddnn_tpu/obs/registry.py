@@ -14,7 +14,7 @@ them behind one object the engine owns:
 * ``registry.snapshot()`` — one JSON-safe dict of everything measurable
   *right now*: recorder last-values, host-meter walls, compile counts
   (foreground/background), AOT service stats, tracer state. The engine logs
-  it at end of run; tests and the bench read single keys out of it;
+  it at end of run; tests read single keys out of it;
 * meters registered once (``attach(...)``) so future surfaces (a new meter,
   a new service) join the snapshot without new plumbing at every call site.
 
@@ -145,7 +145,7 @@ class MetricsRegistry:
         }
         # gradient-collective wire accounting (ISSUE 12): per-epoch bytes
         # each link class carried, plus the combine structure they were
-        # measured under — the grad_comm bench reads these per arm
+        # measured under (tests/test_grad_comm.py reads them)
         comm = {
             k: self.recorder.last(k)
             for k in ("comm_bytes_ici", "comm_bytes_dcn")

@@ -12,7 +12,7 @@ Usage::
     graftscope postmortem spools/                         # crash stitcher
     graftscope decisions traces/run.trace.json            # DBS journal
     graftscope decisions spools/ --outcome committed --csv  # filtered export
-    graftscope replay runs/bench.json --margin 6          # counterfactual
+    graftscope replay runs/journal.json --margin 6        # counterfactual
     graftscope sweep --grid small --random 8              # knob sweep
     graftscope conformance spools/                        # protocol replay
 
@@ -36,7 +36,7 @@ controller's switch/hold verdicts AND the outer many-stream allocator's
 
 ``replay`` and ``sweep`` (ISSUE 19) are the device-free controller lab
 (balance/replaylab.py): ``replay`` re-runs a recorded decision journal
-(bench artifact, trace, spool, or spool directory) through a fresh
+(journal JSON, trace, spool, or spool directory) through a fresh
 controller — with no overrides it is a strict parity gate (every recorded
 verdict must reproduce bit-for-bit), with ``--hysteresis/--margin/
 --budget-frac/--rate-alpha`` it answers the counterfactual "what would the

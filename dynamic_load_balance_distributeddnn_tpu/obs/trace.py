@@ -539,7 +539,8 @@ def attribution(events: List[dict]) -> Dict:
          "coverage_min": float | None}
 
     ``coverage`` is attributed/wall per epoch; ``coverage_min`` the worst
-    epoch — the quantity the bench's >=0.95 acceptance reads.
+    epoch (``graftscope summarize`` prints both; tests/test_graftscope.py
+    holds the CLI smoke's to >= 0.95).
     """
     walls: Dict[int, float] = {}
     phases: Dict[int, Dict[str, float]] = {}

@@ -33,9 +33,6 @@ def interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-from dynamic_load_balance_distributeddnn_tpu.ops.pallas.flash_attention import (  # noqa: E402
-    flash_attention,
-)
 from dynamic_load_balance_distributeddnn_tpu.ops.pallas.groupnorm import (  # noqa: E402
     fused_group_norm,
 )
@@ -47,7 +44,6 @@ __all__ = [
     "set_use_pallas",
     "use_pallas",
     "interpret_default",
-    "flash_attention",
     "fused_group_norm",
     "fused_softmax_xent",
 ]

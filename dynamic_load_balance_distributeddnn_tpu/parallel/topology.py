@@ -24,7 +24,7 @@ def factor_hosts(devices: Sequence, requested: int = 0) -> Optional[int]:
     order identical to the flat mesh).
 
     ``requested > 0`` forces a SYNTHETIC factorization (single-process CPU
-    tiers, tests, the grad_comm bench — there is no real DCN but the
+    tiers, tests — there is no real DCN but the
     collective structure is exercised end to end). Returns None when no
     usable two-level structure exists (fewer than two groups, uneven or
     non-contiguous host blocks) — the caller falls back to the flat

@@ -3,10 +3,9 @@
 The second sequence-parallel strategy next to ring attention
 (parallel/ring.py), after DeepSpeed-Ulysses: tokens arrive sequence-sharded
 [B, H, T/n, D]; one ``all_to_all`` re-shards to head-sharded [B, H/n, T, D],
-each device runs FULL attention for its head subset (locally — so the Pallas
-flash kernel applies directly), and the inverse ``all_to_all`` restores
-sequence sharding. Two all-to-alls per attention instead of n-1 ppermute
-hops; requires ``num_heads % n_devices == 0``.
+each device runs FULL attention for its head subset locally, and the inverse
+``all_to_all`` restores sequence sharding. Two all-to-alls per attention
+instead of n-1 ppermute hops; requires ``num_heads % n_devices == 0``.
 
 The reference has no sequence parallelism at all (SURVEY §5.7/§2.3 — its LM
 path is bptt=35 truncation); both strategies here are the long-context
@@ -22,6 +21,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dynamic_load_balance_distributeddnn_tpu.parallel.mesh import axis_size, shard_map
+from dynamic_load_balance_distributeddnn_tpu.parallel.ring import reference_attention
 
 SEQ_AXIS = "data"
 
@@ -32,7 +32,6 @@ def ulysses_self_attention(
     v: jnp.ndarray,
     axis_name: str = SEQ_AXIS,
     causal: bool = True,
-    use_flash: bool = False,
 ) -> jnp.ndarray:
     """Attention over a sequence sharded on ``axis_name`` via head all-to-all.
 
@@ -51,18 +50,7 @@ def ulysses_self_attention(
         )
 
     qg, kg, vg = to_heads(q), to_heads(k), to_heads(v)
-    if use_flash:
-        from dynamic_load_balance_distributeddnn_tpu.ops.pallas.flash_attention import (
-            flash_attention,
-        )
-
-        og = flash_attention(qg, kg, vg, causal=causal)
-    else:
-        from dynamic_load_balance_distributeddnn_tpu.parallel.ring import (
-            reference_attention,
-        )
-
-        og = reference_attention(qg, kg, vg, causal=causal)
+    og = reference_attention(qg, kg, vg, causal=causal)
     # scatter sequence, gather heads: [B, H/n, T, D] -> [B, H, T/n, D]
     return jax.lax.all_to_all(
         og, axis_name, split_axis=2, concat_axis=1, tiled=True
@@ -70,16 +58,13 @@ def ulysses_self_attention(
 
 
 def make_ulysses_attention_fn(
-    mesh: Mesh, axis_name: str = SEQ_AXIS, causal: bool = True, use_flash: bool = False
+    mesh: Mesh, axis_name: str = SEQ_AXIS, causal: bool = True
 ):
     """jit-ready global-array wrapper: q,k,v [B, H, T_global, D] sharded on T."""
 
     fn = shard_map(
         functools.partial(
-            ulysses_self_attention,
-            axis_name=axis_name,
-            causal=causal,
-            use_flash=use_flash,
+            ulysses_self_attention, axis_name=axis_name, causal=causal
         ),
         mesh=mesh,
         in_specs=(

@@ -111,7 +111,7 @@ def tree_hop_widths(
     crosses hop ``i``, ``widths[-1]`` the full padded tree and ``widths[0]``
     the top chunk each device carries across the slowest link. Shared by the
     residual allocator (one row-block per hop 0..k-1), the engine's
-    bytes-on-wire accounting and the bench — one formula, no drift.
+    bytes-on-wire accounting and the ZeRO-1 combine — one formula, no drift.
 
     ``pad_multiple`` raises the padding granularity (the ZeRO-1 composition
     pads the raveled tree to a multiple of the WHOLE device count so the
@@ -206,8 +206,8 @@ def tree_allreduce(
     index so no two compressed hops share a rounding field.
 
     With two levels and ``wires=(w, "fp32")`` this IS the PR-12 spine,
-    bit-for-bit at the fp32 wire. Shared verbatim by
-    StepLibrary._hier_combine (production) and the grad_comm bench."""
+    bit-for-bit at the fp32 wire. Called by StepLibrary._hier_combine and,
+    directly, by tests/test_grad_comm.py."""
     import jax.flatten_util
 
     k = len(names) - 1
@@ -244,31 +244,6 @@ def tree_allreduce(
     for i in range(1, k + 1):
         out = jax.lax.all_gather(out, names[i], tiled=True)
     return unravel(out[:t_real]), tuple(new_res)
-
-
-def hier_tree_allreduce(
-    grads,
-    key,
-    host_axis: str,
-    device_axis: str,
-    n_hosts: int,
-    n_devices_per_host: int,
-    wire: str,
-    residual=None,
-):
-    """The PR-12 two-level combine, now a thin delegate onto the N-level
-    :func:`tree_allreduce` (one spine, no parallel implementations): in-host
-    fp32 reduce-scatter, ONE compressed cross-host hop, in-host all-gather.
-    Returns ``(reduced tree, new residual chunk)``."""
-    out, res = tree_allreduce(
-        grads,
-        key,
-        (host_axis, device_axis),
-        (n_hosts, n_devices_per_host),
-        (wire, "fp32"),
-        (residual,) if residual is not None else None,
-    )
-    return out, res[0]
 
 
 def compressed_reduce_scatter_ef(

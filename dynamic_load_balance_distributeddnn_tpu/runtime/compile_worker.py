@@ -381,8 +381,8 @@ class CompileWorkerPool:
     def wait_ready(self, timeout: float = 120.0, all_workers: bool = False) -> bool:
         """Block until at least one worker finished its jax import (spawn +
         import is the pool's fixed cost, ~3-8 s/worker on the CPU tier).
-        ``all_workers=True`` waits for the FULL pool — the bench A/B uses it
-        so late-importing workers don't contend with the measured jobs.
+        ``all_workers=True`` waits for the FULL pool, so that late-importing
+        workers don't contend with the jobs that follow.
         Returns False (immediately, not after the timeout) when the pool
         died before enough workers acked ready."""
         ev = self._all_ready if all_workers else self._ready

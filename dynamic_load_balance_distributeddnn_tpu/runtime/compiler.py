@@ -44,7 +44,7 @@ persistent cache — replays ``lowered.compile()`` in-process as a
 guaranteed cache hit. In-process concurrent compiles contend ~fully on a
 shared resource in the XLA:CPU emitter (jobs overlap 2x but stretch 2x);
 worker processes each own an emitter, so multi-program compile throughput
-finally scales with cores (bench ``compile_workers_ab``). A worker that
+finally scales with cores (a CPU-tier reading; no chip run). A worker that
 dies or rejects a payload costs nothing: the replay compiles in-process,
 exactly the ``backend="thread"`` behavior.
 """
@@ -79,7 +79,7 @@ def default_pool_size() -> int:
     cores (the rest keep the controller thread, transfer pipeline and
     allocator responsive), capped at 16 — beyond that, concurrent XLA:CPU
     program compiles contend on shared emitter state instead of speeding
-    up (bench compile_workers_ab's thread-leg plateau)."""
+    up (seen as a plateau on the CPU tier; no chip run)."""
     cpus = os.cpu_count() or 2
     return max(2, min(16, (cpus * 3) // 4))
 

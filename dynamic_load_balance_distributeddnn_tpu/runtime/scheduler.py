@@ -840,8 +840,7 @@ class MultiStreamEngine:
         """Aggregate pool utilization + per-tenant summary: window count,
         total scheduler wall, the device-idle fraction (1 − busy
         device-seconds / pool capacity over the windows), per-job makespan
-        and migration counts — the quantities bench.py's multistream A/B
-        reports."""
+        and migration counts (read by tests/test_scheduler.py)."""
         cap = 0.0
         busy = 0.0
         for w in self.windows:

@@ -63,8 +63,8 @@ def tag_exit_all(hb_path: str, reason: str) -> None:
 
 
 def tag_exit_reason(hb_path: str, reason: str) -> None:
-    """Write the abort reason INTO the heartbeat file, so the parent (bench
-    retry loop, multi-host peer scanning the heartbeat dir) can tell a
+    """Write the abort reason INTO the heartbeat file, so whoever reads it (a
+    launcher, a multi-host peer scanning the heartbeat dir) can tell a
     watchdog abort apart from a silent freeze or an OOM kill. The tag
     replaces the file's (empty) pulse content; the mtime pulse semantics are
     moot once the process is about to ``os._exit``."""
@@ -185,7 +185,7 @@ def arm_stall_watchdog(
         # first_grace_s — the first unit of work carries the cold compile,
         # which is slow but healthy. Keyed to hb_path's mtime advancing past
         # the arm-time touch: extra_paths get administrative writes (e.g.
-        # the bench's initial incremental-result dump) before any device
+        # kernel_bench's first incremental-result dump) before any device
         # work, which must not end the grace. If the hb file could not be
         # created at all, heartbeats can never land, so the grace could
         # never end — skip it entirely (fail closed at the tight stall_s).

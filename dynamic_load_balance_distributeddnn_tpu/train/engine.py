@@ -309,7 +309,7 @@ class Trainer:
             # run's comm observability, the input of the learned-tree
             # merge and the per-hop codec choice — but it only GATES
             # (falls back to flat) when the operator opted in: forced hier
-            # on a deliberately synthetic split (tests, the bench) must
+            # on a deliberately synthetic split (tests) must
             # stay hier. Multi-host runs skip it entirely: the probe
             # device_puts host-local arrays onto the global mesh
             # (non-addressable from any one process), and a per-process
@@ -876,8 +876,8 @@ class Trainer:
 
     def _comm_bytes_per_step(self) -> "tuple[float, float]":
         """(ICI bytes, DCN bytes) of ONE gradient combine — the logical
-        per-device payload each link class carries, the series the
-        grad_comm bench reports per arm.
+        per-device payload each link class carries (the recorder's
+        ``comm_bytes_*`` series; tests/test_grad_comm.py).
 
         flat: the full f32 tree rides every link it spans — ICI always, DCN
         only when the mesh actually crosses hosts (real processes; a
@@ -1438,7 +1438,7 @@ class Trainer:
         the elastic step for every padded batch shape the balancer can
         produce (multiples of ``bucket`` up to the capacity cap), on every
         used device, by executing dummy steps serially. Kept as the
-        serial-vs-concurrent A/B reference (bench aot_warm_ab) — the AOT
+        serial-vs-concurrent reference (tests/test_aot_compiler.py) — the AOT
         service above is the production path. Without any warm, each
         rebalance's fresh shape pays its XLA compile inside a timed epoch —
         on short benchmark runs the compiles dominate and bury the
@@ -3800,8 +3800,8 @@ class Trainer:
         # Phase set (graftscope): plan_solve -> aot_drain -> train ->
         # speculate -> validate -> record. The phases tile this method, so
         # the trace attributes the epoch span's wall to named segments
-        # (`graftscope summarize` renders the table; the bench asserts
-        # >= 95% coverage on the CPU tier).
+        # (`graftscope summarize` renders the table; tests/test_graftscope.py
+        # asserts >= 95% coverage).
         with tr.span("plan_solve"):
             plan, faults = self._plan_epoch(epoch)
         # epoch-boundary liveness round: catches losses that landed outside
@@ -3926,7 +3926,7 @@ class Trainer:
         # index-aligned with the per-epoch series in the saved artifact
         extras["probe_time"] = probe_s
         if cfg.elastic == "on":
-            # fleet observables: the series the chaos tests/bench read —
+            # fleet observables: the series the chaos tests read —
             # workers_alive steps down on loss and back up on readmission,
             # recoveries counts completed recovery cycles
             extras["workers_alive"] = float(self.world_size)
@@ -3934,7 +3934,7 @@ class Trainer:
         if self._rebalance_ctl is not None:
             # online controller observables: mid-epoch plan switches this
             # epoch (the no-thrash property the tests bound) + the full
-            # ledger snapshot for offline tooling / the bench field
+            # ledger snapshot for offline tooling
             ctl = self._rebalance_ctl
             extras["plan_switches"] = float(ctl.switches - self._switches_last)
             self._switches_last = ctl.switches
@@ -4057,8 +4057,8 @@ class Trainer:
         Compute-mode slow_iters scale with each worker's batch (the injector
         sizes them off ctx.batch_sizes), so comparing raw iters would read
         every rebalance as a new episode and degrade adaptive mode into
-        per-epoch probing — the defect artifacts/SMOOTHING.json's arm B
-        caught. The per-example iteration ratio is plan-invariant."""
+        per-epoch probing (it once did; tests/test_probe_schedule.py holds
+        it). The per-example iteration ratio is plan-invariant."""
         raw = np.asarray(faults.slow_iters_per_step, dtype=np.float64)
         ratio = raw / np.maximum(np.asarray(plan.batch_sizes, dtype=np.float64), 1.0)
         return (
@@ -4777,8 +4777,8 @@ class Trainer:
         self, staged: Dict, win: int, slow_dev, aux_acc, windowed: bool
     ) -> None:
         """Per-step combine cadence, shared by window mode and the legacy
-        per-step mode (superstep="off" — the dispatch-overhead reference the
-        superstep A/B in bench.py measures against). ``windowed`` picks how
+        per-step mode (superstep="off" — the dispatch-overhead reference of
+        tests/test_superstep.py). ``windowed`` picks how
         a worker-step gets its data: ONE window-sliced executable call (the
         step index rides in as a traced scalar, the window slices on device)
         vs host-side slicing plus the single-step executables (one dispatch
@@ -5490,8 +5490,8 @@ class Trainer:
             "dbs_probe_cost": dbs_probe_cost,
             # host-side cost of driving the epoch (enqueue + transfer walls,
             # balance/timing.py HostOverheadMeter) — the quantity the
-            # superstep path exists to shrink; bench.py reports the
-            # per-step value as its dispatch-overhead A/B field
+            # superstep path exists to shrink (tests/test_superstep.py
+            # reads the per-step value)
             "host_dispatch_s": meter.dispatch_s,
             "host_put_s": meter.put_s,
             "host_overhead_per_step_s": meter.per_step(plan.num_steps),
@@ -5647,8 +5647,8 @@ class Trainer:
                     #    the anchor and dt otherwise leaks into the estimate
                     #    and the EMA pumps slow_n without bound;
                     #  - once calibrated, the cost stays FROZEN so every
-                    #    counted epoch injects the same strength — the A/B
-                    #    contract the bench asserts per arm.
+                    #    counted epoch injects the same strength, so two
+                    #    arms of a comparison see the same injection.
                     zero = jax.device_put(jnp.int32(0), topo.devices[d])
                     _, raw_clean, _ = timed(d, args[:-1] + (zero,), fn)
                     # raw-minus-raw: the per-probe dispatch overhead appears

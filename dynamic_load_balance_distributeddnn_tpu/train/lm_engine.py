@@ -97,9 +97,6 @@ class LMTrainer(Trainer):
                 nhid=self.NHID,
                 nlayers=self.NLAYERS,
                 dropout=cfg.lm_dropout if self.DROPOUT is None else self.DROPOUT,
-                # separate knob: flash attention omits attention-prob dropout, a
-                # training-semantics change, so it is NOT tied to use_pallas
-                use_flash=cfg.use_flash_attention,
             )
         self.tx = make_optimizer(cfg.learning_rate, cfg.momentum)
         example = jnp.zeros((1, cfg.bptt), jnp.int32)

@@ -215,9 +215,9 @@ class StepLibrary:
     ) -> "StepLibrary":
         """A minimal library exposing ONLY the ZeRO-1 update spine —
         ``_zero1_update`` + ``_state_spec`` with no model plumbing — for
-        the zero1 A/B bench and the parity tests. Owned HERE so the set of
+        the parity tests (tests/test_zero1.py). Owned HERE so the set of
         attributes the spine reads lives next to the spine: drift breaks
-        at this factory, not at bench time."""
+        at this factory, not in a test's copy."""
         lib = cls.__new__(cls)
         lib.mesh = mesh
         lib.axes = tuple(mesh.axis_names)
@@ -768,8 +768,8 @@ class StepLibrary:
         first. Returns ``(reduced grads tree, new residual tuple)``. The
         tree is raveled ONCE so the whole combine is 2k+1 collectives
         regardless of leaf count (the flat combine pays one psum per
-        leaf); the spine itself lives in parallel/wire.py so the
-        grad_comm bench times the identical code."""
+        leaf); the spine itself lives in parallel/wire.py so
+        tests/test_grad_comm.py drives the identical code."""
         names = self.axes
         sizes = tuple(int(self.mesh.shape[a]) for a in names)
         with jax.named_scope(scopes.COMBINE):

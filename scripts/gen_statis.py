@@ -19,7 +19,7 @@ gloo-on-localhost debug analogue), STATIS_ONLY (comma list of config names
 to run, e.g. "c3_densenet"). Real data is used when present under ./data //
 ./rnn_data (run data/prepare.py first); otherwise the synthetic stand-ins.
 
-Usage: python scripts/gen_statis.py [--out_dir artifacts/acceptance]
+Usage: python scripts/gen_statis.py [--out_dir statis/acceptance]
 """
 
 import argparse
@@ -88,7 +88,7 @@ CONFIGS = {
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--out_dir", default="artifacts/acceptance")
+    ap.add_argument("--out_dir", default="statis/acceptance")
     ns = ap.parse_args()
 
     seed = os.environ.get("STATIS_SEED")

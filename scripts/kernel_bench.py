@@ -1,21 +1,19 @@
 #!/usr/bin/env python
 """Pallas-vs-XLA kernel microbenchmark on the real chip.
 
-For each custom kernel (ops/pallas/: flash attention, fused GroupNorm, fused
-softmax-xent) and each shape the model zoo actually uses — plus the
-long-sequence shapes ring attention targets — time the jitted forward and
-forward+grad against the plain-XLA equivalent the kernel would replace
-(the reference delegates these to cuDNN, SURVEY §2.2; here the alternative
-is stock XLA fusion).
+For each custom kernel behind ``--use_pallas`` (ops/pallas/: fused GroupNorm,
+fused softmax-xent) and each shape the model zoo actually uses, time the
+jitted forward and forward+grad against the plain-XLA equivalent the kernel
+would replace (the reference delegates these to cuDNN, SURVEY §2.2; here the
+alternative is stock XLA fusion).
 
-Writes artifacts/kernel_bench_<platform>.json and a markdown table to
-artifacts/KERNELS.md. The use_pallas / use_flash_attention config defaults
-are chosen from (and justified by) this table.
+Writes <out_dir>/kernel_bench_<platform>.json and a markdown table to
+<out_dir>/KERNELS.md.
 
-Usage: python scripts/kernel_bench.py [--repeats 30] [--quick]
-       python scripts/kernel_bench.py --only cell_attention   (PERF.md's table
-       of the Trinity-Mini cell's attention: blocked XLA against each fused
-       candidate, window and full)
+Usage: python scripts/kernel_bench.py --out_dir DIR [--repeats 30] [--quick]
+       python scripts/kernel_bench.py --out_dir DIR --only cell_attention
+       (PERF.md's table of the Trinity-Mini cell's attention: blocked XLA
+       against each fused candidate, window and full)
 """
 
 import argparse
@@ -38,7 +36,6 @@ import jax
 import jax.numpy as jnp
 
 from dynamic_load_balance_distributeddnn_tpu.ops.losses import per_example_cross_entropy
-from dynamic_load_balance_distributeddnn_tpu.ops.pallas.flash_attention import flash_attention
 from dynamic_load_balance_distributeddnn_tpu.ops.pallas.groupnorm import fused_group_norm
 from dynamic_load_balance_distributeddnn_tpu.ops.pallas.xent import fused_softmax_xent
 
@@ -58,16 +55,6 @@ def timeit(fn, *args, repeats=30):
 # ------------------------------------------------------------ XLA baselines
 
 
-def xla_attention(q, k, v, causal):
-    d = q.shape[-1]
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / jnp.sqrt(d).astype(q.dtype)
-    if causal:
-        t = q.shape[2]
-        mask = jnp.tril(jnp.ones((t, t), bool))
-        s = jnp.where(mask, s, jnp.finfo(s.dtype).min)
-    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
-
-
 def xla_group_norm(x, scale, bias, groups, eps=1e-6):
     shape = x.shape
     c = shape[-1]
@@ -80,40 +67,6 @@ def xla_group_norm(x, scale, bias, groups, eps=1e-6):
 
 
 # ------------------------------------------------------------ benchmark legs
-
-
-def bench_attention(results, dtype, repeats, quick):
-    """LM shapes: the reference transformer is T=35 bptt, 2 heads, d=100
-    (dbs.py:337-343); ring/long-context targets go to 4k."""
-    shapes = [(40, 2, 64, 128), (8, 2, 512, 128), (4, 4, 2048, 128)]
-    if not quick:
-        shapes.append((2, 4, 4096, 128))
-    for b, h, t, d in shapes:
-        kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
-        q = jax.random.normal(kq, (b, h, t, d), dtype)
-        k = jax.random.normal(kk, (b, h, t, d), dtype)
-        v = jax.random.normal(kv, (b, h, t, d), dtype)
-
-        for causal in (True,):
-            pall = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=causal, interpret=False))
-            base = jax.jit(lambda q, k, v: xla_attention(q, k, v, causal))
-            pall_g = jax.jit(jax.grad(lambda q, k, v: flash_attention(q, k, v, causal=causal, interpret=False).sum(), argnums=(0, 1, 2)))
-            base_g = jax.jit(jax.grad(lambda q, k, v: xla_attention(q, k, v, causal).sum(), argnums=(0, 1, 2)))
-            row = {
-                "kernel": "flash_attention",
-                "shape": f"B{b}xH{h}xT{t}xD{d}",
-                "dtype": str(dtype.__name__),
-                "causal": causal,
-            }
-            try:
-                row["fwd_pallas_ms"] = timeit(pall, q, k, v, repeats=repeats) * 1e3
-                row["fwd_xla_ms"] = timeit(base, q, k, v, repeats=repeats) * 1e3
-                row["grad_pallas_ms"] = timeit(pall_g, q, k, v, repeats=repeats) * 1e3
-                row["grad_xla_ms"] = timeit(base_g, q, k, v, repeats=repeats) * 1e3
-            except Exception as e:  # a kernel that won't lower is a result, not a crash
-                row["error"] = f"{type(e).__name__}: {e}"[:300]
-            results.append(row)
-            print(json.dumps(row), flush=True)
 
 
 # ------------------------------------------- the Trinity-Mini cell's attention
@@ -334,7 +287,7 @@ def to_markdown(results, platform, kind):
     return "\n".join(lines) + "\n"
 
 
-LEGS = {"attention": bench_attention, "groupnorm": bench_groupnorm, "xent": bench_xent,
+LEGS = {"groupnorm": bench_groupnorm, "xent": bench_xent,
         "cell_attention": bench_cell_attention}
 
 
@@ -343,9 +296,9 @@ def main():
     ap.add_argument("--repeats", type=int, default=30)
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--dtype", default="bfloat16", choices=["bfloat16", "float32"])
-    ap.add_argument("--out_dir", default="artifacts")
+    ap.add_argument("--out_dir", required=True)
     ap.add_argument("--only", default="", choices=["", *LEGS],
-                    help="run one leg (default: the three kernels' legs)")
+                    help="run one leg (default: groupnorm and xent)")
     ns = ap.parse_args()
 
     dev = jax.devices()[0]
@@ -389,7 +342,7 @@ def main():
                 f.write(to_markdown(self, platform, kind))
 
     results = _IncrementalResults()
-    for leg in [ns.only] if ns.only else ["attention", "groupnorm", "xent"]:
+    for leg in [ns.only] if ns.only else ["groupnorm", "xent"]:
         LEGS[leg](results, dtype, ns.repeats, ns.quick)
     print(f"[kernel_bench] wrote {json_path}")
     return 0

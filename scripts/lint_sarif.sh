@@ -17,7 +17,7 @@
 # deliberately NO baseline file: every finding fails the gate.
 set -u
 cd "$(dirname "$0")/.."
-OUT="${1:-artifacts/lint.sarif}"
+OUT="${1:-statis/lint.sarif}"
 mkdir -p "$(dirname "$OUT")"
 CACHE_ARGS=()
 if [ -n "${GRAFTLINT_CACHE_DIR:-}" ]; then
@@ -25,7 +25,7 @@ if [ -n "${GRAFTLINT_CACHE_DIR:-}" ]; then
 fi
 python -m dynamic_load_balance_distributeddnn_tpu.analysis.cli \
     --flow --format sarif "${CACHE_ARGS[@]}" \
-    dynamic_load_balance_distributeddnn_tpu bench.py > "$OUT"
+    dynamic_load_balance_distributeddnn_tpu > "$OUT"
 rc=$?
 count=$(python - "$OUT" <<'EOF'
 import json, sys

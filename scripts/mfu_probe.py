@@ -19,7 +19,7 @@ E. matmul roofline — a big bf16 matmul timed the same way: what fraction
 F. profiler trace over a few steps, parsed via tensorboard_plugin_profile
    (present in this image) -> device busy fraction + top self-time ops.
 
-Writes artifacts/MFU_PROBE.json incrementally (each section lands as it
+Writes statis/MFU_PROBE.json incrementally (each section lands as it
 completes, so a failure mid-run still leaves the earlier sections).
 
 Usage: python scripts/mfu_probe.py [--cpu] [--quick]
@@ -35,12 +35,12 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-OUT = os.path.join("artifacts", "MFU_PROBE.json")
+OUT = os.path.join("statis", "MFU_PROBE.json")
 RESULT: dict = {"sections": {}}
 
 
 def _save() -> None:
-    os.makedirs("artifacts", exist_ok=True)
+    os.makedirs(os.path.dirname(OUT), exist_ok=True)
     tmp = OUT + ".tmp"
     with open(tmp, "w") as f:
         json.dump(RESULT, f, indent=1)

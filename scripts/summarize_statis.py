@@ -7,7 +7,7 @@ terminal view, and computes the dbs-on/off A/B headline when both arms of a
 config are present in the directory.
 
 Usage:
-  python scripts/summarize_statis.py artifacts/acceptance/statis [more dirs/files]
+  python scripts/summarize_statis.py statis/acceptance/statis [more dirs/files]
 """
 
 import json
@@ -104,7 +104,7 @@ def main(argv):
         on_w = np.diff([0.0] + list(d["wallclock_time"]))
         off_w = np.diff([0.0] + list(off["wallclock_time"]))
         # steady state: skip the calibration epoch (and first reaction, on-arm);
-        # median headline + min alongside, like bench.py's hardened statistic
+        # median headline + min alongside
         on_win = on_w[2:] if len(on_w) > 2 else on_w[-1:]
         off_win = off_w[1:] if len(off_w) > 1 else off_w[-1:]
         on_med, off_med = float(np.median(on_win)), float(np.median(off_win))

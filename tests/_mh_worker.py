@@ -1,7 +1,7 @@
 """Multi-host worker: one process of a multi-process × 2-virtual-device run.
 
-Launched by tests/test_multihost.py (and bench.py's ``elastic_mh_recovery_ab``
-leg) as ``python _mh_worker.py <proc_id> <num_procs> <port>``. Three modes:
+Launched by tests/test_multihost.py as
+``python _mh_worker.py <proc_id> <num_procs> <port>``. Three modes:
 
 * default — the PR-2 era integration run: trains MnistNet with ws=4 workers
   split across the processes (elastic DBS path with a deterministic 3:1

@@ -296,7 +296,7 @@ def test_scan_speculation_precompiles_predicted_tuple(bundle):
 
 def test_aot_off_keeps_legacy_warm(bundle):
     """--aot_warm off: no service, the legacy execute-to-compile warm runs
-    (the A/B reference leg bench.py measures against)."""
+    (the serial reference leg)."""
     cfg = _cfg(warm_start=True, aot_warm=False, epoch_size=1)
     tr = Trainer(cfg, bundle=bundle, timing_model=linear_time, log_to_file=False)
     assert tr._aot is None

@@ -77,6 +77,33 @@ def test_fused_attention_takes_the_blocked_forms_precision():
     assert np.abs(fused - want).max() <= 1.5 * np.abs(blocked - want).max()
 
 
+# float32 and small, ragged shapes (a T the block does not divide, a head size
+# off every tile, a last block under the window): what every call the kernel
+# does not serve runs
+@pytest.mark.parametrize("t,d,block_q,window,grad", [
+    (35, 25, 16, None, False), (48, 32, 16, None, False), (96, 16, 32, None, False),
+    (80, 16, 32, 24, False), (32, 16, 16, None, True), (32, 16, 16, 24, True),
+    (35, 25, 16, None, True), (80, 16, 32, 24, True),
+], ids=str)
+def test_blocked_attention_agrees_with_dense_in_float32(t, d, block_q, window, grad):
+    rng = np.random.RandomState(t + d)
+    q = jnp.asarray(rng.randn(2, t, 4, d) * 0.5, jnp.float32)
+    k = jnp.asarray(rng.randn(2, t, 2, d) * 0.5, jnp.float32)
+    v = jnp.asarray(rng.randn(2, t, 2, d), jnp.float32)
+    forms = (lambda q, k, v: _blocked(q, k, v, window, block_q),
+             lambda q, k, v: dense_attention(q, k, v, window))
+    if not grad:
+        got, want = (form(q, k, v) for form in forms)
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+        return
+    target = jnp.asarray(rng.randn(*q.shape), jnp.float32)
+    got, want = (
+        jax.grad(lambda q, k, v: jnp.sum((form(q, k, v) - target) ** 2), argnums=(0, 1, 2))(q, k, v)
+        for form in forms)
+    for name, g, r in zip("qkv", got, want):
+        np.testing.assert_allclose(g, r, atol=5e-4, rtol=5e-4, err_msg=f"d{name}")
+
+
 def test_fused_attention_refuses_a_ragged_t():
     q, k, v, _ = operands(8, 1)
     with pytest.raises(ValueError, match="must divide by the blocks"):
@@ -99,3 +126,17 @@ def test_the_blocked_form_is_taken_and_says_why(case, why):
                      "dtype": str(q.dtype)}]
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(_blocked(q, k, v, 256, 256), np.float32))
+
+
+def test_no_option_selects_an_attention_kernel(capsys):
+    """The attention is chosen by the code (the trainer's mesh for the
+    paper's LM, the call and the lowering's platform for the routed decoder):
+    the switch that once chose a kernel by hand is refused, not ignored."""
+    import dataclasses
+
+    from dynamic_load_balance_distributeddnn_tpu.config import Config, get_parser
+
+    with pytest.raises(SystemExit):
+        get_parser().parse_args(["-m", "transformer", "--use_flash_attention", "true"])
+    assert "unrecognized arguments: --use_flash_attention" in capsys.readouterr().err
+    assert not [f.name for f in dataclasses.fields(Config) if "flash" in f.name]

@@ -2,7 +2,7 @@
 functions must do what they say at a size the CPU tier affords.
 
 Also the placement rule of the persistent compile cache (compile_cache.py),
-which the smoke, the CLI, bench.py and this suite's conftest all share.
+which the smoke, the CLI and this suite's conftest all share.
 """
 
 import os
@@ -34,6 +34,25 @@ def test_script_refuses_to_run_without_a_tpu(args, tmp_path):
     assert proc.returncode != 0
     assert '"ok": true' not in proc.stdout
     assert "no TPU" in proc.stderr
+
+
+@pytest.mark.parametrize(
+    "kind,shape",
+    [(kind, shape) for _, kind, shape in chip_smoke.KERNEL_CASES],
+    ids=[case_id for case_id, _, _ in chip_smoke.KERNEL_CASES],
+)
+def test_kernel_case_and_its_reference_have_one_shape(kind, shape):
+    """What ``kernels_phase`` compares on the chip is comparable: the kernel
+    and its XLA reference return the same shapes, the kernel in its operands'
+    dtype and a loss in float32 (nothing runs; the compile is
+    ``tests/test_chip_compile.py``'s)."""
+    import jax
+    import jax.numpy as jnp
+
+    kernel_fn, reference_fn, specs, _ = chip_smoke.kernel_case(kind, shape)
+    got = jax.eval_shape(kernel_fn, *specs)
+    assert got.shape == jax.eval_shape(reference_fn, *specs).shape
+    assert got.dtype == (jnp.float32 if kind == "xent" else specs[0].dtype)
 
 
 def test_cnn_phase_trains_and_reports(tmp_path):

@@ -228,8 +228,8 @@ def test_e2e_eight_workers_heterogeneous_map(bundle, tmp_path):
 
 @pytest.mark.slow
 def test_e2e_bfloat16_mixed_precision(bundle, tmp_path):
-    """bf16 compute + f32 master weights (the TPU MXU's native dtype, used by
-    bench.py): training must run and reduce loss like the f32 path, and the
+    """bf16 compute + f32 master weights (the TPU MXU's native dtype, what
+    both benchmark cells run): training must run and reduce loss like the f32 path, and the
     master params must stay f32."""
     import jax
     import jax.numpy as jnp

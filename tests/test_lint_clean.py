@@ -1,9 +1,8 @@
 """Tier-1 gate: the shipped tree must lint clean — single-file AND flow.
 
-Any future PR that reintroduces a G00x violation in the package or bench.py
-fails the default fast pytest run right here — the CI half of the ISSUE-1
-contract (`graftlint dynamic_load_balance_distributeddnn_tpu bench.py`
-exits 0). Since ISSUE 8 the gate also runs the whole-program rules with NO
+Any future PR that reintroduces a G00x violation in the package fails the
+default fast pytest run right here — the CI half of the ISSUE-1 contract
+(`graftlint dynamic_load_balance_distributeddnn_tpu` exits 0). Since ISSUE 8 the gate also runs the whole-program rules with NO
 baseline file (`--flow`: G011 donation lifetimes, G012 thread/lock
 discipline, G013 stale-mesh placement, since ISSUE 10 the graftmesh
 families — G014 collective/axis consistency, G015 sharding-spec flow, G016
@@ -23,10 +22,7 @@ import subprocess
 from dynamic_load_balance_distributeddnn_tpu.analysis.cli import main as cli_main
 
 REPO = pathlib.Path(__file__).resolve().parents[1]
-TARGETS = [
-    str(REPO / "dynamic_load_balance_distributeddnn_tpu"),
-    str(REPO / "bench.py"),
-]
+TARGETS = [str(REPO / "dynamic_load_balance_distributeddnn_tpu")]
 
 
 def test_shipped_tree_lints_clean(capsys):

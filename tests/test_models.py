@@ -46,45 +46,6 @@ def test_cnn_families_exact_param_parity(name, nc, expect):
     assert n == expect, f"{name}: {n:,} params != reference {expect:,}"
 
 
-def test_densenet_default_is_concat():
-    """Round-5 on-chip verdict (pre-ledger record, deleted in PR 21): the literal
-    concat dataflow beats the round-4 buffer fill on XLA:TPU (87 vs 129
-    ms/step, -20% bytes by the TPU cost model), so every default-built
-    DenseNet must run it."""
-    from dynamic_load_balance_distributeddnn_tpu.models.densenet import DenseNet121
-
-    assert DenseNet121().use_buffer is False
-
-
-def test_densenet_buffer_matches_concat():
-    """The dense block's pre-allocated right-to-left buffer (round 4's
-    byte-cut bet, kept as an equivalence oracle after the round-5 on-chip
-    measurement went to concat — models/densenet.py docstring) is
-    numerically the reference's nested concat: same param tree,
-    bitwise-equal forward, grads equal to fp tolerance."""
-    from dynamic_load_balance_distributeddnn_tpu.models.densenet import DenseNet
-
-    m_buf = DenseNet((3, 4), growth_rate=32, num_classes=10, use_buffer=True)
-    m_cat = DenseNet((3, 4), growth_rate=32, num_classes=10, use_buffer=False)
-    x = jnp.asarray(np.random.RandomState(0).randn(4, 32, 32, 3), jnp.float32)
-    p1 = m_buf.init(jax.random.PRNGKey(0), x, train=False)
-    p2 = m_cat.init(jax.random.PRNGKey(0), x, train=False)
-    assert jax.tree_util.tree_structure(p1) == jax.tree_util.tree_structure(p2)
-    for a, b in zip(jax.tree_util.tree_leaves(p1), jax.tree_util.tree_leaves(p2)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-    o1 = m_buf.apply(p1, x, train=False)
-    o2 = m_cat.apply(p1, x, train=False)
-    np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
-
-    g1 = jax.grad(lambda p: jnp.sum(m_buf.apply(p, x, train=False) ** 2))(p1)
-    g2 = jax.grad(lambda p: jnp.sum(m_cat.apply(p, x, train=False) ** 2))(p1)
-    for a, b in zip(jax.tree_util.tree_leaves(g1), jax.tree_util.tree_leaves(g2)):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5
-        )
-
-
 @pytest.mark.slow
 def test_googlenet_fixed_runs():
     spec = build_model("googlenet", num_classes=10)
@@ -110,38 +71,6 @@ def test_outputs_finite_on_random_input():
         spec = build_model(name, num_classes=10)
         out, _ = _init_and_apply(spec, x)
         assert np.isfinite(np.asarray(out)).all(), name
-
-
-def test_transformer_flash_attention_variant():
-    """The use_flash TransformerLM (Pallas flash attention) produces outputs
-    close to the masked-MHA variant's math on the same input distribution and
-    trains (grads finite)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from dynamic_load_balance_distributeddnn_tpu.models import build_model
-
-    spec = build_model(
-        "transformer", ntoken=50, ninp=32, nhead=2, nhid=32, nlayers=1,
-        dropout=0.0, use_flash=True,
-    )
-    tokens = jnp.asarray(np.random.RandomState(0).randint(0, 50, (2, 20)), jnp.int32)
-    params = spec.module.init({"params": jax.random.PRNGKey(0)}, tokens, train=False)
-    out = spec.module.apply(params, tokens, train=False)
-    assert out.shape == (2, 20, 50)
-    assert bool(jnp.isfinite(out).all())
-
-    def loss(p):
-        return jnp.sum(spec.module.apply(p, tokens, train=False) ** 2)
-
-    g = jax.grad(loss)(params)
-    flat = jax.tree_util.tree_leaves(g)
-    assert all(bool(jnp.isfinite(x).all()) for x in flat)
-    # causality: output at position t must not depend on tokens after t
-    tokens2 = tokens.at[:, -1].set((tokens[:, -1] + 1) % 50)
-    out2 = spec.module.apply(params, tokens2, train=False)
-    np.testing.assert_allclose(out[:, :-1], out2[:, :-1], atol=1e-5)
 
 
 def test_grouped_conv_decompose_matches_grouped():

@@ -192,7 +192,7 @@ def test_skipped_epochs_report_cached_sync_time(bundle):
 
 
 def test_adaptive_skips_with_compute_injection(bundle):
-    """Regression (artifacts/SMOOTHING.md arm B, first run): compute-mode
+    """Regression (seen once under a 3:1 compute straggler): compute-mode
     slow_iters scale with each worker's batch, so a naive episode signature
     read every rebalance as a new episode and probed every epoch. The
     plan-normalized iters-per-example ratio must keep skipping."""

@@ -172,8 +172,8 @@ def test_superstep_compiles_once_per_shape_window(bundle):
 
 
 def test_superstep_host_overhead_metered(bundle):
-    """The elastic epoch reports its host dispatch/put walls (the quantity
-    bench.py's dispatch-overhead A/B compares across paths)."""
+    """The elastic epoch reports its host dispatch/put walls (what the
+    superstep path exists to shrink)."""
     tr, rec = _run(bundle, superstep="auto", epochs=1)
     assert rec.data["host_overhead_per_step_s"], "meter series missing"
     v = rec.data["host_overhead_per_step_s"][-1]

@@ -32,7 +32,8 @@ def test_ulysses_matches_full_attention(causal):
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
-def test_ulysses_grad_matches():
+@pytest.mark.parametrize("causal", [False, True])
+def test_ulysses_grad_matches(causal):
     devices = jax.devices()
     mesh = data_mesh(devices)
     n = len(devices)
@@ -43,12 +44,12 @@ def test_ulysses_grad_matches():
     k = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
     v = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
 
-    uly = make_ulysses_attention_fn(mesh, causal=True)
+    uly = make_ulysses_attention_fn(mesh, causal=causal)
 
     g_uly = np.asarray(jax.grad(lambda q: jnp.sum(uly(q, k, v) ** 2))(q))
     g_ref = np.asarray(
         jax.grad(
-            lambda q: jnp.sum(reference_attention(q, k, v, causal=True) ** 2)
+            lambda q: jnp.sum(reference_attention(q, k, v, causal=causal) ** 2)
         )(q)
     )
     np.testing.assert_allclose(g_uly, g_ref, atol=5e-5, rtol=5e-5)

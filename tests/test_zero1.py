@@ -65,7 +65,7 @@ def _int_grads(seed):
 def _zero1_lib(mesh, tx, padded, *, hier=False, wire="fp32", compress=""):
     """The production-owned shell exposing ONLY the shipped ZeRO-1 update
     math — the same code object production dispatches, minus the model
-    plumbing (StepLibrary.zero1_shell, shared with the zero1_ab bench)."""
+    plumbing (StepLibrary.zero1_shell)."""
     return StepLibrary.zero1_shell(
         mesh, tx, padded, hier=hier, wire=wire, compress=compress
     )

@@ -68,12 +68,14 @@ MULTICHIP_LOSS_TOL = 1e-2
 # (id, kind, shape): the widths the main paths really run. attention: the
 # routed decoder's fused kernel (ops/attention.py takes it for bfloat16 calls
 # lowered for a TPU), queries [B,T,H,D] over one key-value head, with a window
-# of T/2 and without; groupnorm: DenseNet-121's first and
+# of T/2 and without, at both head sizes the decoders have; groupnorm: DenseNet-121's first and
 # last stage at per-worker batch 128, [B,H,W,C]; xent: the CNN criterion at
 # B 512 x 10 classes and the LM's 20x35 tokens over the wikitext-2 vocab.
 KERNEL_CASES = (
     ("fused_attention_window512_b2_t1024_h8_d128", "attention_window", (2, 1024, 8, 128)),
     ("fused_attention_full_b2_t1024_h8_d128", "attention_full", (2, 1024, 8, 128)),
+    # Qwen3-Next's full layers: heads of 256 in groups of 8 query heads
+    ("fused_attention_full_b2_t1024_h8_d256", "attention_full", (2, 1024, 8, 256)),
     ("groupnorm_relu_128x32x32x64", "groupnorm", (128, 32, 32, 64)),
     ("groupnorm_relu_128x8x8x512", "groupnorm", (128, 8, 8, 512)),
     ("xent_512x10", "xent", (512, 10)),

@@ -29,7 +29,7 @@ MODELS = [
 DATASETS = ["cifar10", "cifar100", "mnist", "wikitext2"]
 # --lm_arch by name: "paper", or a published config kept as models/<name>.json.
 # Any other value is the path of such a file (tests bring theirs at test widths)
-LM_ARCHS = ["paper", "trinity_mini"]
+LM_ARCHS = ["paper", "trinity_mini", "qwen3_next"]
 
 
 def str2bool(v) -> bool:

@@ -15,9 +15,14 @@ transformer.
 from __future__ import annotations
 
 import dataclasses
+import importlib
 from typing import Any, Callable
 
 import flax.linen as nn
+
+
+# model_type of a published config.json -> the module's class in models/<model_type>.py
+PUBLISHED_FAMILIES = {"afmoe": "AFMoELM", "qwen3_next": "Qwen3NextLM"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -38,6 +43,12 @@ class ModelSpec:
     # the module places its own checkpoints under --remat (one a block); the
     # step then adds none around the whole forward
     own_remat: bool = False
+    # the scanned superstep ties each worker's step to the one before it, as
+    # workers that share a chip run: left free, XLA:TPU runs the workers'
+    # linear-attention layers side by side and holds every worker's
+    # activations at once (15.1 GB of temporaries against 8.0 GB at the
+    # Qwen3-Next cell's size, compiled for a described v5e; PERF.md, PR 32)
+    serial_workers: bool = False
 
     @property
     def train_aux(self) -> bool:
@@ -82,16 +93,20 @@ def build_model(name: str, num_classes: int = 10, **kw) -> ModelSpec:
     ctor = _cnn_constructor(name)
     if ctor is not None:
         return ModelSpec(name, ctor(num_classes=num_classes), "logits", "image")
-    if name == "afmoe":
+    if name in PUBLISHED_FAMILIES:
+        # a published decoder, by the `model_type` of its config.json
         from dynamic_load_balance_distributeddnn_tpu.models import afmoe
 
+        family = importlib.import_module(f"{__name__}.{name}")
         pub = afmoe.published(kw["arch"])
-        if pub.get("model_type") != "afmoe":
-            raise ValueError(f"{kw['arch']!r} is not an afmoe architecture")
-        cfg = afmoe.cut_config(pub, kw["ntoken"], kw.get("layers", ()), kw.get("experts_held"))
-        return ModelSpec(name, afmoe.AFMoELM(cfg, remat=bool(kw.get("remat", False))), "logits",
-                         "tokens", aux_shape=(cfg.layer_dense.count(False), cfg.experts_held + 1),
-                         f32_leaves=afmoe.F32_LEAVES, own_remat=True)
+        if pub.get("model_type") != name:
+            raise ValueError(f"{kw['arch']!r}: its model_type is not {name!r}")
+        cfg = family.cut_config(pub, kw["ntoken"], kw.get("layers", ()), kw.get("experts_held"))
+        module = getattr(family, PUBLISHED_FAMILIES[name])(cfg, remat=bool(kw.get("remat", False)))
+        return ModelSpec(name, module, "logits", "tokens",
+                         aux_shape=(family.expert_layers(cfg), cfg.experts_held + 1),
+                         f32_leaves=family.F32_LEAVES, own_remat=True,
+                         serial_workers=family.SERIAL_WORKERS)
     if name == "transformer":
         from dynamic_load_balance_distributeddnn_tpu.models.transformer import (
             TransformerLM,

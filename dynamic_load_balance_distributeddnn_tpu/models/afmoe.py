@@ -46,6 +46,7 @@ from dynamic_load_balance_distributeddnn_tpu.ops.attention import (
 
 INIT_STD = 0.02
 F32_LEAVES = ("router",)  # leaves the step's bfloat16 cast leaves alone
+SERIAL_WORKERS = False  # ModelSpec.serial_workers: its superstep fits with the workers left free
 
 
 def published(arch: str) -> dict:
@@ -104,6 +105,10 @@ def cut_config(pub: dict, vocab_size: int, layers: Sequence[int] = (),
         layer_dense=tuple(i < pub["num_dense_layers"] for i in kept),
         first_expert=first, experts_held=end - first, **{k: pub[k] for k in same},
     )
+
+
+def expert_layers(cfg: AFMoEConfig) -> int:
+    return cfg.layer_dense.count(False)
 
 
 def _kernel(module: nn.Module, name: str, shape) -> jnp.ndarray:

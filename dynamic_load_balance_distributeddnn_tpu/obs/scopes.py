@@ -40,7 +40,7 @@ INJECT = "inject"      # the injected synthetic load of a straggler
 COMBINE = "combine"    # gradient sums and collectives, the metrics' psum
 UPDATE = "update"      # the optimizer's update and its application
 EVAL = "eval"          # the evaluation step, whole
-# parts of a decoder's forward pass (models/afmoe.py). The innermost scope
+# parts of a decoder's forward pass (models/afmoe.py, models/qwen3_next.py). The innermost scope
 # wins, so these take their time, forward and transposed alike, out of
 # `forward` and `backward`.
 ATTENTION_WINDOW = "attention_window"  # a window layer's attention, projections to output
@@ -49,9 +49,13 @@ ROUTER = "router"                      # scores, top-k and routing weights
 EXPERTS = "experts"                    # dispatch, grouped products, weighted combine
 SHARED_EXPERT = "shared_expert"
 LM_HEAD = "lm_head"                    # the product with the output vocabulary
+LINEAR_ATTENTION = "linear_attention"  # a linear layer's mixer: projections, convolution,
+                                       # norms, gate and output, around `delta_rule`
+DELTA_RULE = "delta_rule"              # the gated delta rule alone (ops/linear_attention.py)
 
 SCOPES = (AUGMENT, FORWARD, BACKWARD, CLIP, INJECT, COMBINE, UPDATE, EVAL,
-          ATTENTION_WINDOW, ATTENTION_FULL, ROUTER, EXPERTS, SHARED_EXPERT, LM_HEAD)
+          ATTENTION_WINDOW, ATTENTION_FULL, ROUTER, EXPERTS, SHARED_EXPERT, LM_HEAD,
+          LINEAR_ATTENTION, DELTA_RULE)
 MAP_FILE = "hlo_scopes.jsonl"
 
 _WRAPPED = re.compile(r"^(\w+)\((.*)\)$")

@@ -1,7 +1,7 @@
 """Routed experts for a chip that holds a share of them.
 
 ``route`` scores every token against ALL published experts (float32 product,
-sigmoid, top-k, as the published code does it) and ``expert_ffn`` is told
+sigmoid or softmax, top-k, as the published code does it) and ``expert_ffn`` is told
 which experts this chip holds: it computes what those give and nothing for
 the rest, which other chips of the deployment would add. No token routed to a
 held expert is dropped, whatever the routing, and the work follows the tokens
@@ -24,27 +24,32 @@ the memory held is one chunk's whatever arrives.
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 
+SCORES = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}
+
+
 def route(
-    m: jnp.ndarray, w_router: jnp.ndarray, expert_bias: jnp.ndarray, k: int,
-    route_norm: bool, route_scale: float,
+    m: jnp.ndarray, w_router: jnp.ndarray, expert_bias: Optional[jnp.ndarray], k: int,
+    route_norm: bool, route_scale: float, score_func: str = "sigmoid",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``(chosen experts [N, k], their weights [N, k] float32)`` of tokens
-    ``m`` ``[N, d]``. Scores are ``sigmoid(m @ w_router)`` in float32 over all
-    experts; the ``k`` largest of ``scores + expert_bias`` are chosen; the
-    weights are the scores at the chosen experts, divided by their sum where
-    ``route_norm``, times ``route_scale``."""
-    scores = jax.nn.sigmoid(
+    ``m`` ``[N, d]``. Scores are ``score_func`` (``sigmoid``, or ``softmax``
+    over all experts) of ``m @ w_router`` in float32; the ``k`` largest of
+    ``scores + expert_bias`` (of the scores, for a model that has no bias:
+    ``None``) are chosen; the weights are the scores at the chosen experts,
+    divided by their sum where ``route_norm``, times ``route_scale``."""
+    scores = SCORES[score_func](
         jnp.dot(m.astype(jnp.float32), w_router.astype(jnp.float32),
                 precision=lax.Precision.HIGHEST)
     )
-    _, chosen = lax.top_k(scores + expert_bias.astype(jnp.float32), k)
+    ranked = scores if expert_bias is None else scores + expert_bias.astype(jnp.float32)
+    _, chosen = lax.top_k(ranked, k)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
     if route_norm:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)

@@ -36,6 +36,7 @@ from dynamic_load_balance_distributeddnn_tpu.data.partitioner import (
     partition_indices,
 )
 from dynamic_load_balance_distributeddnn_tpu.models import build_model
+from dynamic_load_balance_distributeddnn_tpu.models.afmoe import published
 from dynamic_load_balance_distributeddnn_tpu.parallel.mesh import replicated_sharding
 from dynamic_load_balance_distributeddnn_tpu.train.engine import Trainer
 from dynamic_load_balance_distributeddnn_tpu.train.state import create_state, make_optimizer
@@ -78,10 +79,10 @@ class LMTrainer(Trainer):
         set_use_pallas(cfg.use_pallas)
         if cfg.lm_arch != "paper":
             # a published architecture (models/<lm_arch>.json, or the file
-            # --lm_arch names), cut as the command line says; the
-            # vocabulary is the corpus's
+            # --lm_arch names), cut as the command line says; its family is
+            # the file's model_type; the vocabulary is the corpus's
             self.spec = build_model(
-                "afmoe",
+                published(cfg.lm_arch)["model_type"],
                 arch=cfg.lm_arch,
                 ntoken=self.corpus.ntokens,
                 layers=cfg.lm_kept_layers(),

@@ -489,8 +489,13 @@ class StepLibrary:
         acc = None
         aux = []
         for i in range(len(ws_)):
+            x = xs[i]
+            if self.spec.serial_workers and acc is not None:
+                # this worker's step waits for the last one's gradient (see
+                # ModelSpec.serial_workers)
+                x, acc = jax.lax.optimization_barrier((x, acc))
             g, wloss, loss_sum, count, probe, counts = self._local_grads(
-                state.params, xs[i], ys[i], ws_[i], ks[i], slows[i], ks[i]
+                state.params, x, ys[i], ws_[i], ks[i], slows[i], ks[i]
             )
             with jax.named_scope(scopes.COMBINE):
                 if acc is None:

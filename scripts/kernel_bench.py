@@ -12,6 +12,7 @@ Writes <out_dir>/kernel_bench_<platform>.json and a markdown table to
 
 Usage: python scripts/kernel_bench.py --out_dir DIR [--repeats 30] [--quick]
        python scripts/kernel_bench.py --out_dir DIR --only cell_attention
+       python scripts/kernel_bench.py --out_dir DIR --only delta_rule
        (PERF.md's table of the Trinity-Mini cell's attention: blocked XLA
        against each fused candidate, window and full)
 """
@@ -181,6 +182,62 @@ def bench_cell_attention(results, dtype, repeats, quick):
             print(json.dumps(row), flush=True)
 
 
+def bench_delta_rule(results, dtype, repeats, quick):
+    """The mixers of ``qwen3_next.ws4_even_dbs`` as one worker's step calls
+    them, 2 columns of 4,096 tokens: the chunked gated delta rule alone (32
+    heads of 128 x 128, plain XLA; its FLOPs are the recurrence's three
+    products a token and head, whatever the chunked form computes) and, beside
+    it, the full layers' attention (16 query / 2 key-value heads of 256)."""
+    del repeats, quick
+    from dynamic_load_balance_distributeddnn_tpu.ops import attention
+    from dynamic_load_balance_distributeddnn_tpu.ops.linear_attention import gated_delta_rule
+
+    peak = bf16_peak()
+    b, t, h, d = 2, 4096, 32, 128
+    keys = jax.random.split(jax.random.PRNGKey(5), 6)
+
+    def unit(key):
+        x = jax.random.normal(key, (b, t, h, d), jnp.float32)
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    rule = ((unit(keys[0]) * d ** -0.5).astype(dtype), unit(keys[1]).astype(dtype),
+            jax.random.normal(keys[2], (b, t, h, d), dtype),
+            -jax.random.uniform(keys[3], (b, t, h), jnp.float32, 0.0, 2.0),
+            jax.random.uniform(keys[4], (b, t, h), jnp.float32))
+    hq, hkv, dq = 16, 2, 256
+    attn = (jax.random.normal(keys[0], (b, t, hq, dq), dtype),
+            jax.random.normal(keys[1], (b, t, hkv, dq), dtype),
+            jax.random.normal(keys[2], (b, t, hkv, dq), dtype))
+    pairs = b * hq * t * (t + 1) // 2
+    cases = (
+        ("delta_rule", "chunked_xla_64", gated_delta_rule, rule, f"B{b}xT{t}xH{h}xD{d}x{d}",
+         3 * 2 * b * t * h * d * d, 3),
+        ("cell_attention_256", "blocked_xla_256",
+         lambda q, k, v: attention._blocked(q, k, v, None, 256), attn,
+         f"B{b}xT{t}xH{hq}/{hkv}xD{dq}", 4 * dq * pairs, 3.5),
+        ("cell_attention_256", "default_path", attention.blocked_causal_attention, attn,
+         f"B{b}xT{t}xH{hq}/{hkv}xD{dq}", 4 * dq * pairs, 3.5),
+    )
+    for kernel, form, fn, args, shape, fwd_flops, bwd_over_fwd in cases:
+        row = {"kernel": kernel, "form": form, "shape": shape, "dtype": str(dtype.__name__)}
+        try:
+            w = jax.random.normal(keys[5], args[2].shape[:2] + args[0].shape[2:3]
+                                  + args[2].shape[3:], jnp.float32)
+            grad = jax.jit(jax.grad(
+                lambda *a, fn=fn: jnp.sum(fn(*a[:-1]).astype(jnp.float32) * a[-1]),
+                argnums=tuple(range(len(args)))))
+            row["fwd_ms"] = best_of(jax.jit(fn), *args) * 1e3
+            row["fwd_bwd_ms"] = best_of(grad, *args, w) * 1e3
+            if peak:
+                row["fwd_pct_of_peak"] = 100 * fwd_flops / (row["fwd_ms"] * 1e-3) / peak
+                row["fwd_bwd_pct_of_peak"] = (
+                    100 * bwd_over_fwd * fwd_flops / (row["fwd_bwd_ms"] * 1e-3) / peak)
+        except Exception as e:  # a form the compiler refuses is a result
+            row["error"] = f"{type(e).__name__}: {e}"[:300]
+        results.append(row)
+        print(json.dumps(row), flush=True)
+
+
 def bench_groupnorm(results, dtype, repeats, quick):
     """CNN shapes: 32x32 CIFAR maps through the zoo's widths, GroupNorm(32)
     (Net/Resnet.py:11-13); batch = per-worker 128 of the B=512/ws=4 recipe."""
@@ -270,7 +327,7 @@ def to_markdown(results, platform, kind):
         "|---|---|---|---|---|---|---|---|---|",
     ]
     for r in results:
-        if r["kernel"] == "cell_attention":
+        if r["kernel"] in ("cell_attention", "cell_attention_256", "delta_rule"):
             continue  # printed as JSON lines and kept in the .json: other columns
         if "error" in r:
             lines.append(
@@ -288,7 +345,7 @@ def to_markdown(results, platform, kind):
 
 
 LEGS = {"groupnorm": bench_groupnorm, "xent": bench_xent,
-        "cell_attention": bench_cell_attention}
+        "cell_attention": bench_cell_attention, "delta_rule": bench_delta_rule}
 
 
 def main():

@@ -115,3 +115,47 @@ def test_counts_are_not_written_with_the_tracer_off(tmp_path):
     get_tracer().configure("off", trace_dir=str(tmp_path))
     routing.record_epoch(0, [np.arange(10.0)], (2, 5))
     assert not os.path.exists(tmp_path / routing.COUNTS_FILE)
+
+
+# sha256 of the lowered one-step superstep (four workers of two columns x 24
+# tokens, the cut of ARGV) as commit 43d4027 lowers it under jax 0.9.0. A
+# second decoder family shares `ops/moe.py`, `ops/attention.py`, the step
+# library and `ModelSpec` with this one: an edit there that moves this
+# family's program moves Trinity-Mini's, its bytes and its warm cache. After a
+# deliberate change (or a new jax), lower the parent's and this tree's and
+# commit the new value only if the two agree.
+SUPERSTEP_SHA256 = {
+    "bfloat16": "aacfe3584889aaeef78fedadd11ebd2b8e06143aa42976607b155c5a4c03191a",
+    "float32": "0d270149666c4796f524105bf88f1387f57d69f35b60255ed7faf773cc2f20a3",
+}
+
+
+@pytest.mark.parametrize("precision", sorted(SUPERSTEP_SHA256))
+def test_the_lowered_superstep_is_the_parents_to_the_letter(precision):
+    import hashlib
+
+    from jax.sharding import Mesh
+
+    from dynamic_load_balance_distributeddnn_tpu.train.state import TrainState, make_optimizer
+    from dynamic_load_balance_distributeddnn_tpu.train.steps import StepLibrary
+
+    spec = build_model("afmoe", arch=TINY, ntoken=64, layers=[1, 4, 5, 6, 7],
+                       experts_held=(0, 4), remat=True)
+    tx = make_optimizer(0.05, 0.9)
+
+    def init_fn(key):
+        params = spec.module.init({"params": key}, jnp.zeros((1, 24), jnp.int32), train=False)
+        return TrainState(params=params, opt_state=tx.init(params),
+                          step=jnp.zeros((), jnp.int32))
+
+    state = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    lib = StepLibrary(spec, Mesh(np.array(jax.devices()[:1]), ("data",)), tx, grad_clip=0.25,
+                      compute_dtype=jnp.bfloat16 if precision == "bfloat16" else None, remat=True)
+    sds = jax.ShapeDtypeStruct
+    key = jax.eval_shape(lambda: jax.random.split(jax.random.PRNGKey(0), 1))
+    tokens = tuple(sds((1, 2, 24), jnp.int32) for _ in range(4))
+    weights = tuple(sds((1, 2, 24), jnp.float32) for _ in range(4))
+    keys = tuple(sds(key.shape, key.dtype) for _ in range(4))
+    slows = tuple(sds((), jnp.int32) for _ in range(4))
+    text = lib.group_superstep.lower(state, tokens, tokens, weights, keys, slows).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == SUPERSTEP_SHA256[precision]

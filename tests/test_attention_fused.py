@@ -67,6 +67,28 @@ def test_fused_attention_agrees_with_dense(heads, window, blocks):
         assert np.linalg.norm(g - r) <= 0.01 * np.linalg.norm(r), name
 
 
+@pytest.mark.parametrize("blocks", [(128, 128), (256, 128)], ids=str)
+def test_fused_attention_at_heads_of_256_in_groups_of_8(blocks):
+    """Qwen3-Next's full layers: 16 query heads of 256 over 2 key-value heads,
+    no window. The kernel's tiles are the head's 256 lanes wide."""
+    q, k, v, w = operands(16, 2, d=256)
+    want = dense_attention(q, k, v, None)
+    got = fused_causal_attention(q, k, v, None, *blocks, interpret=True)
+    assert got.dtype == jnp.bfloat16 and got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=0.02)
+
+    def loss(attention):
+        return lambda q, k, v: jnp.sum(attention(q, k, v).astype(jnp.float32) * w)
+
+    grads = jax.grad(loss(lambda q, k, v: fused_causal_attention(
+        q, k, v, None, *blocks, interpret=True)), argnums=(0, 1, 2))(q, k, v)
+    wants = jax.grad(loss(lambda q, k, v: dense_attention(q, k, v, None)),
+                     argnums=(0, 1, 2))(q, k, v)
+    for name, g, r in zip("qkv", grads, wants):
+        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        assert np.linalg.norm(g - r) <= 0.01 * np.linalg.norm(r), name
+
+
 def test_fused_attention_takes_the_blocked_forms_precision():
     """bfloat16 products, float32 accumulation and softmax: as far from dense
     attention as the blocked form is, not farther."""

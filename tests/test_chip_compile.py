@@ -152,6 +152,61 @@ def test_the_scopes_see_the_fused_attention_kernels(one_chip, window, scope):
     assert {by_instruction[k] for k in kernels} == {scope}
 
 
+# Qwen3-Next's mixers (PR 32) at its cell's shapes: the full layers' attention
+# through the fused kernel at heads of 256 in groups of 8, and the chunked
+# delta rule, plain XLA: that its gradient compiles for the chip and what it
+# holds for all chunks at once fits beside a training job's state.
+
+
+def test_fused_attention_gradient_is_taken_at_heads_of_256_on_a_v5e(one_chip):
+    import jax.numpy as jnp
+
+    from dynamic_load_balance_distributeddnn_tpu.ops import attention
+    from tests.conftest import traced_instants
+
+    q = jax.ShapeDtypeStruct((2, 4096, 16, 256), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 4096, 2, 256), jnp.bfloat16, sharding=one_chip)
+    with traced_instants("attention_path") as said:
+        compiled = _attention_gradient(
+            lambda q, k, v: attention.blocked_causal_attention(q, k, v)
+        ).lower(q, kv, kv).compile()
+    assert said == [{"path": "fused", "why": "tpu", "window": None, "t": 4096,
+                     "dtype": "bfloat16"}]
+    text = compiled.as_text()
+    assert "fused_attention_fwd" in text and "fused_attention_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 * 2**30
+
+
+def test_delta_rule_gradient_fits_a_v5e_and_lands_in_its_scope(one_chip):
+    import jax.numpy as jnp
+
+    from dynamic_load_balance_distributeddnn_tpu.obs import scopes
+    from dynamic_load_balance_distributeddnn_tpu.ops.linear_attention import gated_delta_rule
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(q, k, v, g, beta):
+        with jax.named_scope(scopes.FORWARD):
+            with jax.named_scope(scopes.LINEAR_ATTENTION):
+                with jax.named_scope(scopes.DELTA_RULE):
+                    o = gated_delta_rule(q, k, v, g, beta)
+            return jnp.sum(o.astype(jnp.float32))
+
+    heads = (2, 4096, 32, 128)
+    args = (sds(heads), sds(heads), sds(heads), sds(heads[:3], jnp.float32),
+            sds(heads[:3], jnp.float32))
+    compiled = jax.jit(jax.grad(jax.checkpoint(layer), argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile()
+    # what is held for all 64 chunks of two columns at once, forward and backward
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text  # plain XLA: no kernel comes with it
+    _, by_instruction = scopes.instruction_scopes(text)
+    named = set(by_instruction.values())
+    assert scopes.DELTA_RULE in named and scopes.LINEAR_ATTENTION not in named
+
+
 def test_expert_layer_gradient_is_a_grouped_matmul_on_a_v5e(one_chip):
     import jax.numpy as jnp
 

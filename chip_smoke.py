@@ -76,6 +76,8 @@ KERNEL_CASES = (
     ("fused_attention_full_b2_t1024_h8_d128", "attention_full", (2, 1024, 8, 128)),
     # Qwen3-Next's full layers: heads of 256 in groups of 8 query heads
     ("fused_attention_full_b2_t1024_h8_d256", "attention_full", (2, 1024, 8, 256)),
+    # Qwen3-Next's linear layers: the gated delta rule, 32 value over 16 key heads of 128
+    ("fused_delta_rule_b2_t1024_h32_d128", "delta_rule", (2, 1024, 32, 128)),
     ("groupnorm_relu_128x32x32x64", "groupnorm", (128, 32, 32, 64)),
     ("groupnorm_relu_128x8x8x512", "groupnorm", (128, 8, 8, 512)),
     ("xent_512x10", "xent", (512, 10)),
@@ -130,6 +132,35 @@ def kernel_case(kind: str, shape):
             return _blocked(q, k, v, window, 256)
 
         return pallas_fn, ref_fn, (q_spec, kv_spec, kv_spec), (0, 1, 2)
+    if kind == "delta_rule":
+        from dynamic_load_balance_distributeddnn_tpu.ops import linear_attention
+        from dynamic_load_balance_distributeddnn_tpu.ops.pallas.delta_rule import (
+            fused_delta_rule,
+        )
+
+        b, t, h, d = shape  # values; queries and keys have half the heads
+
+        def operands(q, k, v, g, beta):
+            """Normal draws as the model's operands: unit q (scaled) and k,
+            g <= 0, beta in (0, 1)."""
+            def unit(x):
+                x = x.astype(f32)
+                return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+            return ((unit(q) * d ** -0.5).astype(bf16), unit(k).astype(bf16), v,
+                    -jax.nn.softplus(g), jax.nn.sigmoid(beta))
+
+        def pallas_fn(*a):
+            block = math.gcd(t, linear_attention.FUSED_BLOCK)  # as gated_delta_rule takes it
+            return fused_delta_rule(*operands(*a), block_t=block, interpret=False)
+
+        def ref_fn(*a):  # the chunked XLA form on the same bfloat16 operands
+            return linear_attention._chunked(*operands(*a), linear_attention.CHUNK)
+
+        keys = jax.ShapeDtypeStruct((b, t, h // 2, d), bf16)
+        gates = jax.ShapeDtypeStruct((b, t, h), f32)
+        return (pallas_fn, ref_fn, (keys, keys, jax.ShapeDtypeStruct(shape, bf16), gates, gates),
+                (0, 1, 2, 3, 4))
     if kind == "groupnorm":
         c = shape[-1]
         groups = math.gcd(32, c)  # models/common.py group_norm

@@ -156,8 +156,9 @@ class LinearAttention(nn.Module):
         k = mixed[..., key_w:2 * key_w].reshape(b, t, hk, dk)
         v = mixed[..., 2 * key_w:].reshape(b, t, hv, dv)
 
-        q = jnp.repeat((unit(q) * dk ** -0.5).astype(a.dtype), hv // hk, axis=2)
-        k = jnp.repeat(unit(k).astype(a.dtype), hv // hk, axis=2)
+        # at their own heads: the rule gives value head h its key head h // (hv / hk)
+        q = (unit(q) * dk ** -0.5).astype(a.dtype)
+        k = unit(k).astype(a.dtype)
         beta = jax.nn.sigmoid(ba[..., :hv])
         a_log = self.param("A_log", _a_log, (hv,))
         dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,))

@@ -184,13 +184,15 @@ def bench_cell_attention(results, dtype, repeats, quick):
 
 def bench_delta_rule(results, dtype, repeats, quick):
     """The mixers of ``qwen3_next.ws4_even_dbs`` as one worker's step calls
-    them, 2 columns of 4,096 tokens: the chunked gated delta rule alone (32
-    heads of 128 x 128, plain XLA; its FLOPs are the recurrence's three
-    products a token and head, whatever the chunked form computes) and, beside
-    it, the full layers' attention (16 query / 2 key-value heads of 256)."""
+    them, 2 columns of 4,096 tokens: the gated delta rule alone (32 heads of
+    128 x 128; its FLOPs are the recurrence's three products a token and
+    head, whatever form computes them) in the chunked XLA form and in the
+    fused one at each block, on the same operands, then as the model calls it
+    (q and k at their 16 key heads), and, beside it, the full layers'
+    attention (16 query / 2 key-value heads of 256)."""
     del repeats, quick
-    from dynamic_load_balance_distributeddnn_tpu.ops import attention
-    from dynamic_load_balance_distributeddnn_tpu.ops.linear_attention import gated_delta_rule
+    from dynamic_load_balance_distributeddnn_tpu.ops import attention, linear_attention
+    from dynamic_load_balance_distributeddnn_tpu.ops.pallas.delta_rule import fused_delta_rule
 
     peak = bf16_peak()
     b, t, h, d = 2, 4096, 32, 128
@@ -209,9 +211,17 @@ def bench_delta_rule(results, dtype, repeats, quick):
             jax.random.normal(keys[1], (b, t, hkv, dq), dtype),
             jax.random.normal(keys[2], (b, t, hkv, dq), dtype))
     pairs = b * hq * t * (t + 1) // 2
+    rule_shape, rule_flops = f"B{b}xT{t}xH{h}xD{d}x{d}", 3 * 2 * b * t * h * d * d
     cases = (
-        ("delta_rule", "chunked_xla_64", gated_delta_rule, rule, f"B{b}xT{t}xH{h}xD{d}x{d}",
-         3 * 2 * b * t * h * d * d, 3),
+        ("delta_rule", "chunked_xla_64",
+         lambda *a: linear_attention._chunked(*a, linear_attention.CHUNK), rule, rule_shape,
+         rule_flops, 3),
+        *((("delta_rule", f"fused_{block}",
+            lambda *a, block=block: fused_delta_rule(*a, block_t=block), rule, rule_shape,
+            rule_flops, 3) for block in (128, 256, 512))),
+        ("delta_rule", "default_path_16_key_heads", linear_attention.gated_delta_rule,
+         (rule[0][:, :, ::2], rule[1][:, :, ::2]) + rule[2:], f"B{b}xT{t}xH{h}/{h // 2}xD{d}x{d}",
+         rule_flops, 3),
         ("cell_attention_256", "blocked_xla_256",
          lambda q, k, v: attention._blocked(q, k, v, None, 256), attn,
          f"B{b}xT{t}xH{hq}/{hkv}xD{dq}", 4 * dq * pairs, 3.5),
@@ -221,8 +231,7 @@ def bench_delta_rule(results, dtype, repeats, quick):
     for kernel, form, fn, args, shape, fwd_flops, bwd_over_fwd in cases:
         row = {"kernel": kernel, "form": form, "shape": shape, "dtype": str(dtype.__name__)}
         try:
-            w = jax.random.normal(keys[5], args[2].shape[:2] + args[0].shape[2:3]
-                                  + args[2].shape[3:], jnp.float32)
+            w = jax.random.normal(keys[5], jax.eval_shape(fn, *args).shape, jnp.float32)
             grad = jax.jit(jax.grad(
                 lambda *a, fn=fn: jnp.sum(fn(*a[:-1]).astype(jnp.float32) * a[-1]),
                 argnums=tuple(range(len(args)))))

@@ -178,33 +178,66 @@ def test_fused_attention_gradient_is_taken_at_heads_of_256_on_a_v5e(one_chip):
 
 
 def test_delta_rule_gradient_fits_a_v5e_and_lands_in_its_scope(one_chip):
+    """One worker's call of the cell (2 columns of 4,096 tokens, 32 value over
+    16 key heads of 128, bfloat16) takes the fused form when lowered for the
+    chip, both kernels land in the ``delta_rule`` scope, and it holds less than
+    the chunked XLA form does for all chunks at once."""
     import jax.numpy as jnp
 
     from dynamic_load_balance_distributeddnn_tpu.obs import scopes
-    from dynamic_load_balance_distributeddnn_tpu.ops.linear_attention import gated_delta_rule
+    from dynamic_load_balance_distributeddnn_tpu.ops import linear_attention
+    from tests.conftest import traced_instants
 
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def layer(q, k, v, g, beta):
-        with jax.named_scope(scopes.FORWARD):
-            with jax.named_scope(scopes.LINEAR_ATTENTION):
-                with jax.named_scope(scopes.DELTA_RULE):
-                    o = gated_delta_rule(q, k, v, g, beta)
-            return jnp.sum(o.astype(jnp.float32))
+    def compiled_gradient(rule):
+        def layer(q, k, v, g, beta):
+            with jax.named_scope(scopes.FORWARD):
+                with jax.named_scope(scopes.LINEAR_ATTENTION):
+                    with jax.named_scope(scopes.DELTA_RULE):
+                        o = rule(q, k, v, g, beta)
+                return jnp.sum(o.astype(jnp.float32))
 
-    heads = (2, 4096, 32, 128)
-    args = (sds(heads), sds(heads), sds(heads), sds(heads[:3], jnp.float32),
-            sds(heads[:3], jnp.float32))
-    compiled = jax.jit(jax.grad(jax.checkpoint(layer), argnums=(0, 1, 2, 3, 4))).lower(
-        *args).compile()
+        return jax.jit(jax.grad(jax.checkpoint(layer), argnums=(0, 1, 2, 3, 4))).lower(
+            *args).compile()
+
+    keys, values = (2, 4096, 16, 128), (2, 4096, 32, 128)
+    args = (sds(keys), sds(keys), sds(values), sds(values[:3], jnp.float32),
+            sds(values[:3], jnp.float32))
+    with traced_instants("linear_attention_path") as said:
+        fused = compiled_gradient(linear_attention.gated_delta_rule)
+    # the checkpoint lowers the forward pass twice
+    assert said and all(x == {"path": "fused", "why": "tpu", "chunk": 64, "t": 4096,
+                              "dtype": "bfloat16", "heads": 32} for x in said)
+    chunked = compiled_gradient(
+        lambda *a: linear_attention._chunked(*a, linear_attention.CHUNK))
     # what is held for all 64 chunks of two columns at once, forward and backward
-    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
-    text = compiled.as_text()
-    assert "tpu_custom_call" not in text  # plain XLA: no kernel comes with it
-    _, by_instruction = scopes.instruction_scopes(text)
-    named = set(by_instruction.values())
-    assert scopes.DELTA_RULE in named and scopes.LINEAR_ATTENTION not in named
+    assert chunked.memory_analysis().temp_size_in_bytes < 3 * 2**30
+    assert "tpu_custom_call" not in chunked.as_text()  # plain XLA
+    # the fused form: the states between chunks and the gradients of q and k a value head
+    assert (fused.memory_analysis().temp_size_in_bytes
+            < chunked.memory_analysis().temp_size_in_bytes)
+    assert fused.memory_analysis().temp_size_in_bytes < 1 * 2**30
+    text = fused.as_text()
+    assert "delta_rule_fwd" in text and "delta_rule_bwd" in text
+    for compiled in (fused, chunked):
+        _, by_instruction = scopes.instruction_scopes(compiled.as_text())
+        named = set(by_instruction.values())
+        assert scopes.DELTA_RULE in named and scopes.LINEAR_ATTENTION not in named
+    by_name = scopes.instruction_scopes(text)[1]
+    kernels = [n for n, line in _instruction_lines(text).items() if "tpu_custom_call" in line]
+    assert len(kernels) >= 2 and all(by_name[n] == scopes.DELTA_RULE for n in kernels)
+
+
+def _instruction_lines(text):
+    """``{instruction name: its line}`` of an HLO module's text."""
+    lines = {}
+    for line in text.splitlines():
+        head = line.strip().removeprefix("ROOT ").split(" = ", 1)
+        if len(head) == 2 and head[0].startswith("%"):
+            lines[head[0].lstrip("%")] = line
+    return lines
 
 
 def test_expert_layer_gradient_is_a_grouped_matmul_on_a_v5e(one_chip):

@@ -114,7 +114,8 @@ def test_each_lowered_delta_rule_says_so_once():
     args = tuple(x.astype(jnp.bfloat16) for x in rule_operands(0.5, t=64))
     with traced_instants("linear_attention_path") as said:
         jax.jit(lambda *a: linear_attention.gated_delta_rule(*a, 32)).lower(*args)
-    assert said == [{"chunk": 32, "t": 64, "dtype": "bfloat16", "heads": 3}]
+    assert said == [{"path": "chunked", "why": "head_dim", "chunk": 32, "t": 64,
+                     "dtype": "bfloat16", "heads": 3}]
 
 
 def test_causal_conv_is_numpys_convolution_per_channel_and_sees_nothing_before_the_window():

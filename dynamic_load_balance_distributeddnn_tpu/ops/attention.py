@@ -2,10 +2,12 @@
 attention with an optional window, in two forms of one algorithm.
 
 *Fused* (``ops/pallas/fused_attention.py``): a flash-form kernel that keeps the
-scores in VMEM. It serves a call whose operands are bfloat16, whose head size
-divides by 128 and whose ``T`` divides by its blocks, when the program is
-lowered for a TPU. *Blocked*, in plain XLA, serves every other call (float32
-operands, odd shapes, the CPU). The choice is made from the call itself and
+scores in VMEM. It serves a call whose operands are bfloat16 or float32, whose
+head size divides by 128 and whose ``T`` divides by its blocks, when the
+program is lowered for a TPU. Its products are those the blocked form gets
+from XLA:TPU at ``Precision.DEFAULT``: bfloat16 operands, float32 rounded to
+them. *Blocked*, in plain XLA, serves every other call (other dtypes, odd
+shapes, the CPU). The choice is made from the call itself and
 from the platform the program is lowered for, never from the process's default
 backend: a program compiled for a described chip takes the path the chip
 would. Each lowered call leaves one ``attention_path`` instant in the tracer.
@@ -41,8 +43,11 @@ _NEG = -1e30
 # case on a v5e (PERF.md section 6, PR 28)
 FUSED_BLOCK = 512
 # its K and V of one head stay in VMEM, twice each for the pipeline, beside the
-# backward pass's float32 dK and dV: 4 MB is 16,384 keys of 128
+# backward pass's float32 dK and dV and, for a float32 call, their float32
+# outputs: counted at the operands' width, 4 MB is 16,384 keys of 128 in
+# bfloat16, 4,096 of 256 in float32
 _FUSED_KV_BYTES = 4 * 2**20
+_FUSED_DTYPES = (jnp.bfloat16, jnp.float32)
 
 
 def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float) -> jnp.ndarray:
@@ -114,11 +119,11 @@ def _why_not_fused(q, k, v) -> Optional[str]:
     """What about the call itself keeps the fused form from serving it."""
     t, d = q.shape[1], q.shape[-1]
     for x in (q, k, v):
-        if x.dtype != jnp.bfloat16:
+        if x.dtype != q.dtype or x.dtype not in _FUSED_DTYPES:
             return str(x.dtype)
     if d % 128:
         return "head_dim"
-    if t % FUSED_BLOCK or t * d * 2 > _FUSED_KV_BYTES:
+    if t % FUSED_BLOCK or t * d * q.dtype.itemsize > _FUSED_KV_BYTES:
         return "t"
     return None
 

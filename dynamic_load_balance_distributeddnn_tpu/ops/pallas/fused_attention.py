@@ -10,10 +10,12 @@ and stay in VMEM for all its query heads and blocks (1 MB each at 4,096 keys
 of 128). Inside, a loop walks the key tiles the mask leaves: the tiles
 between the window's lower edge and the diagonal unmasked, the ones the mask
 cuts (the edge, the diagonal) masked, the rest never touched. Score tiles
-live in VMEM; the softmax keeps a running (max, sum) in float32. Products
-take the operands' dtype (bfloat16 in: bfloat16 products, float32
-accumulation, ``p`` and ``ds`` cast to bfloat16 before their products); the
-scale ``1/sqrt(D)`` is applied to the float32 scores.
+live in VMEM; the softmax keeps a running (max, sum) in float32. Every
+product is taken at ``Precision.DEFAULT`` on bfloat16 operands with float32
+accumulation, as XLA:TPU multiplies float32 at ``DEFAULT`` (one bfloat16
+pass): float32 q, k, v (and dO) are rounded once before the kernel, ``p`` and
+``ds`` before their products. The output and the gradients take the inputs'
+dtypes; the scale ``1/sqrt(D)`` is applied to the float32 scores.
 
 The backward pass is one kernel over the same grid: it replays the tiles
 transposed (keys in sublanes, so the saved per-row log-sum-exp and ``delta =
@@ -36,7 +38,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
 _VMEM_LIMIT = 64 * 2**20  # of the core's 128 MiB; the default scope is 16
+
+
+def _dot(a, b, dims):
+    """``a`` and ``b`` in bfloat16 contracted over ``dims``, in float32."""
+    return jax.lax.dot_general(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16), dims,
+                               precision=jax.lax.Precision.DEFAULT,
+                               preferred_element_type=jnp.float32)
 
 
 def _tile_ranges(q0, block_q: int, block_k: int, window: Optional[int]):
@@ -94,14 +104,14 @@ def _fused_attention_fwd(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, window, 
         m, l, acc = carry
         keys = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
         k, v = k_ref[0, keys, :], v_ref[0, keys, :]
-        s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32) * scale
+        s = _dot(q, k, _NT) * scale
         if masked:
             s = jnp.where(_visible(q0, j * block_k, s.shape, 0, window), s, _NEG)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         fade = jnp.exp(m - m_new)
         l = l * fade + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * fade + jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        acc = acc * fade + _dot(p, v, _NN)
         return m_new, l, acc
 
     m, l, acc = _walk(_tile_ranges(q0, block_q, block_k, window), tile, (
@@ -128,15 +138,15 @@ def _fused_attention_bwd(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref
         keys = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
         k, v = k_ref[0, keys, :], v_ref[0, keys, :]
         # transposed: [block_k, block_q]
-        s = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32) * scale
+        s = _dot(k, q, _NT) * scale
         if masked:
             s = jnp.where(_visible(q0, j * block_k, s.shape, 1, window), s, _NEG)
         p = jnp.exp(s - lse)
-        dv_acc[keys, :] += jnp.dot(p.astype(do.dtype), do, preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+        dv_acc[keys, :] += _dot(p, do, _NN)
+        dp = _dot(v, do, _NT)
         ds = p * (dp - delta) * scale
-        dk_acc[keys, :] += jnp.dot(ds.astype(q.dtype), q, preferred_element_type=jnp.float32)
-        return dq + jnp.dot(ds.T.astype(k.dtype), k, preferred_element_type=jnp.float32)
+        dk_acc[keys, :] += _dot(ds, q, _NN)
+        return dq + _dot(ds.T, k, _NN)
 
     dq = _walk(_tile_ranges(q0, block_q, block_k, window), tile, jnp.zeros(q.shape, jnp.float32))
     dq_ref[0] = dq.astype(dq_ref.dtype)
@@ -149,7 +159,8 @@ def _fused_attention_bwd(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref
 
 def _call(kernel, q, k, ins, outs, scratch, semantics, window, block_q, block_k, interpret):
     """``kernel`` over the grid ``(batch, key-value head, query block, query
-    head of the group)``. ``ins`` and ``outs`` name each operand's kind:
+    head of the group)``; its outputs take ``q``'s and ``k``'s dtypes, whatever
+    its operands were rounded to. ``ins`` and ``outs`` name each operand's kind:
     "rows" (like q, ``[B, T, H*D]``: one head's query block a program), "keys"
     (like k, ``[B, T, Hkv*D]``: the key-value head's whole ``[T, D]``, fetched
     once a head) or "stats" (per-row statistics ``[B, H, 1, T]``)."""
@@ -185,11 +196,17 @@ def _flat(x):
     return x.reshape(*x.shape[:2], -1)
 
 
+def _operands(*xs):
+    """``xs`` flattened to ``[B, T, heads * D]`` in bfloat16, their products'
+    dtype."""
+    return [_flat(x.astype(jnp.bfloat16)) for x in xs]
+
+
 def _forward(q, k, v, window, block_q, block_k, interpret):
     o, lse = _call(
         _fused_attention_fwd, q, k, ("rows", "keys", "keys"), ("rows", "stats"), [],
         ("parallel",) * 4, window, block_q, block_k, interpret,
-    )(_flat(q), _flat(k), _flat(v))
+    )(*_operands(q, k, v))
     return o.reshape(q.shape), lse
 
 
@@ -213,7 +230,7 @@ def _attention_bwd(window, block_q, block_k, interpret, saved, do):
         ("rows", "keys", "keys"), [acc, acc],
         # dK and dV add up over the key-value head's query blocks and heads
         ("parallel", "parallel", "arbitrary", "arbitrary"), window, block_q, block_k, interpret,
-    )(_flat(q), _flat(k), _flat(v), _flat(do), lse, delta[:, :, None, :])
+    )(*_operands(q, k, v, do), lse, delta[:, :, None, :])
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
@@ -227,5 +244,6 @@ def fused_causal_attention(
     """Causal softmax attention, scale ``1/sqrt(D)``; with ``window``, a query
     at ``i`` sees the keys ``j`` with ``0 <= i - j < window``. ``T`` must
     divide by both blocks and ``D`` by 128; K and V of one head (``T * D``
-    elements each) have to fit VMEM several times over."""
+    elements each) have to fit VMEM several times over. Operands are bfloat16
+    or float32; the result takes ``q``'s dtype."""
     return _attention(q, k, v, window, block_q, block_k or block_q, interpret)

@@ -13,8 +13,11 @@ Writes <out_dir>/kernel_bench_<platform>.json and a markdown table to
 Usage: python scripts/kernel_bench.py --out_dir DIR [--repeats 30] [--quick]
        python scripts/kernel_bench.py --out_dir DIR --only cell_attention
        python scripts/kernel_bench.py --out_dir DIR --only delta_rule
-       (PERF.md's table of the Trinity-Mini cell's attention: blocked XLA
-       against each fused candidate, window and full)
+       python scripts/kernel_bench.py --out_dir DIR --only validation_attention
+       (PERF.md's tables of the Trinity-Mini cell's attention: blocked XLA
+       against each fused candidate, window and full; of both decoder cells'
+       float32 validation attention, forward, with each form's distance from
+       dense attention at HIGHEST)
 """
 
 import argparse
@@ -247,6 +250,62 @@ def bench_delta_rule(results, dtype, repeats, quick):
         print(json.dumps(row), flush=True)
 
 
+def bench_validation_attention(results, dtype, repeats, quick):
+    """The attention of both decoder cells' validation (``fused_eval_step``),
+    forward only and float32 whatever ``--dtype`` says: 10 columns of 4,096
+    tokens, Trinity-Mini's 32 query / 4 key-value heads of 128 with the window
+    of 2,048 and without, Qwen3-Next's 16 / 2 heads of 256 without. Each form
+    (blocked XLA, whose products XLA:TPU takes at DEFAULT; the fused kernel,
+    whose products are bfloat16 passes too; the default path) is timed and
+    read against dense float32 attention at HIGHEST: ``rel_err`` is the
+    largest distance over the largest output, ``over_blocked`` its ratio to
+    the blocked form's."""
+    del dtype, repeats, quick
+    import chip_smoke
+    from dynamic_load_balance_distributeddnn_tpu.ops import attention
+    from dynamic_load_balance_distributeddnn_tpu.ops.pallas.fused_attention import (
+        fused_causal_attention,
+    )
+
+    peak = bf16_peak()
+    b, t = 10, 4096
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    for h, hkv, d, window in ((32, 4, 128, 2048), (32, 4, 128, None), (16, 2, 256, None)):
+        q = jax.random.normal(keys[0], (b, t, h, d), jnp.float32)
+        k = jax.random.normal(keys[1], (b, t, hkv, d), jnp.float32)
+        v = jax.random.normal(keys[2], (b, t, hkv, d), jnp.float32)
+        want = jax.jit(lambda q, k, v, w=window: chip_smoke.dense_attention(q, k, v, w))(q, k, v)
+        top = float(jnp.abs(want).max())
+        pairs = b * h * sum(min(i + 1, window or t) for i in range(t))
+        blocked_err = None
+        for form, fn in (
+            ("blocked_xla_256", lambda q, k, v, w=window: attention._blocked(q, k, v, w, 256)),
+            ("fused", lambda q, k, v, w=window: fused_causal_attention(
+                q, k, v, w, attention.FUSED_BLOCK)),
+            ("default_path", lambda q, k, v, w=window: attention.blocked_causal_attention(
+                q, k, v, w)),
+        ):
+            row = {"kernel": "validation_attention", "form": form, "window": window,
+                   "shape": f"B{b}xT{t}xH{h}/{hkv}xD{d}", "dtype": "float32"}
+            try:
+                fwd = jax.jit(fn)
+                got = fwd(q, k, v)
+                row["out_dtype"] = str(got.dtype)
+                row["rel_err"] = float(jnp.abs(got - want).max()) / top
+                blocked_err = blocked_err or row["rel_err"]
+                row["over_blocked"] = row["rel_err"] / blocked_err
+                del got
+                row["fwd_ms"] = best_of(fwd, q, k, v) * 1e3
+                if peak:
+                    row["fwd_pct_of_bf16_peak"] = (
+                        100 * 4 * d * pairs / (row["fwd_ms"] * 1e-3) / peak)
+            except Exception as e:  # a form the compiler refuses is a result
+                row["error"] = f"{type(e).__name__}: {e}"[:300]
+            results.append(row)
+            print(json.dumps(row), flush=True)
+        del q, k, v, want
+
+
 def bench_groupnorm(results, dtype, repeats, quick):
     """CNN shapes: 32x32 CIFAR maps through the zoo's widths, GroupNorm(32)
     (Net/Resnet.py:11-13); batch = per-worker 128 of the B=512/ws=4 recipe."""
@@ -336,7 +395,8 @@ def to_markdown(results, platform, kind):
         "|---|---|---|---|---|---|---|---|---|",
     ]
     for r in results:
-        if r["kernel"] in ("cell_attention", "cell_attention_256", "delta_rule"):
+        if r["kernel"] in ("cell_attention", "cell_attention_256", "delta_rule",
+                           "validation_attention"):
             continue  # printed as JSON lines and kept in the .json: other columns
         if "error" in r:
             lines.append(
@@ -354,7 +414,8 @@ def to_markdown(results, platform, kind):
 
 
 LEGS = {"groupnorm": bench_groupnorm, "xent": bench_xent,
-        "cell_attention": bench_cell_attention, "delta_rule": bench_delta_rule}
+        "cell_attention": bench_cell_attention, "delta_rule": bench_delta_rule,
+        "validation_attention": bench_validation_attention}
 
 
 def main():

@@ -40,14 +40,50 @@ def operands(h, hkv, dtype=jnp.bfloat16, d=D):
     return q, k, v, jax.random.normal(keys[3], (B, T, h, d), jnp.float32)
 
 
+def one_pass_attention(q, k, v, window):
+    """Dense attention with every product's operands rounded to bfloat16, as
+    XLA:TPU multiplies float32 at ``Precision.DEFAULT``: the blocked form's
+    arithmetic on the chip, which the CPU does not round."""
+    def r(x):
+        return x.astype(jnp.bfloat16).astype(jnp.float32)
+
+    b, t, h, d = q.shape
+    hkv = k.shape[2]
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", r(q).reshape(b, t, hkv, h // hkv, d), r(k),
+                   precision="highest") / np.sqrt(d)
+    ahead = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+    mask = ahead >= 0 if window is None else (ahead >= 0) & (ahead < window)
+    p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", r(p), r(v), precision="highest").reshape(b, t, h, d)
+
+
+HEADS = {"8q1kv": (8, 1, D), "32q4kv": (32, 4, D), "8q1kv_d256": (8, 1, 256)}
+WINDOWS = {"window256": 256, "window192": 192, "full": None}
+
+
+def _case(heads, window, blocks, dtype="bfloat16", grad=True):
+    name = f"{heads}-{window}-{blocks}" + (f"-{dtype}" if dtype != "bfloat16" else "")
+    name += "-grad" if grad and dtype != "bfloat16" else ""
+    return pytest.param(HEADS[heads], WINDOWS[window], blocks, jnp.dtype(dtype), grad, id=name)
+
+
 # blocks of 128 against T 512 and a window of 256: whole tiles, tiles the
 # window's edge cuts, the diagonal's, and tiles never touched; 192 and the
-# unequal blocks put the edges inside tiles
-@pytest.mark.parametrize("blocks", [(128, 128), (256, 128), (128, 256)], ids=str)
-@pytest.mark.parametrize("window", [256, 192, None], ids=["window256", "window192", "full"])
-@pytest.mark.parametrize("heads", [(8, 1), (32, 4)], ids=["8q1kv", "32q4kv"])
-def test_fused_attention_agrees_with_dense(heads, window, blocks):
-    q, k, v, w = operands(*heads)
+# unequal blocks put the edges inside tiles. Float32 (validation's calls): the
+# forward pass at both decoders' head sizes, and one gradient
+@pytest.mark.parametrize("heads,window,blocks,dtype,grad", [
+    _case(heads, window, blocks)
+    for heads in ("8q1kv", "32q4kv") for window in WINDOWS
+    for blocks in [(128, 128), (256, 128), (128, 256)]
+] + [
+    _case("32q4kv", "window256", (256, 256), "float32", grad=False),
+    _case("32q4kv", "full", (256, 256), "float32", grad=False),
+    _case("8q1kv_d256", "window256", (256, 256), "float32", grad=False),
+    _case("8q1kv_d256", "full", (256, 256), "float32", grad=False),
+    _case("8q1kv", "window256", (256, 256), "float32"),
+])
+def test_fused_attention_agrees_with_dense(heads, window, blocks, dtype, grad):
+    q, k, v, w = operands(*heads[:2], dtype, d=heads[2])
 
     def loss(attention):
         return lambda q, k, v: jnp.sum(attention(q, k, v).astype(jnp.float32) * w)
@@ -57,12 +93,19 @@ def test_fused_attention_agrees_with_dense(heads, window, blocks):
 
     want = dense_attention(q, k, v, window)
     got = fused(q, k, v)
-    assert got.dtype == jnp.bfloat16 and got.shape == q.shape
+    assert got.dtype == dtype and got.shape == q.shape
     # bfloat16 holds 8 bits: outputs of size one to within 2^-7
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=0.02)
+    if dtype == jnp.float32:
+        # no farther from dense than the blocked form on the chip
+        bar = np.abs(np.asarray(one_pass_attention(q, k, v, window)) - want).max()
+        assert np.abs(np.asarray(got) - want).max() <= 1.25 * bar
+    if not grad:
+        return
     grads = jax.grad(loss(fused), argnums=(0, 1, 2))(q, k, v)
     wants = jax.grad(loss(lambda q, k, v: dense_attention(q, k, v, window)), argnums=(0, 1, 2))(q, k, v)
     for name, g, r in zip("qkv", grads, wants):
+        assert g.dtype == dtype, name
         g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
         assert np.linalg.norm(g - r) <= 0.01 * np.linalg.norm(r), name
 
@@ -132,14 +175,14 @@ def test_fused_attention_refuses_a_ragged_t():
         fused_causal_attention(q, k, v, None, 384, interpret=True)
 
 
-@pytest.mark.parametrize("case,why", [("cpu", "cpu"), ("float32", "float32"),
+@pytest.mark.parametrize("case,why", [("cpu", "cpu"), ("float32", "cpu"), ("float16", "float16"),
                                       ("head_dim", "head_dim"), ("t", "t")])
 def test_the_blocked_form_is_taken_and_says_why(case, why):
     """On the CPU every call is blocked: by the platform where the kernel
-    would have served the call, else by what about the call it does not
-    serve. The result is the blocked form's, to the bit."""
-    q, k, v, _ = operands(8, 1, jnp.float32 if case == "float32" else jnp.bfloat16,
-                          64 if case == "head_dim" else D)
+    would have served the call (bfloat16 or float32), else by what about the
+    call it does not serve. The result is the blocked form's, to the bit."""
+    dtype = case if case in ("float32", "float16") else jnp.bfloat16
+    q, k, v, _ = operands(8, 1, dtype, 64 if case == "head_dim" else D)
     if case == "t":
         q, k, v = (x[:, : FUSED_BLOCK // 2] for x in (q, k, v))
     with traced_instants("attention_path") as said:

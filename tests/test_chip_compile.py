@@ -152,6 +152,35 @@ def test_the_scopes_see_the_fused_attention_kernels(one_chip, window, scope):
     assert {by_instruction[k] for k in kernels} == {scope}
 
 
+@pytest.mark.parametrize("heads,d,window", [(32, 128, 2048), (16, 256, None)],
+                         ids=["trinity_window", "qwen3_next_full"])
+def test_float32_validation_attention_is_fused_and_fits_a_v5e(one_chip, heads, d, window):
+    """Validation's call (``fused_eval_step``: 10 columns of 4,096 tokens,
+    float32 throughout) lowered for the chip takes the fused kernel, keeps
+    its output float32, and holds no block of float32 scores (the blocked
+    form's are 1.3 GB a block at heads of 128)."""
+    import jax.numpy as jnp
+
+    from dynamic_load_balance_distributeddnn_tpu.ops import attention
+    from tests.conftest import traced_instants
+
+    q = jax.ShapeDtypeStruct((10, 4096, heads, d), jnp.float32, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((10, 4096, heads // 8, d), jnp.float32, sharding=one_chip)
+    with traced_instants("attention_path") as said:
+        lowered = jax.jit(lambda q, k, v: attention.blocked_causal_attention(q, k, v, window)
+                          ).lower(q, kv, kv)
+    assert said == [{"path": "fused", "why": "tpu", "window": window, "t": 4096,
+                     "dtype": "float32"}]
+    assert lowered.out_info.dtype == jnp.float32
+    compiled = lowered.compile()
+    assert "fused_attention_fwd" in compiled.as_text()
+    # the kernel's float32 output before its reshape and the products'
+    # bfloat16 copies of q, k and v: no block of scores
+    n = 10 * 4096 * d
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= n * (heads * 4 + (heads + heads // 4) * 2)
+
+
 # Qwen3-Next's mixers (PR 32) at its cell's shapes: the full layers' attention
 # through the fused kernel at heads of 256 in groups of 8, and the chunked
 # delta rule, plain XLA: that its gradient compiles for the chip and what it
